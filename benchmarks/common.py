@@ -15,7 +15,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 
 from repro.configs import base as cfgbase
@@ -25,6 +24,7 @@ from repro.core import capacity as cap
 from repro.core.dummy import pack_global_batch
 from repro.data.synthetic import make_lm_records
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import batch_specs, named
 from repro.models.model import build_model
 
@@ -70,7 +70,7 @@ def run_training(
 ) -> BenchResult:
     """One timed run. ``data_parallel`` host devices form the DP mesh."""
     model = build_model(cfg)
-    mesh = jax.make_mesh((data_parallel, 1), ("data", "model"))
+    mesh = make_mesh((data_parallel, 1), ("data", "model"))
     shape = ShapeConfig("bench", seq_len, global_batch, "train")
     tcfg = TrainConfig(model=cfg, shape=shape, het=HetConfig(),
                        optimizer=OptimizerConfig(
@@ -83,7 +83,7 @@ def run_training(
                           seed=seed)
     rng = np.random.default_rng(seed)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = steps_mod.init_train_state(model, tcfg, mesh,
                                            jax.random.PRNGKey(seed))
         step_fn = steps_mod.build_train_step(model, tcfg, mesh)

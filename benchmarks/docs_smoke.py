@@ -54,6 +54,8 @@ def run_docs_smoke(readme_path: str = README) -> int:
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    # dry runs are a CPU tier; never contend with a parent for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     for args in commands:
         argv = [sys.executable, "-m", _TRAIN_MODULE] + args
         if "--dry-run" not in args:

@@ -72,10 +72,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from benchmarks.reduce_bench import count_pod_collectives, \
     synthetic_grad_tree
-from repro import compat
 from repro.configs.base import OptimizerConfig
 from repro.core import buckets as bkt
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.optim import adam
 
 _BLOCK = steps_mod._BLOCK
@@ -278,7 +278,7 @@ def bench_backward(mesh, pods: int, bucket_mb: float, iters: int,
         g = jax.tree.map(lambda a: a[0], gl)
         flat = bkt.pack_buckets(g, layout)
         x = flat.reshape(layout.num_buckets, pods, -1)
-        onehot = compat.manual_axis_onehot("pod", pods, tie=flat)
+        onehot = bkt.rank_onehot("pod", pods)
 
         def prep(k, raw_k):
             return bkt.prepare_bucket(
@@ -301,9 +301,9 @@ def bench_backward(mesh, pods: int, bucket_mb: float, iters: int,
     results: Dict[str, Any] = {}
     outs = {}
     for name, f in (("serial", serial), ("flush_ordered", flush_ordered)):
-        sm = compat.shard_map(f, mesh=mesh, in_specs=P("pod"),
-                              out_specs=P(), axis_names={"pod"},
-                              check_vma=False)
+        sm = jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                           out_specs=P(), axis_names={"pod"},
+                           check_vma=False)
         jf = jax.jit(sm)
         out = jax.block_until_ready(jf(stacked))
         t0 = time.perf_counter()
@@ -373,10 +373,10 @@ def bench_modes(tree: Dict[str, jnp.ndarray], mesh, pods: int,
     results: Dict[str, Any] = {}
     outs = {}
     for name, f in (("serial", serial), ("overlap", overlap)):
-        sm = compat.shard_map(f, mesh=mesh, in_specs=(P("pod"), P(), P(),
-                                                      P()),
-                              out_specs=(P(), P(), P()),
-                              axis_names={"pod"}, check_vma=False)
+        sm = jax.shard_map(f, mesh=mesh,
+                           in_specs=(P("pod"), P(), P(), P()),
+                           out_specs=(P(), P(), P()),
+                           axis_names={"pod"}, check_vma=False)
         jf = jax.jit(sm)
         out = jax.block_until_ready(jf(stacked, pb0, m0, v0))
         t0 = time.perf_counter()
@@ -433,15 +433,14 @@ def check_invariants(res: Dict[str, Any]) -> None:
         assert res[mode]["exact_match"]
         # the pipeline trades launches for overlap: 2 per bucket
         nb = res[mode]["_layout"]["num_buckets"]
-        floor = 0 if compat.NATIVE_MANUAL_COLLECTIVES else 1
-        assert res[mode]["overlap"]["collectives"] <= 2 * nb + floor, (
+        assert res[mode]["overlap"]["collectives"] <= 2 * nb, (
             f"{mode}: {res[mode]['overlap']['collectives']} collectives "
-            f"exceeds 2/bucket bound {2 * nb + floor}")
+            f"exceeds 2/bucket bound {2 * nb}")
 
 
 def main(quick: bool = False, out: str = "BENCH_overlap.json",
          bucket_mb: float = 0.25) -> Dict[str, Any]:
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     pods = 2
     if quick:
         tree = synthetic_grad_tree(num_leaves=12, scale=24)

@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs import base
 from repro.configs.base import (HetConfig, OptimizerConfig, ShapeConfig,
@@ -62,6 +61,7 @@ from repro.core import capacity, dummy
 from repro.core import pipeline as pipe
 from repro.data import synthetic
 from repro.launch import steps
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import named
 from repro.models.model import build_model
 
@@ -83,7 +83,7 @@ def _measured_leg(num_steps: int) -> Dict[str, Any]:
                               compute_dtype="float32",
                               scan_layers=False)
     model = build_model(cfg)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     shape = ShapeConfig("t", 16, 8, "train")
     rec = synthetic.make_lm_records(16, 17, cfg.vocab_size, seed=5)
     plan = capacity.plan_capacities(16, [1, 1, 1, 1])
@@ -99,7 +99,7 @@ def _measured_leg(num_steps: int) -> Dict[str, Any]:
                           pipeline_stages=stages),
             optimizer=OptimizerConfig(lr=1e-3, warmup_steps=2,
                                       grad_clip=0.0))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = steps.init_train_state(model, tcfg, mesh,
                                            jax.random.PRNGKey(0))
             step = steps.build_train_step(model, tcfg, mesh)
@@ -187,7 +187,7 @@ def _restore_leg() -> Dict[str, Any]:
                               compute_dtype="float32",
                               scan_layers=False, num_layers=4)
     model = build_model(cfg)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     shape = ShapeConfig("t", 16, 8, "train")
     rec = synthetic.make_lm_records(16, 17, cfg.vocab_size, seed=7)
     plan = capacity.plan_capacities(16, [1, 1, 1, 1])
@@ -210,7 +210,7 @@ def _restore_leg() -> Dict[str, Any]:
     assert cut_skew.tolist() != cut_uni.tolist(), (cut_skew, cut_uni)
 
     # uninterrupted reference: 2 steps under the uniform cut
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = steps.init_train_state(model, t_uni, mesh,
                                     jax.random.PRNGKey(0))
         f_uni = steps.build_train_step(model, t_uni, mesh)
@@ -221,7 +221,7 @@ def _restore_leg() -> Dict[str, Any]:
 
     # interrupted: 1 step under the SKEWED cut, save, restore into the
     # uniform cut, continue
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = steps.init_train_state(model, t_skew, mesh,
                                     jax.random.PRNGKey(0))
         f_skew = steps.build_train_step(model, t_skew, mesh)
@@ -241,7 +241,7 @@ def _restore_leg() -> Dict[str, Any]:
 
     host, meta = mgr.restore(steps.state_shapes(model, t_uni, mesh))
     saved_cut = meta["format"]["pipeline"]["plan"]["rows_per_rank"]
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sr = jax.device_put(
             host, named(mesh, steps.state_specs(model, t_uni, mesh)))
         sr, m2b = f_uni(sr, batch)

@@ -12,9 +12,7 @@ four cross-pod reduction schedules wired behind ``HetConfig``:
 For each path it reports:
   * cross-pod collective-launch count, counted from the jaxpr (the
     latency-bound quantity a heterogeneous DCN link cares about);
-  * modeled per-rank DCN bytes for the *native* schedule
-    (core/buckets.py byte models — the CPU psum emulation in compat.py
-    moves more bytes but launches the same collectives);
+  * modeled per-rank DCN bytes (core/buckets.py byte models);
   * measured wall time per reduction on the host mesh;
   * max abs error vs the exact sum.
 
@@ -44,9 +42,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import buckets as bkt
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 
 _BLOCK = steps_mod._BLOCK
 _COLLECTIVES = ("psum", "all_gather", "all_to_all", "reduce_scatter",
@@ -144,9 +142,9 @@ def bench_paths(tree: Dict[str, jnp.ndarray], mesh, pods: int,
 
     results = {}
     for name, (f, compress, is_bucketed) in paths.items():
-        sm = compat.shard_map(f, mesh=mesh, in_specs=P("pod"),
-                              out_specs=P(), axis_names={"pod"},
-                              check_vma=False)
+        sm = jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                           out_specs=P(), axis_names={"pod"},
+                           check_vma=False)
         jf = jax.jit(sm)
         out = jax.block_until_ready(jf(stacked))       # compile + warmup
         t0 = time.perf_counter()
@@ -176,11 +174,8 @@ def bench_paths(tree: Dict[str, jnp.ndarray], mesh, pods: int,
         "bucket_elems": layout.bucket_elems,
         "num_buckets": layout.num_buckets,
         "collective_bound": layout.num_buckets,
-        # the native schedule is 2 launches/step for the whole tree; the
-        # counted numbers on old-jax stacks include the psum emulation's
-        # rank-derivation scatter (compat.py)
+        # the schedule is 2 launches/step for the whole tree
         "native_bucketed_collectives": 2,
-        "native_manual_collectives": compat.NATIVE_MANUAL_COLLECTIVES,
     }
     return results
 
@@ -188,10 +183,9 @@ def bench_paths(tree: Dict[str, jnp.ndarray], mesh, pods: int,
 def check_invariants(res: Dict[str, Any]) -> None:
     """The acceptance invariant — fail loudly on regression."""
     # the schedule has an inherent floor independent of bucket count:
-    # 2 launches natively (exchange + broadcast legs), +1 on the
-    # old-jax emulation (rank-derivation scatter, compat.py); a layout
-    # with fewer buckets than the floor cannot go below it
-    floor = 2 if compat.NATIVE_MANUAL_COLLECTIVES else 3
+    # 2 launches (exchange + broadcast legs); a layout with fewer
+    # buckets than the floor cannot go below it
+    floor = 2
     bound = max(res["_layout"]["collective_bound"], floor)
     for name in ("bucketed", "bucketed_int8"):
         c = res[name]["collectives"]
@@ -220,7 +214,7 @@ def check_invariants(res: Dict[str, Any]) -> None:
 
 def main(quick: bool = False, out: str = "BENCH_reduce.json",
          bucket_mb: float = 0.25) -> Dict[str, Any]:
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     pods = 2
     if quick:
         tree = synthetic_grad_tree(num_leaves=12, scale=24)

@@ -86,6 +86,9 @@ def _run_quick_test_tier() -> float:
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(repo, "src") +
                          os.pathsep + env.get("PYTHONPATH", ""))
+    # a CPU tier: this process already holds JAX's backend, and a child
+    # that reached for an accelerator would contend for it
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.time()
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-q", "-m", "not slow",

@@ -65,10 +65,10 @@ import numpy as np
 
 jax.config.update("jax_platform_name", "cpu")
 
-from repro import compat
 from repro.configs import base as cfgbase
 from repro.launch import serve as serve_mod
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.models.kvcache import PagedLayout
 from repro.models.model import build_model
 from repro.serve import Request
@@ -167,7 +167,7 @@ def _static_baseline(reqs: Sequence[Request], slots: int,
 
 def _run_engine(model, params, mesh, layout, slots, prefill_batch,
                 speeds, reqs):
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         eng = serve_mod.build_engine(model, params, mesh, layout,
                                      slots, prefill_batch, speeds)
         return eng.run(reqs)
@@ -177,7 +177,7 @@ def main(quick: bool = False, out: str = "BENCH_serve.json",
          seed: int = 0) -> Dict:
     t_all = time.time()
     cfg, model = _tiny_model()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = steps_mod.init_params_sharded(model, mesh,
                                            jax.random.PRNGKey(seed))
     failures: List[str] = []
@@ -254,7 +254,7 @@ def main(quick: bool = False, out: str = "BENCH_serve.json",
                       [Request(rid=0, prompt=prompt,
                                max_new_tokens=gen, arrival=0.0)])
     paged_toks = res.tokens[0]
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         ref = serve_mod.static_generate(
             model, params, mesh,
             np.asarray([prompt], np.int32), gen)

@@ -33,6 +33,7 @@ from repro.data.loader import PrefetchLoader
 from repro.data.sampler import HetSampler
 from repro.data.synthetic import build_synthetic_corpus
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import batch_specs, named
 from repro.models.model import build_model
 
@@ -61,7 +62,7 @@ def main():
 
     n_dev = len(jax.devices())
     dp = min(n_dev, 4)
-    mesh = jax.make_mesh((dp, 1), ("data", "model"))
+    mesh = make_mesh((dp, 1), ("data", "model"))
     print(f"[example] mesh: data={dp} (heterogeneous 'nodes')")
 
     # unequal node capacities, paper-style (fast, fast, slow, slower)

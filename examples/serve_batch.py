@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import base as cfgbase
 from repro.configs.base import ShapeConfig
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 
 
@@ -39,7 +40,7 @@ def main():
     n_dev = len(jax.devices())
     data = 2 if n_dev >= 4 else 1
     mdl = 2 if n_dev >= 4 else 1
-    mesh = jax.make_mesh((data, mdl), ("data", "model"))
+    mesh = make_mesh((data, mdl), ("data", "model"))
     max_len = args.prompt_len + args.gen
     shape = ShapeConfig("serve", max_len, args.batch, "decode")
 

@@ -1,48 +1,20 @@
-"""Version compatibility for the jax public API surface we use.
+"""Backend compatibility: where Pallas kernels cannot be compiled.
 
-The codebase is written against the current jax API (``jax.shard_map``
-with ``axis_names=``, ``jax.set_mesh``, keyword ``AbstractMesh``).
-Older jaxlibs (0.4.x, as baked into some containers) expose the same
-functionality under ``jax.experimental.shard_map`` with an ``auto=``
-complement set and context-manager meshes. Routing every call through
-this module keeps the rest of the tree version-agnostic.
-
-Nothing here changes semantics: ``shard_map(axis_names=S)`` always
-means "axes in S are manual, every other mesh axis stays automatic".
-
-Manual collectives: old jaxlib's SPMD partitioner aborts (hard C++
-check-fail, not a catchable error) on ``all_gather`` / ``all_to_all``
-inside a *partially*-manual region (manual subset of axes, the rest
-auto). ``manual_all_gather`` / ``manual_all_to_all`` below route to the
-native primitives on current jax and fall back to a psum-based
-emulation otherwise: mask-into-zeros + psum is mathematically an
-all-gather, and gather-then-select is an all-to-all. The emulation
-keeps the collective *count* identical (one psum per call) but moves
-full-buffer bytes; the analytic byte models in core/buckets.py describe
-the native schedule, which is what runs on real multi-host deployments.
+Kernels selected via ``attention_impl="pallas"`` /
+``quantize_impl="pallas"`` compile on a TPU/GPU backend. XLA's CPU
+backend only runs Pallas in interpret mode, so on CPU those paths run
+interpreted — same numerics, no fused-kernel performance.
 """
 from __future__ import annotations
 
 import logging
-from typing import Any, Optional, Sequence, Set, Tuple, Union
+from typing import Set
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh
 
 logger = logging.getLogger(__name__)
 
-AxisNames = Union[str, Tuple[str, ...]]
-
-# native all_gather/all_to_all inside partially-manual shard_map regions
-# only work on the current-API jax (see module docstring)
-NATIVE_MANUAL_COLLECTIVES = hasattr(jax, "shard_map")
-
-# Backends whose Pallas pipeline can *compile* pallas_call. The CPU
-# backend on the compat jaxlib raises at lowering time ("Only interpret
-# mode is supported on CPU backend"), so kernels selected via
-# ``attention_impl="pallas"`` / ``quantize_impl="pallas"`` must run in
-# interpret mode there — same numerics, no fused-kernel perf.
+# Backends whose Pallas pipeline can *compile* pallas_call.
 PALLAS_COMPILED_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
 
 _warned_pallas_fallbacks: Set[str] = set()
@@ -69,177 +41,3 @@ def pallas_interpret_fallback(what: str) -> bool:
             "kernel performance lost). Deploy on a TPU/GPU backend for "
             "the compiled kernel.", what, jax.default_backend())
     return True
-
-# Sharding-invariant RNG: current jax defaults this on; old versions
-# default off, making jax.random values depend on the OUTPUT SHARDING
-# of the jitted computation. The M8 invariant (one global key IS the
-# broadcast — identical init on every mesh, and identical across
-# reduction modes whose param specs differ) requires it.
-try:
-    jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:                                 # future removal
-    pass
-
-
-def _ambient_mesh() -> Mesh:
-    """The mesh installed by ``set_mesh`` (old-API fallback path)."""
-    from jax._src.mesh import thread_resources
-
-    m = thread_resources.env.physical_mesh
-    if m.empty:
-        raise RuntimeError(
-            "shard_map(mesh=None) needs an ambient mesh; wrap the call "
-            "in `with compat.set_mesh(mesh):`")
-    return m
-
-
-def shard_map(f, *, mesh: Optional[Mesh] = None, in_specs: Any,
-              out_specs: Any, axis_names: Optional[Set[str]] = None,
-              check_vma: bool = False):
-    """``jax.shard_map`` with manual ``axis_names``, on any jax version.
-
-    ``axis_names=None`` means every mesh axis is manual (the jax
-    default); ``mesh=None`` uses the ambient mesh from ``set_mesh``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    m = mesh if mesh is not None else _ambient_mesh()
-    auto = (frozenset() if axis_names is None
-            else frozenset(m.axis_names) - set(axis_names))
-    return _sm(f, mesh=m, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
-
-
-def set_mesh(mesh: Mesh):
-    """Context manager making ``mesh`` ambient (jax.set_mesh analogue).
-
-    On old jax a ``Mesh`` is itself the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``AbstractMesh`` across the keyword/positional signature change."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(tuple(axes), tuple(shape))))
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (renamed from ``TPUCompilerParams``)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def pad_trailing(x: jnp.ndarray, pad: int) -> jnp.ndarray:
-    """Zero-pad the LAST axis, safe inside manual shard_map regions.
-
-    Old partitioners check-fail on the HLO Pad op inside partially-
-    manual regions; a concat of zeros lowers cleanly and is identical.
-    No-op (and no HLO emitted) when ``pad == 0``.
-    """
-    if pad == 0:
-        return x
-    z = jnp.zeros(x.shape[:-1] + (pad,), x.dtype)
-    return jnp.concatenate([x, z], axis=-1)
-
-
-# --------------------------------------------------------------------------
-# manual-region collectives (inside shard_map)
-# --------------------------------------------------------------------------
-
-
-def manual_axis_onehot(axis: AxisNames, axis_size: int,
-                       tie: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """(axis_size,) f32 one-hot of this rank's linearized position.
-
-    The linearization is *defined by psum_scatter's scatter order* —
-    derived by reduce-scattering an identity matrix — so entry ``i`` of
-    a ``psum_scatter`` over ``axis`` lands on the rank whose one-hot is
-    ``e_i``. This self-consistency is what the bucketed reduction's
-    owner-shard bookkeeping relies on; it also sidesteps
-    ``axis_index``'s unsupported PartitionId lowering inside
-    partially-manual regions on old jaxlibs.
-
-    ``tie``: any traced array from the region's inputs. Old partitioners
-    also check-fail on collectives over *constants* in partially-manual
-    regions; adding ``0 * tie`` routes the identity through the input
-    lattice. Pass it whenever one is at hand.
-
-    On current jax this is collective-free (``axis_index`` lowers
-    natively, and its linearization over named axes matches
-    psum_scatter's scatter order); the identity reduce-scatter only
-    runs on the old-jax emulation path where ``axis_index`` cannot
-    lower.
-    """
-    if NATIVE_MANUAL_COLLECTIVES:
-        idx = jax.lax.axis_index(axis)
-        return jax.nn.one_hot(idx, axis_size, dtype=jnp.float32)
-    eye = jnp.eye(axis_size, dtype=jnp.float32)
-    if tie is not None:
-        eye = eye + jnp.zeros((), jnp.float32) * \
-            tie.reshape(-1)[0].astype(jnp.float32)
-    return jax.lax.psum_scatter(eye, axis, scatter_dimension=0,
-                                tiled=False) / axis_size
-
-
-def manual_axis_index(axis: AxisNames, axis_size: int,
-                      tie: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Linearized (scatter-ordered) rank index over the manual axes."""
-    return jnp.argmax(
-        manual_axis_onehot(axis, axis_size, tie)).astype(jnp.int32)
-
-
-def manual_all_gather(x: jnp.ndarray, axis: AxisNames, axis_size: int,
-                      onehot: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """all_gather inside a (partially-)manual region -> (axis_size, *x).
-
-    Stacks every rank's ``x`` along a new leading axis in psum_scatter
-    rank order (the ``tiled=False`` all_gather layout). ``axis_size``
-    must be the static total size of ``axis``. ``onehot``: pass a
-    precomputed ``manual_axis_onehot`` to share the rank-derivation
-    scatter between several emulated collectives.
-    """
-    if NATIVE_MANUAL_COLLECTIVES:
-        return jax.lax.all_gather(x, axis, axis=0, tiled=False)
-    # emulation: mask the local shard into its slot, then psum
-    if onehot is None:
-        onehot = manual_axis_onehot(axis, axis_size, tie=x)
-    mask = onehot.reshape((axis_size,) + (1,) * x.ndim)
-    wide = jnp.float32 if x.dtype == jnp.int8 else x.dtype
-    out = jax.lax.psum(mask * x[None].astype(wide), axis)
-    return out.astype(x.dtype)
-
-
-def manual_all_to_all(x: jnp.ndarray, axis: AxisNames, axis_size: int,
-                      onehot: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """all_to_all over the leading dim inside a manual region.
-
-    ``x`` has shape (axis_size, ...): row j is this rank's message for
-    rank j. Returns (axis_size, ...): row j is rank j's message for
-    this rank.
-    """
-    if NATIVE_MANUAL_COLLECTIVES and isinstance(axis, str):
-        return jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
-                                  tiled=True)
-    if onehot is None:
-        onehot = manual_axis_onehot(axis, axis_size, tie=x)
-    gathered = manual_all_gather(x, axis, axis_size, onehot)  # (P, P, ...)
-    mask = onehot.reshape((1, axis_size) + (1,) * (x.ndim - 1))
-    wide = jnp.float32 if x.dtype == jnp.int8 else x.dtype
-    out = jnp.sum(mask * gathered.astype(wide), axis=1)
-    return out.astype(x.dtype)

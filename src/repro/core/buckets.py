@@ -54,11 +54,8 @@ reduced bucket as it lands (the train step fuses the per-bucket AdamW
 update there — see optim/adam.py::apply_update_flat). The pipeline
 costs 2 collectives *per bucket* instead of 2 total — the latency/
 overlap trade a heterogeneous DCN link wants once buckets are sized to
-hide the launch overhead. On current jax the pipeline is a
-``lax.scan``; the old-jaxlib SPMD partitioner check-fails on
-collectives inside a scan in a partially-manual region, so the compat
-path unrolls the identical body in python (same dependency structure,
-nb-times-larger HLO).
+hide the launch overhead. The pipeline is a ``lax.scan`` over
+buckets.
 
 Checkpoint portability: the packed layout is a pure function of
 (param tree, bucket_mb, reduction ranks, block size), so
@@ -82,14 +79,27 @@ import dataclasses
 import hashlib
 import json
 import math
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import compression
 from repro.kernels.quantize import ops as q_ops
+
+AxisNames = Union[str, Tuple[str, ...]]
+
+
+def rank_onehot(axis: AxisNames, axis_size: int) -> jnp.ndarray:
+    """(axis_size,) f32 one-hot of this rank's position over ``axis``.
+
+    Call inside a region manual over ``axis``. ``axis_index``
+    linearizes named axes in ``psum_scatter``'s scatter order, so entry
+    ``i`` of a reduce-scatter lands on the rank whose one-hot is
+    ``e_i`` — the owner-shard bookkeeping relies on that.
+    """
+    return jax.nn.one_hot(jax.lax.axis_index(axis), axis_size,
+                          dtype=jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,7 +285,7 @@ def pack_buckets(tree: Any, layout: BucketLayout) -> jnp.ndarray:
         raise ValueError(
             f"tree holds {flat.shape[0]} elements, layout expects "
             f"{layout.total}")
-    flat = compat.pad_trailing(flat, layout.padded_total - layout.total)
+    flat = jnp.pad(flat, (0, layout.padded_total - layout.total))
     return flat.reshape(layout.num_buckets, layout.bucket_elems)
 
 
@@ -347,7 +357,7 @@ def exchange_buckets(
     buckets: jnp.ndarray,
     err: Optional[jnp.ndarray] = None,
     *,
-    axis: compat.AxisNames,
+    axis: AxisNames,
     axis_size: int,
     compress: bool = False,
     block_size: int = 256,
@@ -387,9 +397,7 @@ def exchange_buckets(
     if not compress:
         sh = jax.lax.psum_scatter(x, axis, scatter_dimension=1,
                                   tiled=False)              # (nb, shard)
-        onehot = (None if compat.NATIVE_MANUAL_COLLECTIVES
-                  else compat.manual_axis_onehot(axis, p, tie=buckets))
-        full = compat.manual_all_gather(sh, axis, p, onehot)
+        full = jax.lax.all_gather(sh, axis)
         return jnp.moveaxis(full, 0, 1).reshape(nb, be), err
 
     if shard % block_size:
@@ -400,9 +408,7 @@ def exchange_buckets(
 
     want_err = err is not None
     corrected = x + (err.reshape(nb, p, shard) if want_err else 0.0)
-    # collective-free on native jax (axis_index); one tiny identity
-    # scatter on the emulated stack
-    onehot = compat.manual_axis_onehot(axis, p, tie=buckets)
+    onehot = rank_onehot(axis, p)
     if key is not None:
         # decorrelate stochastic rounding across ranks
         key = jax.random.fold_in(key, jnp.argmax(onehot).astype(jnp.int32))
@@ -450,7 +456,7 @@ def exchange_buckets(
         q.reshape(nb, p, ns, block_size), s.reshape(nb, p, ns))
     # rank-major leading axis for the exchange: row j = message to rank j
     wire = jnp.moveaxis(payload, 1, 0)       # (p, nb, ns, block+4)
-    rx = compat.manual_all_to_all(wire, axis, p, onehot)  # row j = from j
+    rx = jax.lax.all_to_all(wire, axis, 0, 0, tiled=True)  # row j = from j
     q_x, s_x = compression.split_payload(rx, block_size)
 
     # fused dequant-accumulate over the peer axis (receive side)
@@ -470,7 +476,7 @@ def exchange_buckets(
 
     payload2 = compression.fuse_payload(
         q2.reshape(nb, ns, block_size), s2.reshape(nb, ns))
-    g2 = compat.manual_all_gather(payload2, axis, p, onehot)
+    g2 = jax.lax.all_gather(payload2, axis)
     qg, sg = compression.split_payload(g2, block_size)
     full = qg.astype(jnp.float32) * sg[..., None]      # (p, nb, ns, B)
     full = jnp.moveaxis(full, 0, 1).reshape(nb, be)
@@ -519,7 +525,7 @@ def exchange_prepared_bucket(
     payload: Any,
     resid1: Optional[jnp.ndarray],
     *,
-    axis: compat.AxisNames,
+    axis: AxisNames,
     axis_size: int,
     compress: bool,
     block_size: int,
@@ -543,11 +549,11 @@ def exchange_prepared_bucket(
     if not compress:
         sh = jax.lax.psum_scatter(payload, axis, scatter_dimension=0,
                                   tiled=False)             # (shard,)
-        full = compat.manual_all_gather(sh, axis, p, onehot)
+        full = jax.lax.all_gather(sh, axis)
         return full.reshape(-1), None
 
     ns = payload.shape[1]
-    rx = compat.manual_all_to_all(payload, axis, p, onehot)
+    rx = jax.lax.all_to_all(payload, axis, 0, 0, tiled=True)
     q_x, s_x = compression.split_payload(rx, block_size)
     shard_sum = q_ops.dequant_accum(
         q_x.reshape(p, ns, block_size), s_x.reshape(p, ns),
@@ -561,7 +567,7 @@ def exchange_prepared_bucket(
         new_err = resid1 + resid2[None, :] * onehot[:, None]
     payload2 = compression.fuse_payload(
         q2.reshape(ns, block_size), s2)
-    g2 = compat.manual_all_gather(payload2, axis, p, onehot)
+    g2 = jax.lax.all_gather(payload2, axis)
     qg, sg = compression.split_payload(g2, block_size)
     full = qg.astype(jnp.float32) * sg[..., None]          # (p, ns, B)
     return full.reshape(-1), new_err
@@ -590,10 +596,7 @@ def run_overlapped_pipeline(
     moment it lands (default: passthrough). The last bucket exchanges
     in an epilogue so no dead prepare is ever issued.
 
-    On current jax the steady state is a ``lax.scan``; the old-jaxlib
-    SPMD partitioner check-fails on collectives inside a scan in a
-    partially-manual region, so the compat path unrolls the identical
-    body in python (same dependency structure, nb-times-larger HLO).
+    The steady state is a ``lax.scan`` over buckets 0..nb-2.
 
     Returns (stacked bucket_fn outputs, stacked new error slices or
     None, final bucket_fn carry).
@@ -625,7 +628,7 @@ def run_overlapped_pipeline(
 
     carry = (prep(0, raw[0], err[0] if want_err else None), fn_carry)
     outs_h = nerrs_h = None
-    if nb > 1 and compat.NATIVE_MANUAL_COLLECTIVES:
+    if nb > 1:
         xs = (jnp.arange(nb - 1), raw[1:],
               err[1:] if want_err else jnp.zeros((nb - 1,), jnp.float32),
               jax.tree.map(lambda a: a[:nb - 1], bucket_xs)
@@ -636,15 +639,6 @@ def run_overlapped_pipeline(
                                   s[2] if want_err else None,
                                   s[3] if bucket_xs is not None else None)),
             carry, xs)
-    elif nb > 1:
-        head_list = []
-        for k in range(nb - 1):
-            carry, head_k = body(
-                carry, (k, raw[k + 1],
-                        err[k + 1] if want_err else None, bx_at(k)))
-            head_list.append(head_k)
-        outs_h, nerrs_h = jax.tree.map(lambda *ls: jnp.stack(ls),
-                                       *head_list)
     prepared, fc = carry
     fc, out_last, nerr_last = exch_one(prepared, fc, bx_at(nb - 1),
                                        nb - 1)
@@ -662,7 +656,7 @@ def exchange_buckets_overlapped(
     buckets: jnp.ndarray,
     err: Optional[jnp.ndarray] = None,
     *,
-    axis: compat.AxisNames,
+    axis: AxisNames,
     axis_size: int,
     compress: bool = False,
     block_size: int = 256,
@@ -712,7 +706,7 @@ def exchange_buckets_overlapped(
     x = buckets.reshape(nb, p, shard)
     want_err = compress and err is not None
     e = err.reshape(nb, p, shard) if want_err else None
-    onehot = compat.manual_axis_onehot(axis, p, tie=buckets)
+    onehot = rank_onehot(axis, p)
 
     def prep(k, raw_k, err_k):
         bkey = (jax.random.fold_in(key, k) if (compress and key is not None)
@@ -895,9 +889,7 @@ def modeled_link_bytes(layout: BucketLayout, ranks: int, *,
     ragged exchange never transmits (and ``exchange_buckets`` skips
     quantizing), so bucketed int8 never models more bytes than the
     per-leaf int8 walk (sum of per-leaf block counts >= the stream's
-    block count). This models the *native* schedule; the psum-based
-    CPU emulation in compat.py moves more bytes but issues the same
-    number of collectives.
+    block count).
     """
     p = ranks
     n = layout.padded_total

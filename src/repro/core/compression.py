@@ -78,38 +78,20 @@ def fuse_payload(q: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
     """Fuse int8 values + f32 scales into ONE wire buffer per block.
 
     ``q``: (..., blocks, block_size) int8, ``s``: (..., blocks) f32.
-    On current jax this is an int8 buffer of block_size + 4 bytes per
-    block — the scale bit-cast into 4 trailing bytes — so a compressed
-    exchange is a single collective instead of one for values + one for
-    scales. On old jaxlibs ``bitcast_convert_type`` is broken inside
-    partially-manual regions AND the emulated collectives move f32
-    anyway (compat.py), so the fused buffer is f32 with one trailing
-    scale lane: identical collective structure and numerics, without
-    the bit-packing.
+    The result is an int8 buffer of block_size + 4 bytes per block —
+    the scale bit-cast into 4 trailing bytes — so a compressed exchange
+    is a single collective instead of one for values + one for scales.
     """
-    from repro import compat
-
-    if compat.NATIVE_MANUAL_COLLECTIVES:
-        s_bytes = jax.lax.bitcast_convert_type(s, jnp.int8)
-        return jnp.concatenate([q, s_bytes], axis=-1)
-    return jnp.concatenate([q.astype(jnp.float32), s[..., None]], axis=-1)
+    s_bytes = jax.lax.bitcast_convert_type(s, jnp.int8)
+    return jnp.concatenate([q, s_bytes], axis=-1)
 
 
 def split_payload(payload: jnp.ndarray, block_size: int
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Inverse of :func:`fuse_payload`: -> (q int8, s f32).
-
-    Dispatches on the payload dtype (int8 = bit-packed, f32 = fused
-    lanes); int8 code values are exact in f32, so the round trip is
-    lossless either way.
-    """
-    if payload.dtype == jnp.int8:
-        q = payload[..., :block_size]
-        s = jax.lax.bitcast_convert_type(payload[..., block_size:],
-                                         jnp.float32)
-        return q, s
-    q = payload[..., :block_size].astype(jnp.int8)
-    s = payload[..., block_size]
+    """Inverse of :func:`fuse_payload`: -> (q int8, s f32), lossless."""
+    q = payload[..., :block_size]
+    s = jax.lax.bitcast_convert_type(payload[..., block_size:],
+                                     jnp.float32)
     return q, s
 
 

@@ -40,8 +40,7 @@ in-pod legs left to XLA's automatic ("data"-FSDP) partitioning — its
 ``HetConfig.overlap`` path therefore pipelines the flat engine
 (core/buckets.py) over the pod axis rather than calling the 3-level
 functions here (wiring the fully-manual 3-level pipeline into the step
-is an open ROADMAP item: grad-of-scan cannot lower inside partially-
-manual regions on the compat jaxlib).
+is an open ROADMAP item).
 """
 from __future__ import annotations
 
@@ -50,7 +49,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import buckets as bkt
 from repro.kernels.quantize import ops as q_ops
 from repro.kernels.quantize import ref as q_ref
@@ -58,7 +56,7 @@ from repro.kernels.quantize import ref as q_ref
 
 def _pad_to(x: jnp.ndarray, mult: int) -> jnp.ndarray:
     flat = x.reshape(-1)
-    return compat.pad_trailing(flat, (-flat.shape[0]) % mult)
+    return jnp.pad(flat, (0, (-flat.shape[0]) % mult))
 
 
 def hierarchical_reduce_leaf(
@@ -92,16 +90,15 @@ def hierarchical_reduce_leaf(
         new_err = corrected - deq_local
         # int8 payload + per-block scales cross the DCN link; the sum
         # is rebuilt from the per-pod (values, scales) pairs
-        q_all = compat.manual_all_gather(q, pod_axis, pod_size)
-        s_all = compat.manual_all_gather(s, pod_axis, pod_size)
+        q_all = jax.lax.all_gather(q, pod_axis)
+        s_all = jax.lax.all_gather(s, pod_axis)
         shard = jnp.einsum("pbk,pb->bk", q_all.astype(jnp.float32),
                            s_all).reshape(-1)[:shard.shape[0]]
     else:
         new_err = err
         shard = jax.lax.psum(shard, pod_axis)
     # 3) in-pod all-gather over ICI to rebuild the full leaf
-    full = compat.manual_all_gather(shard, data_axis,
-                                    data_size).reshape(-1)
+    full = jax.lax.all_gather(shard, data_axis).reshape(-1)
     n = 1
     for d in shape:
         n *= d
@@ -183,7 +180,7 @@ def hierarchical_reduce_bucketed(
         shard, err, axis=pod_axis, axis_size=pod_size,
         compress=compress, block_size=block_size, key=key, impl=impl)
     # 3) in-pod all-gather (ICI): rebuild the full stack
-    full = compat.manual_all_gather(red, data_axis, data_size)
+    full = jax.lax.all_gather(red, data_axis)
     flat = jnp.moveaxis(full, 0, 1).reshape(nb, be)
     return bkt.unpack_buckets(flat, layout), new_err
 
@@ -229,7 +226,7 @@ def hierarchical_reduce_bucketed_overlapped(
             f"multiple_of={data_size * pod_size * block_size}")
     want_err = compress and err is not None
     e = err.reshape(nb, pod_size, shard // pod_size) if want_err else None
-    onehot = compat.manual_axis_onehot(pod_axis, pod_size, tie=flat)
+    onehot = bkt.rank_onehot(pod_axis, pod_size)
 
     def prep(k, raw_k, err_k):
         # in-pod reduce-scatter (ICI) for bucket k, then the cross-pod
@@ -254,7 +251,7 @@ def hierarchical_reduce_bucketed_overlapped(
             compress=compress, block_size=block_size, impl=impl,
             interpret=False, onehot=onehot)             # (shard,)
         # in-pod all-gather (ICI) rebuilds bucket k as it lands
-        full = compat.manual_all_gather(red_k, data_axis, data_size)
+        full = jax.lax.all_gather(red_k, data_axis)
         return full.reshape(be), nerr_k
 
     # shared driver: bucket k+1's ICI reduce-scatter + quantize (prep)

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1e30
 
 
@@ -134,7 +132,7 @@ def cross_entropy_pallas(
             pltpu.VMEM((block_t, 1), jnp.float32),    # true logit
             pltpu.VMEM((block_t, 1), jnp.float32),    # sum logits (smoothing)
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(hidden, lm_head.astype(hidden.dtype), labels[:, None].astype(jnp.int32))

@@ -65,7 +65,7 @@ def flash_decode_paged(
         in HBM (NULL blocks fill with zeros) and run ``ref.mha_dense``.
       * "pallas" — in-kernel block gather: the block-table lookup drives
         the kernel's DMA index_map, so no window is ever materialized.
-        fp32-bitwise vs the reference path.
+        Within compute-dtype tolerance of the reference path.
 
     ``kv_lens`` are effective context lengths: positions >= kv_lens[i]
     are masked, so callers attending to a just-written token pass

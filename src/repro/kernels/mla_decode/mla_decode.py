@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1e30
 
 
@@ -108,7 +106,7 @@ def mla_decode_pallas(q_abs, q_r, ckv, kr, kv_len, scale,
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q_abs, q_r, ckv, kr, kv_len.reshape(b, 1).astype(jnp.int32))
@@ -218,7 +216,7 @@ def mla_decode_paged_pallas(q_abs, q_r, ckv_pool, kr_pool, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, r), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),

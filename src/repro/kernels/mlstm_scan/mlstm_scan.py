@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_BIG = -1e30
 
 
@@ -174,7 +172,7 @@ def mlstm_scan_pallas(
             pltpu.VMEM((block_h, dk), jnp.float32),
             pltpu.VMEM((block_h, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt, it, ft)

@@ -31,8 +31,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _quant_kernel(x_ref, noise_ref, q_ref, s_ref, *, stochastic: bool):
@@ -85,7 +84,7 @@ def quantize_int8_pallas(
             jax.ShapeDtypeStruct((nb_p, block_size), jnp.int8),
             jax.ShapeDtypeStruct((nb_p, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(blocks, noise)
@@ -133,7 +132,7 @@ def dequant_accum_pallas(
         ],
         out_specs=pl.BlockSpec((rows, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb_p, block), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, s[..., None].astype(jnp.float32))

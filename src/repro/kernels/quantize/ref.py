@@ -19,12 +19,10 @@ def quantize_int8(
     key: Optional[jax.Array] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(flat) f32 -> (int8 values, f32 per-block scales)."""
-    from repro import compat
-
     flat = x.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
     padded = -(-n // block_size) * block_size
-    flat = compat.pad_trailing(flat, padded - n)
+    flat = jnp.pad(flat, (0, padded - n))
     blocks = flat.reshape(-1, block_size)
     scale = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True) / 127.0
     scale = jnp.maximum(scale, 1e-12)

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref,
                 y_ref, fin_ref, state_ref, *,
@@ -161,7 +159,7 @@ def ssd_scan_pallas(
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_h, p, n), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xt, dtt, A.astype(jnp.float32)[:, None], Bt, Ct,

@@ -35,8 +35,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def _cell_id(arch: str, shape: str, mesh_kind: str) -> str:
     return f"{arch}__{shape}__{mesh_kind}"
@@ -73,7 +71,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         "het_mode": het_mode, "compression": compression,
         "accum": accum if shape.kind == "train" else 1,
     }
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tcfg = TrainConfig(
                 model=cfg, shape=shape,

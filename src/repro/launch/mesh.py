@@ -11,10 +11,31 @@ earn their keep.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
+    """The one mesh constructor: every axis ``Auto``-sharded.
+
+    The step builders place arrays with ``NamedSharding`` +
+    ``with_sharding_constraint`` and let XLA propagate the rest, which
+    is what ``Auto`` axes mean. (``jax.make_mesh`` defaults to
+    ``Explicit`` axes, which turn on sharding-in-types and reject that
+    style.) ``devices``: an explicit device list, e.g.
+    ``jax.devices()[:n]`` for a sub-mesh; ``None`` lets jax order all
+    devices for the physical topology.
+    """
+    shape, axes = tuple(shape), tuple(axes)
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    devs = np.asarray(list(devices)).reshape(shape)
+    return Mesh(devs, axes, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False, pipe: int = 1) -> Mesh:
@@ -23,14 +44,14 @@ def make_production_mesh(*, multi_pod: bool = False, pipe: int = 1) -> Mesh:
     if pipe > 1:
         shape = (pipe,) + shape
         axes = ("pipe",) + axes
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1, pipe: int = 1) -> Mesh:
     """Small mesh over however many (host) devices exist — for tests."""
     if pipe > 1:
-        return jax.make_mesh((pipe, data, model), ("pipe", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pipe, data, model), ("pipe", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -29,12 +29,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import base as cfgbase
 from repro.configs.base import ShapeConfig
 from repro.launch import sharding as shr
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import dp_size
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import dp_size, make_mesh
 from repro.models.kvcache import PagedLayout
 from repro.models.model import Model, build_model
 from repro.serve import (CapacityRouter, EngineConfig, Request, Scheduler,
@@ -51,7 +51,7 @@ def build_engine(model: Model, params, mesh, layout: PagedLayout,
     Compiles one decode step (fixed (slots,) shapes, cache donated) and
     one prefill step per length bucket (fixed (prefill_batch, bucket)
     shapes, cache donated). Call — and run the engine — inside
-    ``compat.set_mesh(mesh)``.
+    ``jax.set_mesh(mesh)``.
     """
     router = CapacityRouter(slots, pod_speeds)
     sched = Scheduler(layout, router, slots, bucket_lens)
@@ -114,6 +114,17 @@ def static_generate(model: Model, params, mesh, prompts: np.ndarray,
     return np.stack(out, axis=1)
 
 
+def paged_layout(args) -> PagedLayout:
+    """The KV pool the CLI arguments ask for: blocks for the longest
+    prompt + generation per slot, ``--num-blocks`` (0 = slots x that)
+    in the pool."""
+    max_seq = args.max_prompt + args.max_gen
+    mbs = -(-max_seq // args.block_size)
+    num_blocks = args.num_blocks or args.slots * mbs
+    return PagedLayout(block_size=args.block_size,
+                       num_blocks=num_blocks, max_blocks_per_seq=mbs)
+
+
 def serve(args):
     cfg = (cfgbase.smoke_config(args.arch) if args.smoke
            else cfgbase.resolve(args.arch))
@@ -125,16 +136,11 @@ def serve(args):
     dshape = tuple(int(x) for x in args.devices.split(","))
     axes = ("data", "model") if len(dshape) == 2 else ("pod", "data",
                                                        "model")
-    mesh = jax.make_mesh(dshape, axes)
+    mesh = make_mesh(dshape, axes)
     pod_speeds = ([float(s) for s in args.pod_speeds.split(",")]
                   if args.pod_speeds else [1.0] * dp_size(mesh))
 
-    max_seq = args.max_prompt + args.max_gen
-    mbs = -(-max_seq // args.block_size)
-    num_blocks = args.num_blocks or args.slots * mbs
-    layout = PagedLayout(block_size=args.block_size,
-                         num_blocks=num_blocks, max_blocks_per_seq=mbs)
-
+    layout = paged_layout(args)
     params = steps_mod.init_params_sharded(model, mesh,
                                            jax.random.PRNGKey(args.seed))
     reqs = synthetic_requests(
@@ -142,7 +148,7 @@ def serve(args):
         (args.min_prompt, args.max_prompt), (args.min_gen, args.max_gen),
         args.seed)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         engine = build_engine(model, params, mesh, layout, args.slots,
                               args.prefill_batch, pod_speeds)
         result = engine.run(reqs)
@@ -166,7 +172,7 @@ def serve(args):
     return result
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true")
@@ -194,7 +200,12 @@ def main():
                          "blocks through the block table inside the "
                          "kernel (interpret-mode fallback, loudly, off "
                          "TPU); 'reference' materializes the window")
-    serve(ap.parse_args())
+    return ap.parse_args(argv)
+
+
+def main():
+    use_compile_cache()
+    serve(parse_args())
 
 
 if __name__ == "__main__":

@@ -64,7 +64,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import (ModelConfig, OptimizerConfig, ShapeConfig,
                                 TrainConfig)
 from repro.core import accumulate as acc
@@ -429,7 +428,7 @@ def init_train_state(model: Model, tcfg: TrainConfig, mesh: Mesh,
                 lambda p: jnp.zeros(p.shape, jnp.float32), shapes.err)
         return TrainState(params=params, opt=opt, err=err)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(init, out_shardings=shr.named(mesh, specs))(key)
 
 
@@ -437,7 +436,7 @@ def init_params_sharded(model: Model, mesh: Mesh, key):
     """Initialize bare params with the production shardings (serving)."""
     params_shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     pspecs = shr.param_specs(model.cfg, params_shape, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(model.init_params,
                        out_shardings=shr.named(mesh, pspecs))(key)
 
@@ -448,7 +447,7 @@ def init_cache_sharded(model: Model, shape: ShapeConfig, mesh: Mesh):
     cache_shape = jax.eval_shape(
         functools.partial(model.init_cache, b, shape.seq_len))
     cspecs = shr.cache_specs(model.cfg, cache_shape, mesh, b)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(functools.partial(model.init_cache, b,
                                          shape.seq_len),
                        out_shardings=shr.named(mesh, cspecs))()
@@ -469,7 +468,7 @@ def _quant_lastdim(x: jnp.ndarray, block: int):
     """
     last = x.shape[-1]
     bs = min(block, last)
-    x = compat.pad_trailing(x, (-last) % bs)
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, (-last) % bs)])
     nb = x.shape[-1] // bs
     blocks = x.reshape(*x.shape[:-1], nb, bs)
     scale = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True) / 127.0
@@ -514,8 +513,8 @@ def _cross_pod_reduce(grads: Any, err: Any, compress: str, pods: int,
                  if e is not None else e)
         # int8 payload + per-block scales are what cross the DCN link;
         # gathered along a NEW leading pod axis (all shardings preserved)
-        q_all = compat.manual_all_gather(q, "pod", pods)
-        s_all = compat.manual_all_gather(s, "pod", pods)
+        q_all = jax.lax.all_gather(q, "pod")
+        s_all = jax.lax.all_gather(s, "pod")
         deq = jnp.sum(q_all.astype(jnp.float32) * s_all[..., None],
                       axis=0)
         out = deq.reshape(*deq.shape[:-2], -1)[..., :last]
@@ -627,8 +626,7 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
     """The ``overlap="backward"`` train step: flush gradient buckets
     DURING backprop instead of after it.
 
-    Structure (identical on current jax and the old-jaxlib compat
-    stack): the batch is reshaped rank-major and every backward stage
+    Structure: the batch is reshaped rank-major and every backward stage
     is a vmapped per-layer VJP in plain SPMD at the TOP level of the
     jitted program (models/transformer.py staged segments — requires
     ``scan_layers=False`` so the monolithic comparison path compiles
@@ -848,29 +846,27 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
             payload, resid1 = prepared
             if compress_flag and use_err:
                 def region(pl, rs):
-                    onehot = compat.manual_axis_onehot(
-                        red_axis, ranks, tie=pl)
+                    onehot = bkt.rank_onehot(red_axis, ranks)
                     red, ne = bkt.exchange_prepared_bucket(
                         pl[0], rs[0], axis=red_axis, axis_size=ranks,
                         compress=True, block_size=_BLOCK, impl=q_impl,
                         interpret=False, onehot=onehot)
                     return red, ne[None]
 
-                return compat.shard_map(
+                return jax.shard_map(
                     region, mesh=mesh, in_specs=(buf_spec, buf_spec),
                     out_specs=(P(), buf_spec), axis_names=axis_set,
                     check_vma=False)(payload, resid1)
 
             def region(pl):
-                onehot = compat.manual_axis_onehot(
-                    red_axis, ranks, tie=pl)
+                onehot = bkt.rank_onehot(red_axis, ranks)
                 red, _ = bkt.exchange_prepared_bucket(
                     pl[0], None, axis=red_axis, axis_size=ranks,
                     compress=compress_flag, block_size=_BLOCK,
                     impl=q_impl, interpret=False, onehot=onehot)
                 return red
 
-            red = compat.shard_map(
+            red = jax.shard_map(
                 region, mesh=mesh, in_specs=buf_spec, out_specs=P(),
                 axis_names=axis_set, check_vma=False)(payload)
             return red, None
@@ -994,19 +990,14 @@ def _pipe_send(x: jnp.ndarray, mesh: Mesh, spec: P,
     pipe-replicated values, so the ring ppermute is value-preserving —
     it exists to hand the runtime the placement edge between
     consecutive stages (the activation / cotangent hop the modeled
-    timeline charges to DCN). On the compat stack (no native manual
-    collectives — old jaxlib check-fails ppermute around the staged
-    VJPs) the hop degrades to a sharding constraint; without a pipe
-    axis on the mesh it is the identity.
+    timeline charges to DCN). Without a pipe axis on the mesh it is the
+    identity.
     """
     if "pipe" not in mesh.axis_names:
         return x
-    if not compat.NATIVE_MANUAL_COLLECTIVES:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, spec))
     n = mesh.shape["pipe"]
     perm = [(i, (i + direction) % n) for i in range(n)]
-    return compat.shard_map(
+    return jax.shard_map(
         lambda v: jax.lax.ppermute(v, "pipe", perm),
         mesh=mesh, in_specs=spec, out_specs=spec,
         axis_names={"pipe"}, check_vma=False)(x)
@@ -1309,15 +1300,14 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
             payload, _ = prepared
 
             def region(pl):
-                onehot = compat.manual_axis_onehot(red_axis, ranks,
-                                                   tie=pl)
+                onehot = bkt.rank_onehot(red_axis, ranks)
                 red, _ = bkt.exchange_prepared_bucket(
                     pl[0], None, axis=red_axis, axis_size=ranks,
                     compress=False, block_size=_BLOCK, impl=q_impl,
                     interpret=False, onehot=onehot)
                 return red
 
-            red = compat.shard_map(
+            red = jax.shard_map(
                 region, mesh=mesh, in_specs=buf_spec, out_specs=P(),
                 axis_names=axis_set, check_vma=False)(payload)
             return red, None
@@ -1446,6 +1436,21 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
 # --------------------------------------------------------------------------
 
 
+def _fed_batch_specs(cfg: ModelConfig, tcfg: TrainConfig,
+                     mesh: Mesh) -> Dict[str, P]:
+    """Specs of the batch ``launch/train.py`` feeds the step.
+
+    Packed batches hold one capacity-plan buffer per DP rank
+    (``buffer_rows * dp_size`` rows), so they always shard over the DP
+    axes — even when the global batch does not divide the DP size, as
+    uneven row shares make common. Canonical batches are the
+    ``global_batch`` rows in global order.
+    """
+    rows = (tcfg.shape.global_batch if tcfg.het.weighting == "canonical"
+            else dp_size(mesh))
+    return shr.batch_specs(cfg, mesh, rows)
+
+
 def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                      ) -> Callable[[TrainState, Dict], Tuple[TrainState,
                                                              Dict]]:
@@ -1488,7 +1493,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
         pipe_step = _build_pipeline_step(model, tcfg, mesh, splan=splan,
                                          layout=layout)
         specs = state_specs(model, tcfg, mesh)
-        bspecs = shr.batch_specs(cfg, mesh, tcfg.shape.global_batch)
+        bspecs = _fed_batch_specs(cfg, tcfg, mesh)
         return jax.jit(
             pipe_step,
             in_shardings=(shr.named(mesh, specs),
@@ -1507,7 +1512,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
             compress=compress, use_err=use_err,
             fused_stream=fused_stream)
         specs = state_specs(model, tcfg, mesh)
-        bspecs = shr.batch_specs(cfg, mesh, tcfg.shape.global_batch)
+        bspecs = _fed_batch_specs(cfg, tcfg, mesh)
         return jax.jit(
             bwd_step,
             in_shardings=(shr.named(mesh, specs),
@@ -1568,23 +1573,6 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                 impl=q_impl)
             return g, (ne if ne is not None else ())
         return _cross_pod_reduce(g, err, compress, n_pods)
-
-    def vmapped_rank_grads(params, batch, ranks, rank_spec):
-        """Per-rank stacked grads computed OUTSIDE the manual region.
-
-        Old jaxlibs cannot lower grad-of-scan (the layer stack, chunked
-        CE, accumulation) inside a partially-manual shard_map region —
-        the SPMD partitioner check-fails. Fallback: reshape the batch
-        rank-major, vmap the grad over the rank dim (plain SPMD — the
-        vmap dim shards over the reduction axes), and enter the manual
-        region only for the reduction itself.
-        """
-        sb = jax.tree.map(
-            lambda v: jax.lax.with_sharding_constraint(
-                v.reshape(ranks, v.shape[0] // ranks, *v.shape[1:]),
-                rank_spec), batch)
-        g, o, w = jax.vmap(compute_grads, in_axes=(None, 0))(params, sb)
-        return g, jnp.sum(o), jnp.sum(w)
 
     # ---- fused overlap step (HetConfig.overlap="buckets") ---------------
     # The optimizer moves INSIDE the manual region: the per-bucket
@@ -1670,60 +1658,35 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                                       layout.bucket_elems)
                         if use_err else err)
 
-            if compat.NATIVE_MANUAL_COLLECTIVES:
-                pspecs_in = state_specs(model, tcfg, mesh).params
+            pspecs_in = state_specs(model, tcfg, mesh).params
 
-                def local(params, b, err, m, v, step_no, lr_in):
-                    g, o, w = compute_grads(params, b)
-                    if hier:
-                        # re-pin lost (data, model) layouts (see the
-                        # hierarchical branch below)
-                        g = jax.tree.map(
-                            lambda gr, s:
-                            jax.lax.with_sharding_constraint(gr, s),
-                            g, pspecs_in)
-                    o = jax.lax.psum(o, red_axis)
-                    w = jax.lax.psum(w, red_axis)
-                    np_, nm, nv, ne, gn, tr = fused_reduce_update(
-                        g, params, m, v, unslice_err(err), w,
-                        step_no, lr_in)
-                    return (np_, nm, nv, reslice_err(ne, err), o, w,
-                            gn, tr)
+            def local(params, b, err, m, v, step_no, lr_in):
+                g, o, w = compute_grads(params, b)
+                if hier:
+                    # re-pin lost (data, model) layouts (see the
+                    # hierarchical branch below)
+                    g = jax.tree.map(
+                        lambda gr, s:
+                        jax.lax.with_sharding_constraint(gr, s),
+                        g, pspecs_in)
+                o = jax.lax.psum(o, red_axis)
+                w = jax.lax.psum(w, red_axis)
+                np_, nm, nv, ne, gn, tr = fused_reduce_update(
+                    g, params, m, v, unslice_err(err), w,
+                    step_no, lr_in)
+                return (np_, nm, nv, reslice_err(ne, err), o, w,
+                        gn, tr)
 
-                (new_params, new_m, new_v, new_err, o, w, gnorm,
-                 trust) = compat.shard_map(
-                    local, mesh=mesh,
-                    in_specs=(P(), batch_spec, err_spec, P(), P(),
-                              P(), P()),
-                    out_specs=(P(), P(), P(), err_spec, P(), P(),
-                               P(), P()),
-                    axis_names=axes, check_vma=False,
-                )(state.params, batch, err_in, state.opt.m,
-                  state.opt.v, lr_step, lr)
-            else:
-                ranks = n_pods if hier else n_dp
-                rank_spec = P("pod", "data") if hier else P(dp)
-                g, o, w = vmapped_rank_grads(state.params, batch, ranks,
-                                             rank_spec)
-
-                def reduce_update(gl, err, params, m, v, w_sum,
-                                  step_no, lr_in):
-                    gg = jax.tree.map(lambda a: a[0], gl)
-                    np_, nm, nv, ne, gn, tr = fused_reduce_update(
-                        gg, params, m, v, unslice_err(err), w_sum,
-                        step_no, lr_in)
-                    return np_, nm, nv, reslice_err(ne, err), gn, tr
-
-                (new_params, new_m, new_v, new_err, gnorm, trust) = \
-                    compat.shard_map(
-                        reduce_update, mesh=mesh,
-                        in_specs=(P("pod") if hier else P(dp), err_spec,
-                                  P(), P(), P(), P(), P(), P()),
-                        out_specs=(P(), P(), P(), err_spec, P(), P()),
-                        axis_names=axes, check_vma=False,
-                    )(g, err_in, state.params, state.opt.m,
-                      state.opt.v, w, lr_step, lr)
-
+            (new_params, new_m, new_v, new_err, o, w, gnorm,
+             trust) = jax.shard_map(
+                local, mesh=mesh,
+                in_specs=(P(), batch_spec, err_spec, P(), P(),
+                          P(), P()),
+                out_specs=(P(), P(), P(), err_spec, P(), P(),
+                           P(), P()),
+                axis_names=axes, check_vma=False,
+            )(state.params, batch, err_in, state.opt.m,
+              state.opt.v, lr_step, lr)
             loss = weighting.finalize(o, w)
             metrics = {"loss": loss, "weight": w, "grad_norm": gnorm,
                        "lr": lr}
@@ -1768,46 +1731,31 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
         if overlap:
             return overlap_step(state, batch)
         if hier:
-            if compat.NATIVE_MANUAL_COLLECTIVES:
-                pspecs_in = state_specs(model, tcfg, mesh).params
+            pspecs_in = state_specs(model, tcfg, mesh).params
 
-                def pod_local(params, b, err):
-                    g, o, w = compute_grads(params, b)
-                    # inside the partially-manual region XLA's sharding
-                    # propagation can lose the (data, model) layout of
-                    # the gradients; re-pin them to the param specs so
-                    # the pod exchange moves shards, not replicated
-                    # leaves
-                    g = jax.tree.map(
-                        lambda gr, s: jax.lax.with_sharding_constraint(
-                            gr, s),
-                        g, pspecs_in)
-                    g, ne = apply_pod_reduce(g, err)
-                    return g, jax.lax.psum(o, "pod"), \
-                        jax.lax.psum(w, "pod"), ne
+            def pod_local(params, b, err):
+                g, o, w = compute_grads(params, b)
+                # inside the partially-manual region XLA's sharding
+                # propagation can lose the (data, model) layout of
+                # the gradients; re-pin them to the param specs so
+                # the pod exchange moves shards, not replicated
+                # leaves
+                g = jax.tree.map(
+                    lambda gr, s: jax.lax.with_sharding_constraint(
+                        gr, s),
+                    g, pspecs_in)
+                g, ne = apply_pod_reduce(g, err)
+                return g, jax.lax.psum(o, "pod"), \
+                    jax.lax.psum(w, "pod"), ne
 
-                grads, o, w, new_err = compat.shard_map(
-                    pod_local, mesh=mesh,
-                    in_specs=(P(), P("pod"), P("pod") if use_err
-                              else P()),
-                    out_specs=(P(), P(), P(), P("pod") if use_err
-                               else P()),
-                    axis_names={"pod"}, check_vma=False,
-                )(state.params, batch, state.err)
-            else:
-                g, o, w = vmapped_rank_grads(state.params, batch, n_pods,
-                                             P("pod", "data"))
-
-                def pod_reduce(gl, err):
-                    return apply_pod_reduce(
-                        jax.tree.map(lambda a: a[0], gl), err)
-
-                grads, new_err = compat.shard_map(
-                    pod_reduce, mesh=mesh,
-                    in_specs=(P("pod"), P("pod") if use_err else P()),
-                    out_specs=(P(), P("pod") if use_err else P()),
-                    axis_names={"pod"}, check_vma=False,
-                )(g, state.err)
+            grads, o, w, new_err = jax.shard_map(
+                pod_local, mesh=mesh,
+                in_specs=(P(), P("pod"), P("pod") if use_err
+                          else P()),
+                out_specs=(P(), P(), P(), P("pod") if use_err
+                           else P()),
+                axis_names={"pod"}, check_vma=False,
+            )(state.params, batch, state.err)
         elif bucketed_ar:
             axis = dp if len(dp) > 1 else dp[0]
 
@@ -1818,27 +1766,17 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                                           impl=q_impl)
                 return out
 
-            if compat.NATIVE_MANUAL_COLLECTIVES:
-                def dp_local(params, b):
-                    g, o, w = compute_grads(params, b)
-                    return reduce_buckets(g), jax.lax.psum(o, dp), \
-                        jax.lax.psum(w, dp)
+            def dp_local(params, b):
+                g, o, w = compute_grads(params, b)
+                return reduce_buckets(g), jax.lax.psum(o, dp), \
+                    jax.lax.psum(w, dp)
 
-                grads, o, w = compat.shard_map(
-                    dp_local, mesh=mesh,
-                    in_specs=(P(), P(dp)),
-                    out_specs=(P(), P(), P()),
-                    axis_names=set(dp), check_vma=False,
-                )(state.params, batch)
-            else:
-                g, o, w = vmapped_rank_grads(state.params, batch, n_dp,
-                                             P(dp))
-                grads = compat.shard_map(
-                    lambda gl: reduce_buckets(
-                        jax.tree.map(lambda a: a[0], gl)),
-                    mesh=mesh, in_specs=P(dp), out_specs=P(),
-                    axis_names=set(dp), check_vma=False,
-                )(g)
+            grads, o, w = jax.shard_map(
+                dp_local, mesh=mesh,
+                in_specs=(P(), P(dp)),
+                out_specs=(P(), P(), P()),
+                axis_names=set(dp), check_vma=False,
+            )(state.params, batch)
             new_err = state.err
         else:
             grads, o, w = compute_grads(state.params, batch)
@@ -1854,7 +1792,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
         return TrainState(params=params, opt=opt, err=new_err), metrics
 
     specs = state_specs(model, tcfg, mesh)
-    bspecs = shr.batch_specs(cfg, mesh, tcfg.shape.global_batch)
+    bspecs = _fed_batch_specs(cfg, tcfg, mesh)
     return jax.jit(
         step,
         in_shardings=(shr.named(mesh, specs), shr.named(mesh, bspecs)),

@@ -38,18 +38,16 @@ import argparse
 import dataclasses
 import os
 import time
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
 import numpy as np
 
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs import base as cfgbase
-from repro.configs.base import (HetConfig, OptimizerConfig, ShapeConfig,
-                                TrainConfig)
+from repro.configs.base import (HetConfig, ModelConfig, OptimizerConfig,
+                                ShapeConfig, TrainConfig)
 from repro.core import capacity as cap
 from repro.core import chaos, elastic
 from repro.core.straggler import RemeshRequired, StragglerMonitor
@@ -58,14 +56,18 @@ from repro.data.loader import PrefetchLoader
 from repro.data.sampler import HetSampler
 from repro.data.synthetic import build_synthetic_corpus
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import dp_axes, dp_size
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import dp_size, make_mesh
 from repro.launch.sharding import batch_specs, named
 from repro.models.model import build_model
 
 
-def build_everything(args):
-    cfg = (cfgbase.smoke_config(args.arch) if args.smoke
-           else cfgbase.resolve(args.arch))
+def build_everything(args, cfg: Optional[ModelConfig] = None):
+    """``cfg``: the model configuration; ``None`` resolves ``--arch``
+    (``--smoke`` for the reduced same-family config)."""
+    if cfg is None:
+        cfg = (cfgbase.smoke_config(args.arch) if args.smoke
+               else cfgbase.resolve(args.arch))
     if getattr(args, "no_scan_layers", False):
         # unrolled layer stack — required by --overlap backward (the
         # staged layer-by-layer backward is an unrolled program)
@@ -80,7 +82,7 @@ def build_everything(args):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n_needed}")
     axes = ("data", "model") if len(dshape) == 2 else ("pod", "data",
                                                        "model")
-    mesh = jax.make_mesh(dshape, axes)
+    mesh = make_mesh(dshape, axes)
 
     shape = ShapeConfig("cli", args.seq_len, args.global_batch, "train")
     tcfg = TrainConfig(
@@ -131,8 +133,7 @@ def mesh_for_topology(topo: elastic.MeshTopology):
     if n > len(jax.devices()):
         raise SystemExit(f"re-mesh needs {n} devices, "
                          f"have {len(jax.devices())}")
-    devs = np.asarray(jax.devices()[:n]).reshape(shape)
-    return jax.sharding.Mesh(devs, topo.mesh_axes())
+    return make_mesh(shape, topo.mesh_axes(), jax.devices()[:n])
 
 
 def _parse_kill(spec: str) -> Optional[Tuple[int, int]]:
@@ -170,8 +171,17 @@ def build_chaos_engine(args, tcfg: TrainConfig, mesh,
         raise SystemExit(f"[train] {e}") from e
 
 
-def train(args) -> Dict[str, float]:
-    cfg, model, mesh, tcfg = build_everything(args)
+def train(args, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
+    """Run the training loop. ``cfg`` overrides ``--arch`` (see
+    :func:`build_everything`).
+
+    Returns ``steps`` and ``wall_s``; once a step ran, also
+    ``first_loss``/``last_loss``, the per-step ``losses`` and wall
+    times ``step_s`` (each step waited on with ``block_until_ready``),
+    and ``batch_rows_by_device``: the rows of the first batch held by
+    each device id, i.e. where the data-parallel shards landed.
+    """
+    cfg, model, mesh, tcfg = build_everything(args, cfg)
     topo = topology_from_mesh(mesh)
     plan = make_plan(tcfg, mesh)
     print(f"[train] {cfg.name}: {cfg.param_count():,} params, mesh "
@@ -215,7 +225,7 @@ def train(args) -> Dict[str, float]:
     def build_runtime(mesh, plan):
         """Everything that depends on the mesh / plan (rebuilt on
         re-mesh)."""
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step_fn = steps_mod.build_train_step(model, tcfg, mesh)
         canonical = tcfg.het.weighting == "canonical"
         sampler = HetSampler(ds, plan, seed=tcfg.seed,
@@ -258,7 +268,7 @@ def train(args) -> Dict[str, float]:
             print(f"[train] restore: pipeline stage plan changed: "
                   f"{_pdesc(saved_pipe)} -> {_pdesc(cur_pipe)}")
         specs = steps_mod.state_specs(model, tcfg, mesh)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(host, named(mesh, specs))
         stream = meta.get("stream") or {}
         position = (int(meta["step"]),
@@ -277,7 +287,7 @@ def train(args) -> Dict[str, float]:
         print(f"[train] resumed from step {start_step} "
               f"(epoch {epoch}, batch {batch_in_epoch})")
     else:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = steps_mod.init_train_state(
                 model, tcfg, mesh, jax.random.PRNGKey(tcfg.seed))
 
@@ -293,12 +303,14 @@ def train(args) -> Dict[str, float]:
 
     step = start_step
     losses = []
+    step_s = []
+    batch_rows_by_device: Dict[int, int] = {}
     t_start = time.time()
     body_raised = False
     try:
         while step < args.steps:
             try:
-                with compat.set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     while step < args.steps:
                         consumed = 0
                         for raw in loader.iter_epoch(epoch):
@@ -318,11 +330,18 @@ def train(args) -> Dict[str, float]:
                                     raw["weights"][:, :args.seq_len]),
                             }
                             batch = jax.device_put(batch, bspecs)
+                            if not batch_rows_by_device:
+                                batch_rows_by_device = {
+                                    s.device.id: int(s.data.shape[0])
+                                    for s in batch["inputs"]
+                                    .addressable_shards}
                             t0 = time.time()
-                            state, metrics = step_fn(state, batch)
-                            loss = float(metrics["loss"])
+                            state, metrics = jax.block_until_ready(
+                                step_fn(state, batch))
                             dt = time.time() - t0
+                            loss = float(metrics["loss"])
                             losses.append(loss)
+                            step_s.append(dt)
                             step += 1
                             batch_in_epoch = consumed
                             # per-rank step times: on real fleets each
@@ -420,6 +439,7 @@ def train(args) -> Dict[str, float]:
                 # drop its loss entries so the final summary reports
                 # only steps that are part of the resumed run
                 del losses[max(step - start_step, 0):]
+                del step_s[max(step - start_step, 0):]
                 monitor = StragglerMonitor(
                     num_ranks=n_dp, ema_decay=tcfg.het.straggler_ema,
                     replan_interval=tcfg.het.replan_interval)
@@ -461,10 +481,11 @@ def train(args) -> Dict[str, float]:
     print(f"[train] done: {step - start_step} steps in {wall:.1f}s, "
           f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
     return {"steps": step, "wall_s": wall, "first_loss": losses[0],
-            "last_loss": losses[-1]}
+            "last_loss": losses[-1], "losses": losses, "step_s": step_s,
+            "batch_rows_by_device": batch_rows_by_device}
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--smoke", action="store_true",
@@ -547,8 +568,12 @@ def main():
                          "from global step S (exercises the elastic "
                          "remesh restart); alias for a one-entry "
                          "--chaos kill schedule")
-    args = ap.parse_args()
-    train(args)
+    return ap.parse_args(argv)
+
+
+def main():
+    use_compile_cache()
+    train(parse_args())
 
 
 if __name__ == "__main__":

@@ -518,7 +518,7 @@ def moe_block(params, x: jnp.ndarray, cfg: ModelConfig,
         # mesh=None -> ambient mesh: a concrete all-Auto mesh object
         # would clash with the partially-manual context inside the
         # hierarchical pod reduction (nested shard_map)
-        out, aux = compat.shard_map(
+        out, aux = jax.shard_map(
             sharded_moe, mesh=None,
             in_specs=(spec_x, P(None, None),
                       P(ctx.tp_axis, None, None), P(ctx.tp_axis, None, None),

@@ -293,9 +293,9 @@ def attention_decode_paged(params, x: jnp.ndarray, cfg: ModelConfig,
     # (NULL entries fill with zeros — bit-identical to untouched
     # contiguous cache, which keeps it bitwise equal to
     # attention_decode in fp32); the pallas path gathers blocks through
-    # the block table INSIDE the kernel (no HBM window), fp32-bitwise
-    # vs the reference, and runs interpreted with a loud warning where
-    # the backend can't compile Pallas.
+    # the block table INSIDE the kernel (no HBM window), within
+    # compute-dtype tolerance of the reference, and runs interpreted
+    # with a loud warning where the backend can't compile Pallas.
     out = attn_ops.flash_decode_paged(
         q, k_cache, v_cache, block_tables, kv_lens + 1,
         impl=cfg.attention_impl,

@@ -92,7 +92,7 @@ def test_canonical_bit_identity_across_replans():
             OptimizerConfig, ShapeConfig
         from repro.models.model import build_model
         from repro.launch import steps
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity
         from repro.data import sampler, synthetic
         from repro.data.dataset import ShardedDataset
@@ -111,12 +111,12 @@ def test_canonical_bit_identity_across_replans():
             optimizer=OptimizerConfig(lr=1e-3, warmup_steps=2))
 
         def run(plans):           # plans: one CapacityPlan per step
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             smp = sampler.HetSampler(ds, plans[0], seed=3,
                                      canonical_order=True)
             entries = smp.epoch_batches(0)
             losses, state = [], None
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = steps.init_train_state(m, tcfg, mesh,
                                                jax.random.PRNGKey(0))
                 step = steps.build_train_step(m, tcfg, mesh)

@@ -22,6 +22,7 @@ import pytest
 
 from repro.configs import base as cfgs
 from repro.configs.base import HetConfig, TrainConfig
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 README = os.path.join(REPO, "README.md")
@@ -102,8 +103,8 @@ def test_readme_matrix_rows_match_validation(readme_tables):
 
     table = _find_table(readme_tables, "grad_reduction", "overlap",
                         "status")
-    flat_mesh = jax.make_mesh((1, 1), ("data", "model"))
-    pod_mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    flat_mesh = make_mesh((1, 1), ("data", "model"))
+    pod_mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     seen = set()
     for row in table[1:]:
         reduction = row[0].strip("`")
@@ -323,7 +324,6 @@ def test_label_smoothing_is_wired_through_the_train_step():
     """TrainConfig.label_smoothing is a LIVE knob (the docstring says
     so): it must reach the CE loss both via loss_fn and via
     build_train_step."""
-    from repro import compat
     from repro.configs.base import OptimizerConfig, ShapeConfig
     from repro.launch import steps
     from repro.models.model import build_model
@@ -345,7 +345,7 @@ def test_label_smoothing_is_wired_through_the_train_step():
     o1, _, _ = model.loss_fn(params, batch, label_smoothing=0.2)
     assert float(o0) != float(o1), "label_smoothing kwarg is dead"
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("t", 16, 2, "train")
 
     def one_loss(smoothing):
@@ -353,7 +353,7 @@ def test_label_smoothing_is_wired_through_the_train_step():
                            het=HetConfig(),
                            optimizer=OptimizerConfig(grad_clip=0.0),
                            label_smoothing=smoothing)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = steps.init_train_state(model, tcfg, mesh,
                                            jax.random.PRNGKey(0))
             step = steps.build_train_step(model, tcfg, mesh)

@@ -38,7 +38,7 @@ def test_spmd_train_step_equals_single_process():
             OptimizerConfig, ShapeConfig
         from repro.models.model import build_model
         from repro.launch import steps
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity, dummy, weighting
         from repro.data import synthetic
         import dataclasses
@@ -46,7 +46,7 @@ def test_spmd_train_step_equals_single_process():
         cfg = dataclasses.replace(base.smoke_config("tinyllama-1.1b"),
                                   compute_dtype="float32")
         m = build_model(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeConfig("t", 16, 8, "train")
         tcfg = TrainConfig(model=cfg, shape=shape,
                            het=HetConfig(accum_steps=1),
@@ -58,7 +58,7 @@ def test_spmd_train_step_equals_single_process():
         packed = dummy.pack_global_batch(
             {"inputs": rec["inputs"][:, :16],
              "labels": rec["labels"][:, :16]}, plan)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = steps.init_train_state(m, tcfg, mesh,
                                            jax.random.PRNGKey(0))
             step = steps.build_train_step(m, tcfg, mesh)
@@ -93,14 +93,14 @@ def test_reduction_modes_agree():
             OptimizerConfig, ShapeConfig
         from repro.models.model import build_model
         from repro.launch import steps
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity, dummy
         from repro.data import synthetic
 
         cfg = dataclasses.replace(base.smoke_config("olmo-1b"),
                                   compute_dtype="float32")
         m = build_model(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeConfig("t", 16, 8, "train")
         rec = synthetic.make_lm_records(8, 17, cfg.vocab_size, seed=5)
         plan = capacity.plan_capacities(8, [1, 1, 1, 1])
@@ -115,7 +115,7 @@ def test_reduction_modes_agree():
                                              bucket_mb=bucket_mb),
                                optimizer=OptimizerConfig(
                                    lr=1e-3, warmup_steps=2))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = steps.init_train_state(m, tcfg, mesh,
                                                jax.random.PRNGKey(0))
                 step = steps.build_train_step(m, tcfg, mesh)
@@ -155,11 +155,11 @@ def test_bucketed_exchange_matches_per_leaf_psum():
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import buckets as bkt
         from repro.core import hierarchical as hier
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         pods = 2
         k = jax.random.PRNGKey(0)
         tree = {"w": jax.random.normal(k, (67, 33)),
@@ -182,7 +182,7 @@ def test_bucketed_exchange_matches_per_leaf_psum():
                     flat, None, axis="pod", axis_size=pods,
                     compress=compress)
                 return bkt.unpack_buckets(red, layout)
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P("pod"), out_specs=P(),
                 axis_names={"pod"}, check_vma=False))
 
@@ -191,7 +191,7 @@ def test_bucketed_exchange_matches_per_leaf_psum():
             return jax.tree.map(lambda a: jax.lax.psum(a, "pod"), g)
 
         exact = bucketed(False)(stacked)
-        plain = jax.jit(compat.shard_map(
+        plain = jax.jit(jax.shard_map(
             per_leaf_psum, mesh=mesh, in_specs=P("pod"), out_specs=P(),
             axis_names={"pod"}, check_vma=False))(stacked)
         for a, b, c in zip(jax.tree.leaves(exact), jax.tree.leaves(ref),
@@ -221,7 +221,7 @@ def test_bucketed_exchange_matches_per_leaf_psum():
             return out
         stacked4 = jax.tree.map(
             lambda v: jnp.stack([v.astype(jnp.float32)] * 4), tree)
-        out3 = jax.jit(compat.shard_map(
+        out3 = jax.jit(jax.shard_map(
             f3, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(),
             axis_names={"pod", "data"}, check_vma=False))(stacked4)
         for a, b in zip(jax.tree.leaves(out3), jax.tree.leaves(tree)):
@@ -262,7 +262,7 @@ def test_elastic_restart_resumes_identically():
             OptimizerConfig, ShapeConfig
         from repro.models.model import build_model
         from repro.launch import steps
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity, dummy
         from repro.data import synthetic
         from repro.checkpoint.checkpoint import CheckpointManager
@@ -281,11 +281,11 @@ def test_elastic_restart_resumes_identically():
             return {k: jnp.asarray(v) for k, v in packed.items()}
 
         # phase 1: 2-pod mesh, 2 steps, checkpoint
-        mesh2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
         tcfg = TrainConfig(model=cfg, shape=shape, het=HetConfig(),
                            optimizer=ocfg)
         plan4 = capacity.plan_capacities(8, [1, 1, 1, 1])
-        with compat.set_mesh(mesh2):
+        with jax.set_mesh(mesh2):
             state = steps.init_train_state(m, tcfg, mesh2,
                                            jax.random.PRNGKey(0))
             step2 = steps.build_train_step(m, tcfg, mesh2)
@@ -299,8 +299,8 @@ def test_elastic_restart_resumes_identically():
             mgr.save(1, host, meta={"seed": 0}, block=True)
 
             # phase 2: pod lost -> re-mesh to single pod, restore, resume
-            mesh1 = jax.make_mesh((4, 2), ("data", "model"))
-            with compat.set_mesh(mesh1):
+            mesh1 = make_mesh((4, 2), ("data", "model"))
+            with jax.set_mesh(mesh1):
                 fresh = steps.init_train_state(m, tcfg, mesh1,
                                                jax.random.PRNGKey(0))
                 restored_host, meta = mgr.restore(jax.device_get(fresh))

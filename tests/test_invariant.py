@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import base as cfgbase
 from repro.core import accumulate, capacity, dummy, weighting

@@ -19,6 +19,7 @@ import pytest
 
 from repro.configs.base import OptimizerConfig
 from repro.core import buckets as bkt
+from repro.launch.mesh import make_mesh
 from repro.optim import adam, lamb
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -204,7 +205,7 @@ def test_overlap_config_validation():
     from repro.configs.base import HetConfig, TrainConfig
     from repro.launch.steps import _overlap_enabled
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = cfgs.smoke_config("olmo-1b")
     for het, err in ((HetConfig(overlap="buckets"), "explicit"),
                      (HetConfig(overlap="backward"), "explicit"),
@@ -232,7 +233,7 @@ def test_backward_overlap_build_validation():
     from repro.launch.steps import validate_train_config
     from repro.models.model import build_model
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     het = HetConfig(overlap="backward",
                     grad_reduction="bucketed_allreduce", bucket_mb=0.05)
 
@@ -332,11 +333,11 @@ def test_overlapped_exchange_bitwise_matches_monolithic():
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import buckets as bkt
         from repro.core import hierarchical as hier
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         pods = 2
         rng = np.random.default_rng(0)
         tree = {"w": jnp.asarray(rng.standard_normal((130, 17)),
@@ -362,7 +363,7 @@ def test_overlapped_exchange_bitwise_matches_monolithic():
                         flat, e, axis="pod", axis_size=pods,
                         compress=compress, total=layout.total)
                 return red, (ne if ne is not None else jnp.zeros(()))
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P("pod"),
                 out_specs=(P(), P("pod")) if with_err else (P(), P()),
                 axis_names={"pod"}, check_vma=False))(stacked)
@@ -409,7 +410,7 @@ def test_overlapped_exchange_bitwise_matches_monolithic():
                         flat, err0, axis="pod", axis_size=pods,
                         compress=True, total=layout_p.total)
                 return red, ne
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P("pod"),
                 out_specs=(P(), P("pod")),
                 axis_names={"pod"}, check_vma=False))(stacked_p)
@@ -438,7 +439,7 @@ def test_overlapped_exchange_bitwise_matches_monolithic():
                 out, ne = fn(g, e, layout3, data_size=2, pod_size=pods,
                              compress=compress)
                 return out, (ne if ne is not None else jnp.zeros(()))
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P(("pod", "data")),
                 out_specs=(P(), P(("pod", "data"))) if with_err
                 else (P(), P()),
@@ -472,14 +473,14 @@ def test_fused_overlap_train_step_matches_monolithic():
             OptimizerConfig, ShapeConfig
         from repro.models.model import build_model
         from repro.launch import steps
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity, dummy
         from repro.data import synthetic
 
         cfg = dataclasses.replace(base.smoke_config("olmo-1b"),
                                   compute_dtype="float32")
         m = build_model(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeConfig("t", 16, 8, "train")
         rec = synthetic.make_lm_records(8, 17, cfg.vocab_size, seed=5)
         plan = capacity.plan_capacities(8, [1, 1, 1, 1])
@@ -496,7 +497,7 @@ def test_fused_overlap_train_step_matches_monolithic():
                                optimizer=OptimizerConfig(
                                    lr=1e-3, warmup_steps=2,
                                    grad_clip=clip))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = steps.init_train_state(m, tcfg, mesh,
                                                jax.random.PRNGKey(0))
                 step = steps.build_train_step(m, tcfg, mesh)
@@ -561,7 +562,7 @@ def test_backward_overlap_train_step_matches_monolithic():
             OptimizerConfig, ShapeConfig
         from repro.models.model import build_model
         from repro.launch import steps
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity, dummy
         from repro.data import synthetic
 
@@ -569,7 +570,7 @@ def test_backward_overlap_train_step_matches_monolithic():
                                   compute_dtype="float32",
                                   scan_layers=False)
         m = build_model(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeConfig("t", 16, 8, "train")
         rec = synthetic.make_lm_records(8, 17, cfg.vocab_size, seed=5)
         plan = capacity.plan_capacities(8, [1, 1, 1, 1])
@@ -587,7 +588,7 @@ def test_backward_overlap_train_step_matches_monolithic():
                                optimizer=OptimizerConfig(
                                    name=opt, lr=1e-3, warmup_steps=2,
                                    grad_clip=clip))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = steps.init_train_state(m, tcfg, mesh,
                                                jax.random.PRNGKey(0))
                 step = steps.build_train_step(m, tcfg, mesh)
@@ -686,7 +687,7 @@ def test_backward_overlap_train_step_matches_monolithic():
                                optimizer=OptimizerConfig(
                                    lr=1e-3, warmup_steps=2,
                                    grad_clip=0.0))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = steps.init_train_state(sm, tcfg, mesh,
                                                jax.random.PRNGKey(0))
                 step = steps.build_train_step(sm, tcfg, mesh)

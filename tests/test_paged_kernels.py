@@ -2,19 +2,16 @@
 
 The paged Pallas kernels gather KV blocks through the block table
 INSIDE the kernel; the reference path materializes the window in HBM
-first (``.at[tables].get(mode="fill", fill_value=0)``). The acceptance
-bar is asymmetric by design:
+first (``.at[tables].get(mode="fill", fill_value=0)``). Both kernels
+(GQA flash decode and absorbed-MLA decode) are streaming online
+softmaxes over block tiles — a different reduction order than the
+dense reference — so parity is a tolerance at the compute dtype,
+swept across ragged kv_lens / block sizes / head counts / GQA group
+sizes.
 
-  * GQA flash decode — fp32-BITWISE equal to the reference across
-    ragged kv_lens / block sizes / head counts / GQA group sizes (the
-    kernel replicates ``ref.mha_dense``'s exact contraction shapes; a
-    same-math different-shape einsum drifts by 1 ulp on XLA CPU).
-  * absorbed-MLA decode — within compute-dtype tolerance (the kernel is
-    a streaming online-softmax, a different — better — reduction order
-    than the dense reference).
-
-Everything runs in interpret mode (``pallas_interpret`` marker) so the
-sweep executes on the compat CPU jaxlib in CI.
+Everything runs in interpret mode (``pallas_interpret`` marker) on the
+CPU backend; tests/test_tpu_compile.py compiles the same kernels for a
+described v5e at real widths.
 """
 import dataclasses
 
@@ -22,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import ops as attn_ops
 from repro.kernels.mla_decode import ops as mla_ops
@@ -50,36 +47,47 @@ def _ragged_tables(rng, batch, mb, bs, n_pool):
     return jnp.asarray(tables), jnp.asarray(kv_lens)
 
 
+# Absolute tolerance per compute dtype for outputs that are convex
+# combinations of unit-normal values: fp32 allows reduction-order
+# drift only; bf16 allows one rounding of an O(1) output (2^-8 ulp at
+# 1.0, doubled for values up to ~4). A kernel that dropped a block or
+# mis-masked a position would miss both by orders of magnitude.
+ATOL = {"float32": 2e-5, "bfloat16": 2 ** -6}
+
+
 @settings(max_examples=25, deadline=None)
 @given(bs=st.sampled_from([2, 4, 8]),
        mb=st.integers(min_value=1, max_value=4),
        hkv=st.sampled_from([1, 2, 3]),
        q_per_kv=st.sampled_from([1, 2, 4]),
        d=st.sampled_from([4, 8, 16]),
+       dtype=st.sampled_from(sorted(ATOL)),
        lens_seed=st.integers(min_value=0, max_value=2 ** 16))
 def test_gqa_paged_pallas_bitwise_vs_reference(pallas_interpret, bs, mb,
-                                               hkv, q_per_kv, d,
+                                               hkv, q_per_kv, d, dtype,
                                                lens_seed):
+    """GQA paged kernel vs materialize-then-attend, within the compute
+    dtype's tolerance (the name predates the streaming kernel, which is
+    no longer bitwise)."""
     rng = np.random.default_rng((bs, mb, hkv, q_per_kv, d, lens_seed))
     batch = int(rng.integers(1, 5))
     h = hkv * q_per_kv
     n_pool = batch * mb + 2           # spare blocks stay unmapped
     tables, kv_lens = _ragged_tables(rng, batch, mb, bs, n_pool)
-    q = jnp.asarray(rng.standard_normal((batch, 1, h, d)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((n_pool, bs, hkv, d)),
-                         jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((n_pool, bs, hkv, d)),
-                         jnp.float32)
+    q = jnp.asarray(rng.standard_normal((batch, 1, h, d)), dtype)
+    k_pool = jnp.asarray(rng.standard_normal((n_pool, bs, hkv, d)), dtype)
+    v_pool = jnp.asarray(rng.standard_normal((n_pool, bs, hkv, d)), dtype)
     out_ref = attn_ops.flash_decode_paged(
         q, k_pool, v_pool, tables, kv_lens, impl="reference")
     out_pal = attn_ops.flash_decode_paged(
         q, k_pool, v_pool, tables, kv_lens, impl="pallas",
         interpret=pallas_interpret)
-    assert np.array_equal(np.asarray(out_ref), np.asarray(out_pal)), (
-        f"paged GQA pallas decode not fp32-bitwise vs reference "
-        f"(max err {np.abs(np.asarray(out_ref) - np.asarray(out_pal)).max()}"
-        f", shapes bs={bs} mb={mb} hkv={hkv} qpk={q_per_kv} d={d} "
-        f"kv_lens={np.asarray(kv_lens).tolist()})")
+    assert out_pal.dtype == out_ref.dtype == jnp.dtype(dtype)
+    np.testing.assert_allclose(
+        np.asarray(out_ref, np.float32), np.asarray(out_pal, np.float32),
+        rtol=0, atol=ATOL[dtype],
+        err_msg=f"shapes bs={bs} mb={mb} hkv={hkv} qpk={q_per_kv} d={d} "
+                f"kv_lens={np.asarray(kv_lens).tolist()}")
 
 
 @settings(max_examples=20, deadline=None)
@@ -111,8 +119,8 @@ def test_mla_paged_pallas_tolerance_vs_reference(pallas_interpret, bs, mb,
 
 def test_gqa_paged_null_sentinel_fully_masked(pallas_interpret):
     """An inactive slot (all-NULL table, kv_len 1) must match the
-    reference's zero-fill gather bitwise — the clamped DMA source block
-    holds real data the kernel is required to zero out."""
+    reference's zero-fill gather — the clamped DMA source block holds
+    real data the kernel is required to zero out."""
     rng = np.random.default_rng(7)
     bs, mb, hkv, d, n_pool = 4, 3, 2, 8, 6
     k_pool = jnp.asarray(rng.standard_normal((n_pool, bs, hkv, d)),
@@ -130,18 +138,19 @@ def test_gqa_paged_null_sentinel_fully_masked(pallas_interpret):
     out_pal = attn_ops.flash_decode_paged(
         q, k_pool, v_pool, tables, kv_lens, impl="pallas",
         interpret=pallas_interpret)
-    assert np.array_equal(np.asarray(out_ref), np.asarray(out_pal))
+    np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_pal),
+                               rtol=0, atol=ATOL["float32"])
 
 
 def test_model_level_paged_decode_bitwise_fp32(pallas_interpret):
     """Full-model parity: decode_paged logits with attention_impl=
-    'pallas' are fp32-bitwise (GQA) / tolerance-equal (MLA) vs the
-    reference engine path, through scatter + attention + unembed."""
+    'pallas' match the reference engine path in fp32 within tolerance
+    (GQA and MLA alike), through scatter + attention + unembed."""
     from repro.configs import base as cfgbase
     from repro.models import kvcache as kvc
     from repro.models.model import build_model
 
-    for arch, bitwise in [("olmo-1b", True), ("deepseek-v2-236b", False)]:
+    for arch in ("olmo-1b", "deepseek-v2-236b"):
         cfg = dataclasses.replace(
             cfgbase.smoke_config(arch), param_dtype="float32",
             compute_dtype="float32", remat="none")
@@ -170,13 +179,8 @@ def test_model_level_paged_decode_bitwise_fp32(pallas_interpret):
                                      kv_lens)
         lp, _ = model_p.decode_paged(params, toks, cache_p, tables,
                                      kv_lens)
-        if bitwise:
-            assert np.array_equal(np.asarray(lr), np.asarray(lp)), (
-                f"{arch}: pallas decode logits not fp32-bitwise vs "
-                f"reference")
-        else:
-            np.testing.assert_allclose(np.asarray(lr), np.asarray(lp),
-                                       atol=1e-4)
+        np.testing.assert_allclose(np.asarray(lr), np.asarray(lp),
+                                   atol=1e-4, err_msg=arch)
 
 
 def test_unknown_impl_raises():

@@ -22,6 +22,7 @@ from repro.configs import base as cfgs
 from repro.configs.base import HetConfig, TrainConfig
 from repro.core import capacity
 from repro.core import pipeline as pipe
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -243,7 +244,7 @@ def test_pipeline_config_validation():
     from repro.models.model import build_model
 
     cfg = cfgs.smoke_config("olmo-1b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def tcfg(model_cfg, **het_kw):
         return TrainConfig(model=model_cfg, het=HetConfig(
@@ -267,7 +268,7 @@ def test_pipeline_config_validation():
         validate_train_config(thin, tcfg(thin_cfg), mesh)
 
     # a pipe mesh axis must be sized to pipeline_stages
-    pipe_mesh = jax.make_mesh((1, 1, 1), ("pipe", "data", "model"))
+    pipe_mesh = make_mesh((1, 1, 1), ("pipe", "data", "model"))
     with pytest.raises(ValueError, match="pipe"):
         validate_train_config(flat, tcfg(flat_cfg), pipe_mesh)
 
@@ -291,7 +292,7 @@ def test_checkpoint_format_records_stage_plan():
     from repro.launch import steps
     from repro.models.model import build_model
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = dataclasses.replace(cfgs.smoke_config("olmo-1b"),
                               scan_layers=False, num_layers=4)
     model = build_model(cfg)
@@ -329,7 +330,7 @@ def test_pipeline_step_matches_pure_dp():
             OptimizerConfig, ShapeConfig
         from repro.models.model import build_model
         from repro.launch import steps
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity, dummy
         from repro.data import synthetic
 
@@ -337,7 +338,7 @@ def test_pipeline_step_matches_pure_dp():
                                   compute_dtype="float32",
                                   scan_layers=False)
         m = build_model(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeConfig("t", 16, 8, "train")
         rec = synthetic.make_lm_records(16, 17, cfg.vocab_size, seed=5)
         plan = capacity.plan_capacities(16, [1, 1, 1, 1])
@@ -357,7 +358,7 @@ def test_pipeline_step_matches_pure_dp():
                 optimizer=OptimizerConfig(name=opt, lr=1e-3,
                                           warmup_steps=2,
                                           grad_clip=0.0))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = steps.init_train_state(m, tcfg, mesh,
                                                jax.random.PRNGKey(0))
                 step = steps.build_train_step(m, tcfg, mesh)

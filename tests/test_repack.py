@@ -28,6 +28,7 @@ from repro.configs.base import OptimizerConfig
 from repro.core import buckets as bkt
 from repro.core import elastic
 from repro.core.capacity import CapacityPlan, plan_capacities
+from repro.launch.mesh import make_mesh
 from repro.optim import adam
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -641,7 +642,7 @@ def test_checkpoint_format_block_records_layout():
     from repro.launch import steps
     from repro.models.model import build_model
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = cfgs.smoke_config("olmo-1b")
     model = build_model(cfg)
     packed = TrainConfig(model=cfg, het=HetConfig(
@@ -682,7 +683,7 @@ def test_overlap_checkpoint_three_way_restore_bit_identical():
         from repro.models.model import build_model
         from repro.launch import steps
         from repro.launch.sharding import named
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.core import capacity, dummy, elastic
         from repro.checkpoint.checkpoint import CheckpointManager
         from repro.data import synthetic
@@ -708,11 +709,11 @@ def test_overlap_checkpoint_three_way_restore_bit_identical():
             return {k: jnp.asarray(v) for k, v in packed.items()}
 
         # uninterrupted run: 2-pod mesh, overlap pipeline, ckpt @ step 1
-        meshA = jax.make_mesh((2, 1, 2), ("pod", "data", "model"))
+        meshA = make_mesh((2, 1, 2), ("pod", "data", "model"))
         topoA = elastic.MeshTopology(pods=2, data_per_pod=1, model=2)
         planA = capacity.plan_capacities(2, [1, 1])
         tA = tcfg_for(0.05, "buckets")
-        with compat.set_mesh(meshA):
+        with jax.set_mesh(meshA):
             st = steps.init_train_state(m, tA, meshA,
                                         jax.random.PRNGKey(0))
             fA = steps.build_train_step(m, tA, meshA)
@@ -734,7 +735,7 @@ def test_overlap_checkpoint_three_way_restore_bit_identical():
             host, meta = mgr.restore(steps.state_shapes(m, tcfg, mesh))
             assert elastic.validate_resume_equivalence(meta["plan"],
                                                        plan)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 sr = jax.device_put(
                     host, named(mesh, steps.state_specs(m, tcfg, mesh)))
                 f = steps.build_train_step(m, tcfg, mesh)
@@ -774,7 +775,7 @@ def test_overlap_checkpoint_three_way_restore_bit_identical():
         dec = elastic.plan_remesh(topoA, [0], planA.global_rows)
         assert dec.restart_required and dec.accum_scale == 2
         assert elastic.validate_resume_equivalence(planA, dec.plan)
-        meshC = jax.make_mesh(dec.topology.mesh_shape(),
+        meshC = make_mesh(dec.topology.mesh_shape(),
                               dec.topology.mesh_axes())
         tC = tcfg_for(0.02, "buckets", accum=dec.accum_scale)
         loC = steps.bucket_layout(m, tC, meshC)

@@ -9,10 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import base as cfgbase
 from repro.launch import serve as serve_mod
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.models.kvcache import PagedLayout
 from repro.models.model import build_model
 from repro.serve import (BlockPool, CapacityRouter, Request, Scheduler,
@@ -219,7 +219,7 @@ def test_decode_step_compiles_once():
     the decode step exactly once (fixed shapes, donated cache)."""
     cfg, model, _ = _model("olmo-1b", compute_dtype="float32",
                            attention_impl="dense")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = steps_mod.init_params_sharded(model, mesh,
                                            jax.random.PRNGKey(0))
     layout = PagedLayout(block_size=4, num_blocks=12,
@@ -227,7 +227,7 @@ def test_decode_step_compiles_once():
     reqs = [Request(0, (1, 2, 3), 4, 0.0),
             Request(1, tuple(range(1, 8)), 3, 0.5),
             Request(2, (9, 8), 5, 4.0)]
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         eng = serve_mod.build_engine(model, params, mesh, layout,
                                      slots=2, prefill_batch=2,
                                      pod_speeds=[1.0])
@@ -252,12 +252,12 @@ _PR9_REQS = [Request(0, (1, 2, 3), 4, 0.0),
 def _engine_run(impl):
     cfg, model, _ = _model("olmo-1b", compute_dtype="float32",
                            attention_impl=impl)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = steps_mod.init_params_sharded(model, mesh,
                                            jax.random.PRNGKey(0))
     layout = PagedLayout(block_size=4, num_blocks=12,
                          max_blocks_per_seq=4)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         eng = serve_mod.build_engine(model, params, mesh, layout,
                                      slots=2, prefill_batch=2,
                                      pod_speeds=[1.0])
@@ -292,8 +292,8 @@ def test_engine_pallas_retrace_guard_still_fires():
     wide = 3                                  # engine compiled slots=2
     tables = jnp.full((wide, 4), layout.null_block, jnp.int32)
     tables = tables.at[:, 0].set(jnp.arange(wide))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         cache = eng.init_cache_fn()
         eng.decode_fn(jnp.zeros((wide,), jnp.int32), cache, tables,
                       jnp.zeros((wide,), jnp.int32))
@@ -308,7 +308,7 @@ def test_serve_batch_spec_warns_once_per_build(caplog, monkeypatch):
     import logging
 
     cfg, model, _ = _model("olmo-1b", compute_dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = steps_mod.init_params_sharded(model, mesh,
                                            jax.random.PRNGKey(0))
     layout = PagedLayout(block_size=4, num_blocks=12,
@@ -317,7 +317,7 @@ def test_serve_batch_spec_warns_once_per_build(caplog, monkeypatch):
     monkeypatch.setattr(steps_mod, "dp_size", lambda m: 2)
     slots = 3
     with caplog.at_level(logging.WARNING, logger="repro.launch.steps"):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             decode = steps_mod.build_paged_decode_step(model, mesh,
                                                        layout, slots)
             cache = jax.jit(functools.partial(model.init_paged_cache,

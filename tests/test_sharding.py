@@ -2,15 +2,14 @@
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro import compat
 from repro.configs import base as cfgbase
 from repro.launch import sharding as shr
 from repro.models import transformer as tr
 
-MESH = compat.abstract_mesh((16, 16), ("data", "model"))
-MESH3 = compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_fit_spec_drops_nondivisible():

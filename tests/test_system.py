@@ -87,3 +87,16 @@ def test_train_driver_hierarchical_int8(tmp_path):
     first, last = [float(x) for x in
                    lines[0].split("loss")[1].strip().split(" -> ")]
     assert last < first
+
+
+@pytest.mark.parametrize("argv", [[], ["--four-chips"]])
+def test_chip_smoke_refuses_to_run_without_a_tpu(argv):
+    """chip_smoke.py proves the entry points run on a chip: on the CPU
+    it must fail, name the platform it found, and print no result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")] + argv,
+        capture_output=True, text=True, env=env, timeout=300, cwd=REPO)
+    assert proc.returncode != 0
+    assert "platform 'cpu'" in proc.stderr, proc.stderr[-2000:]
+    assert '"ok"' not in proc.stdout
