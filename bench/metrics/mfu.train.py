@@ -1,0 +1,13 @@
+"""Model FLOP utilisation of training: the FLOPs the useful tokens of
+the traced window require (forward and backward, no recomputation),
+over the window, the chips and each chip's bf16 peak."""
+from benchlib import flops
+
+
+def read(rec):
+    if rec["kind"] != "train" or not rec["traced_tokens"]:
+        return None
+    need = rec["traced_tokens"] * flops.train_flops_per_token(
+        rec["cfg"], rec["mix"]["seq_len"])
+    peak = rec["chips"] * rec["peaks"]["bf16_flops_per_s"]
+    return 100.0 * need / (rec["window_s"] * peak)
