@@ -161,6 +161,9 @@ def serve(args):
           f"(p50 {s['p50_time_per_token']:.3f} / "
           f"p99 {s['p99_time_per_token']:.3f} per token, "
           f"ttft {s['mean_ttft']:.3f})")
+    print(f"[serve] measured ttft p50 {s['ttft_ms_p50']:.1f} / p99 "
+          f"{s['ttft_ms_p99']:.1f} ms, token gap p50 "
+          f"{s['token_gap_ms_p50']:.2f} / p99 {s['token_gap_ms_p99']:.2f} ms")
     print(f"[serve] {s['decode_steps']} decode steps, "
           f"{s['prefill_groups']} prefill groups, "
           f"{s['preemptions']} preemptions, block util "

@@ -1543,7 +1543,16 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                 p, b, inner_ctx, label_smoothing=tcfg.label_smoothing)
             return o, w
 
-        grad_fn = jax.value_and_grad(objective, has_aux=True)
+        def grad_fn(p, b):
+            # value_and_grad, split so that the device trace names the
+            # forward and the backward pass apart
+            with jax.named_scope("forward"):
+                o, pullback, w = jax.vjp(lambda q: objective(q, b), p,
+                                         has_aux=True)
+            with jax.named_scope("backward"):
+                g, = pullback(jnp.ones_like(o))
+            return (o, w), g
+
         if accum == 1:
             (o, w), g = grad_fn(params, batch)
             return g, o, w
@@ -1725,7 +1734,8 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
         metrics = {"loss": loss, "weight": w, **met}
         return TrainState(params=params, opt=opt, err=state.err), metrics
 
-    def step(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
+    def train_step(state: TrainState, batch: Dict
+                   ) -> Tuple[TrainState, Dict]:
         if canonical:
             return canonical_step(state, batch)
         if overlap:
@@ -1744,9 +1754,10 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                     lambda gr, s: jax.lax.with_sharding_constraint(
                         gr, s),
                     g, pspecs_in)
-                g, ne = apply_pod_reduce(g, err)
-                return g, jax.lax.psum(o, "pod"), \
-                    jax.lax.psum(w, "pod"), ne
+                with jax.named_scope("exchange"):
+                    g, ne = apply_pod_reduce(g, err)
+                    return g, jax.lax.psum(o, "pod"), \
+                        jax.lax.psum(w, "pod"), ne
 
             grads, o, w, new_err = jax.shard_map(
                 pod_local, mesh=mesh,
@@ -1768,8 +1779,9 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
 
             def dp_local(params, b):
                 g, o, w = compute_grads(params, b)
-                return reduce_buckets(g), jax.lax.psum(o, dp), \
-                    jax.lax.psum(w, dp)
+                with jax.named_scope("exchange"):
+                    return reduce_buckets(g), jax.lax.psum(o, dp), \
+                        jax.lax.psum(w, dp)
 
             grads, o, w = jax.shard_map(
                 dp_local, mesh=mesh,
@@ -1781,20 +1793,24 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
         else:
             grads, o, w = compute_grads(state.params, batch)
             new_err = state.err
-        loss = weighting.finalize(o, w)
-        grads = weighting.scale_grads(grads, w)
-        lr = schedules.learning_rate(ocfg, state.opt.step + 1)
-        opt_apply = (lamb.apply_update if ocfg.name == "lamb"
-                     else adam.apply_update)
-        params, opt, met = opt_apply(state.params, grads,
-                                     state.opt, ocfg, lr)
+        with jax.named_scope("exchange"):
+            # the weighted mean over every rank's rows (with plain
+            # allreduce the partitioner places the sums' collectives)
+            loss = weighting.finalize(o, w)
+            grads = weighting.scale_grads(grads, w)
+        with jax.named_scope("optimizer"):
+            lr = schedules.learning_rate(ocfg, state.opt.step + 1)
+            opt_apply = (lamb.apply_update if ocfg.name == "lamb"
+                         else adam.apply_update)
+            params, opt, met = opt_apply(state.params, grads,
+                                         state.opt, ocfg, lr)
         metrics = {"loss": loss, "weight": w, **met}
         return TrainState(params=params, opt=opt, err=new_err), metrics
 
     specs = state_specs(model, tcfg, mesh)
     bspecs = _fed_batch_specs(cfg, tcfg, mesh)
     return jax.jit(
-        step,
+        train_step,
         in_shardings=(shr.named(mesh, specs), shr.named(mesh, bspecs)),
         out_shardings=(shr.named(mesh, specs), None),
         donate_argnums=(0,),
@@ -1906,7 +1922,7 @@ def build_paged_prefill_step(model: Model, mesh: Mesh, layout,
     cfg = model.cfg
     ctx = make_parallel_ctx(mesh)
 
-    def prefill(params, prompts, lens, cache, tables):
+    def paged_prefill_step(params, prompts, lens, cache, tables):
         return model.prefill_paged(params, prompts, lens, cache, tables,
                                    ctx)
 
@@ -1919,7 +1935,7 @@ def build_paged_prefill_step(model: Model, mesh: Mesh, layout,
     logit_spec = shr.fit_spec((batch, cfg.vocab_size), P(bspec, "model"),
                               mesh)
     return jax.jit(
-        prefill,
+        paged_prefill_step,
         in_shardings=(shr.named(mesh, pspecs),
                       NamedSharding(mesh, P(bspec, None)),
                       NamedSharding(mesh, P(bspec)),
@@ -1944,7 +1960,7 @@ def build_paged_decode_step(model: Model, mesh: Mesh, layout,
     cfg = model.cfg
     ctx = make_parallel_ctx(mesh)
 
-    def decode(params, tokens, cache, tables, kv_lens):
+    def paged_decode_step(params, tokens, cache, tables, kv_lens):
         return model.decode_paged(params, tokens, cache, tables, kv_lens,
                                   ctx)
 
@@ -1957,7 +1973,7 @@ def build_paged_decode_step(model: Model, mesh: Mesh, layout,
     logit_spec = shr.fit_spec((slots, cfg.vocab_size), P(bspec, "model"),
                               mesh)
     return jax.jit(
-        decode,
+        paged_decode_step,
         in_shardings=(shr.named(mesh, pspecs),
                       NamedSharding(mesh, P(bspec)),
                       shr.named(mesh, cspecs),

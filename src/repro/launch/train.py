@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs import base as cfgbase
 from repro.configs.base import (HetConfig, ModelConfig, OptimizerConfig,
@@ -171,6 +172,38 @@ def build_chaos_engine(args, tcfg: TrainConfig, mesh,
         raise SystemExit(f"[train] {e}") from e
 
 
+def _batches(loader: PrefetchLoader, epoch: int, skip: int):
+    """``(epoch, index in its epoch, raw batch)`` from ``epoch`` on, the
+    first ``skip`` batches of it left out (a resume mid-epoch)."""
+    while True:
+        consumed = 0
+        for raw in loader.iter_epoch(epoch):
+            consumed += 1
+            if consumed > skip:
+                yield epoch, consumed, raw
+        epoch += 1
+        skip = 0
+
+
+def _monitor(monitor: StragglerMonitor, engine: chaos.ChaosEngine,
+             plan: cap.CapacityPlan, sampler: HetSampler, step: int,
+             dt: float) -> cap.CapacityPlan:
+    """Report the step's per-rank times and replan when due; returns the
+    plan in force. On real fleets each host reports; here the chaos
+    engine differentiates ranks from the host clock (slowdowns inflate,
+    kills/flaky drop the report). No schedule => every rank reports the
+    measured time."""
+    monitor.observe(engine.step_times(step, plan.rows_per_rank, dt))
+    if monitor.should_replan():
+        new_plan = monitor.replan(plan)
+        if new_plan.rows_per_rank.tolist() != plan.rows_per_rank.tolist():
+            print(f"[train] replan: rows {plan.rows_per_rank.tolist()} -> "
+                  f"{new_plan.rows_per_rank.tolist()}")
+        plan = new_plan
+        sampler.set_plan(plan)
+    return plan
+
+
 def train(args, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
     """Run the training loop. ``cfg`` overrides ``--arch`` (see
     :func:`build_everything`).
@@ -180,6 +213,12 @@ def train(args, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
     times ``step_s`` (each step waited on with ``block_until_ready``),
     and ``batch_rows_by_device``: the rows of the first batch held by
     each device id, i.e. where the data-parallel shards landed.
+
+    Each step is a ``train.iteration`` span (``repro/obs.py``) holding
+    ``train.input`` (the loader's next batch, put on the devices),
+    ``train.step`` (the step, waited on; its length is ``step_s``) and
+    ``train.monitor`` (loss read-back, straggler report and replan, the
+    log line, and ``train.checkpoint`` around a periodic save).
     """
     cfg, model, mesh, tcfg = build_everything(args, cfg)
     topo = topology_from_mesh(mesh)
@@ -305,74 +344,52 @@ def train(args, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
     losses = []
     step_s = []
     batch_rows_by_device: Dict[int, int] = {}
-    t_start = time.time()
+    t_start = time.perf_counter()
     body_raised = False
     try:
         while step < args.steps:
             try:
                 with jax.set_mesh(mesh):
+                    stream = _batches(loader, epoch, batch_in_epoch)
                     while step < args.steps:
-                        consumed = 0
-                        for raw in loader.iter_epoch(epoch):
-                            consumed += 1
-                            if consumed <= batch_in_epoch:
-                                continue      # resume mid-epoch: skip
-                            if step >= args.steps:
-                                break
-                            # hetsampler pads the *labels*: inputs are
-                            # the shifted view
-                            batch = {
-                                "inputs": jnp.asarray(
-                                    raw["inputs"][:, :args.seq_len]),
-                                "labels": jnp.asarray(
-                                    raw["labels"][:, :args.seq_len]),
-                                "weights": jnp.asarray(
-                                    raw["weights"][:, :args.seq_len]),
-                            }
-                            batch = jax.device_put(batch, bspecs)
+                        with obs.span("train.iteration"):
+                            with obs.span("train.input"):
+                                b_epoch, b_index, raw = next(stream)
+                                # hetsampler pads the *labels*: inputs
+                                # are the shifted view
+                                batch = jax.device_put({
+                                    name: jnp.asarray(
+                                        raw[name][:, :args.seq_len])
+                                    for name in ("inputs", "labels",
+                                                 "weights")}, bspecs)
                             if not batch_rows_by_device:
                                 batch_rows_by_device = {
                                     s.device.id: int(s.data.shape[0])
                                     for s in batch["inputs"]
                                     .addressable_shards}
-                            t0 = time.time()
-                            state, metrics = jax.block_until_ready(
-                                step_fn(state, batch))
-                            dt = time.time() - t0
-                            loss = float(metrics["loss"])
-                            losses.append(loss)
-                            step_s.append(dt)
+                            with obs.span("train.step",
+                                          step=step + 1) as timed:
+                                state, metrics = jax.block_until_ready(
+                                    step_fn(state, batch))
+                            dt = timed.seconds
                             step += 1
-                            batch_in_epoch = consumed
-                            # per-rank step times: on real fleets each
-                            # host reports; here the chaos engine
-                            # differentiates ranks from the host clock
-                            # (slowdowns inflate, kills/flaky drop the
-                            # report). No schedule => every rank reports
-                            # the measured time.
-                            monitor.observe(engine.step_times(
-                                step, plan.rows_per_rank, dt))
-                            if monitor.should_replan():
-                                new_plan = monitor.replan(plan)
-                                if new_plan.rows_per_rank.tolist() != \
-                                        plan.rows_per_rank.tolist():
-                                    print(f"[train] replan: rows "
-                                          f"{plan.rows_per_rank.tolist()}"
-                                          f" -> "
-                                          f"{new_plan.rows_per_rank.tolist()}")
-                                plan = new_plan
-                                sampler.set_plan(plan)
-                            if step % args.log_every == 0:
-                                print(f"[train] step {step:5d} loss "
-                                      f"{loss:.4f} ({dt * 1e3:.0f} ms)")
-                            if tcfg.ckpt_every and \
-                                    step % tcfg.ckpt_every == 0:
-                                mgr.save(step, jax.device_get(state),
-                                         meta=save_meta())
-                        if step >= args.steps:
-                            break
-                        epoch += 1
-                        batch_in_epoch = 0
+                            epoch, batch_in_epoch = b_epoch, b_index
+                            with obs.span("train.monitor"):
+                                loss = float(metrics["loss"])
+                                losses.append(loss)
+                                step_s.append(dt)
+                                plan = _monitor(monitor, engine, plan,
+                                                sampler, step, dt)
+                                if step % args.log_every == 0:
+                                    print(f"[train] step {step:5d} loss "
+                                          f"{loss:.4f} ({dt * 1e3:.0f} "
+                                          f"ms)")
+                                if tcfg.ckpt_every and \
+                                        step % tcfg.ckpt_every == 0:
+                                    with obs.span("train.checkpoint"):
+                                        mgr.save(step,
+                                                 jax.device_get(state),
+                                                 meta=save_meta())
             except RemeshRequired as e:
                 mgr.wait()                 # flush any in-flight write
                 if mgr.latest_step() is None:
@@ -473,7 +490,7 @@ def train(args, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
                 raise
             print(f"[train] WARNING: checkpoint writer failed during "
                   f"shutdown: {werr!r}")
-    wall = time.time() - t_start
+    wall = time.perf_counter() - t_start
     if not losses:                       # resumed an already-done run
         print(f"[train] nothing to do: checkpoint already at step "
               f"{step} >= --steps {args.steps}")

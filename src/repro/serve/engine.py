@@ -23,17 +23,29 @@ Both are max-over-pods because the mesh is one SPMD program — the step
 returns when the slowest pod finishes, which is exactly why the router
 gives slow pods proportionally fewer sequences (min-max of
 active_p/speed_p is the HetSeq capacity argument on the serving side).
+
+**Real clock.** Beside the model, the loop's host work is spanned
+(``repro/obs.py``): each iteration is a ``serve.iteration`` holding
+``serve.admit`` (arrivals, admission, bucketing), ``serve.prefill``
+(with ``serve.fetch`` of its logits), ``serve.prepare`` (block tables
+and the step's inputs, uploaded), ``serve.decode`` (the step call),
+``serve.fetch`` (logits to the host) and ``serve.sample`` (argmax and
+emit). Each request's submission, admissions, first and last token
+are ``request.*`` events sharing one ``req`` id; ``stats`` reports the
+measured time to first token and gap between tokens from them.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
+                    Tuple)
 
 import numpy as np
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.kvcache import PagedLayout
 from repro.serve.scheduler import Request, Scheduler, SeqState
 
@@ -79,6 +91,7 @@ class ServeEngine:
         self.decode_fn = decode_fn
         self.prefill_fns = prefill_fns
         self.init_cache_fn = init_cache_fn
+        self._runs = 0                  # run() calls, for the spans
 
     # -- modeled costs -----------------------------------------------------
 
@@ -108,6 +121,8 @@ class ServeEngine:
         token_times: Dict[int, List[float]] = {r.rid: [] for r in arrivals}
         arrival_of = {r.rid: r.arrival for r in arrivals}
 
+        self._runs += 1
+        req_of: Dict[int, int] = {}         # rid -> obs request id
         cache = self.init_cache_fn()
         clock, ai = 0.0, 0
         decode_steps = prefill_groups = 0
@@ -120,7 +135,11 @@ class ServeEngine:
             seq.last_token = tok
             tokens_out[seq.rid].append(tok)
             token_times[seq.rid].append(t)
+            if len(tokens_out[seq.rid]) == 1:
+                obs.event("request.first_token", req=req_of[seq.rid])
             if seq.done:
+                obs.event("request.last_token", req=req_of[seq.rid],
+                          tokens=len(tokens_out[seq.rid]))
                 sched.finish(seq)
 
         it = 0
@@ -130,53 +149,71 @@ class ServeEngine:
                 raise RuntimeError(
                     f"serve loop exceeded {self.cfg.max_iterations} "
                     f"iterations — scheduler stuck?")
-            # idle: jump the clock to the next arrival
-            if (not sched.running and not sched.waiting
-                    and ai < len(arrivals)):
-                clock = max(clock, arrivals[ai].arrival)
-            while ai < len(arrivals) and arrivals[ai].arrival <= clock:
-                sched.submit(arrivals[ai])
-                ai += 1
+            with obs.span("serve.iteration"):
+                with obs.span("serve.admit"):
+                    # idle: jump the clock to the next arrival
+                    if (not sched.running and not sched.waiting
+                            and ai < len(arrivals)):
+                        clock = max(clock, arrivals[ai].arrival)
+                    while (ai < len(arrivals)
+                           and arrivals[ai].arrival <= clock):
+                        rid = arrivals[ai].rid
+                        sched.submit(arrivals[ai])
+                        req_of[rid] = obs.new_id()
+                        obs.event("request.submit", req=req_of[rid],
+                                  rid=rid)
+                        ai += 1
+                    admitted = sched.try_admit()
+                    by_bucket: Dict[int, List[SeqState]] = {}
+                    for seq in admitted:
+                        obs.event("request.admit", req=req_of[seq.rid])
+                        by_bucket.setdefault(
+                            sched.bucket_for(len(seq.prompt)),
+                            []).append(seq)
+                for bucket in sorted(by_bucket):
+                    group = by_bucket[bucket]
+                    Bp = self.cfg.prefill_batch
+                    for lo in range(0, len(group), Bp):
+                        chunk = group[lo:lo + Bp]
+                        cache, logits = self._prefill(chunk, bucket, Bp,
+                                                      cache, NULL, MB)
+                        clock += self._prefill_dt(bucket, chunk)
+                        prefill_groups += 1
+                        with obs.span("serve.sample"):
+                            toks = np.argmax(logits[:len(chunk)], axis=-1)
+                            for seq, tok in zip(chunk, toks):
+                                seq.kv_len = len(seq.prompt)
+                                emit(seq, int(tok), clock)
 
-            admitted = sched.try_admit()
-            by_bucket: Dict[int, List[SeqState]] = {}
-            for seq in admitted:
-                by_bucket.setdefault(
-                    sched.bucket_for(len(seq.prompt)), []).append(seq)
-            for bucket in sorted(by_bucket):
-                group = by_bucket[bucket]
-                Bp = self.cfg.prefill_batch
-                for lo in range(0, len(group), Bp):
-                    chunk = group[lo:lo + Bp]
-                    cache, logits = self._prefill(chunk, bucket, Bp,
-                                                  cache, NULL, MB)
-                    clock += self._prefill_dt(bucket, chunk)
-                    prefill_groups += 1
-                    toks = np.argmax(logits[:len(chunk)], axis=-1)
-                    for seq, tok in zip(chunk, toks):
-                        seq.kv_len = len(seq.prompt)
-                        emit(seq, int(tok), clock)
-
-            if sched.running:
-                # grow block tables BEFORE the step (the new token
-                # writes at position kv_len); may preempt newest-first
-                for slot in sorted(sched.running):
-                    seq = sched.running.get(slot)
-                    if seq is not None and not sched.ensure_next_block(
-                            seq):
-                        continue            # seq preempted itself
                 if not sched.running:
                     continue
-                tok_arr = np.zeros((D,), np.int32)
-                tbl_arr = np.full((D, MB), NULL, np.int32)
-                len_arr = np.zeros((D,), np.int32)
-                for slot, seq in sched.running.items():
-                    tok_arr[slot] = seq.last_token
-                    tbl_arr[slot, :len(seq.blocks)] = seq.blocks
-                    len_arr[slot] = seq.kv_len
-                logits, cache = self.decode_fn(
-                    jnp.asarray(tok_arr), cache, jnp.asarray(tbl_arr),
-                    jnp.asarray(len_arr))
+                with obs.span("serve.prepare"):
+                    # grow block tables BEFORE the step (the new token
+                    # writes at position kv_len); may preempt
+                    # newest-first
+                    for slot in sorted(sched.running):
+                        seq = sched.running.get(slot)
+                        if seq is not None and not \
+                                sched.ensure_next_block(seq):
+                            continue            # seq preempted itself
+                    if not sched.running:
+                        continue
+                    tok_arr = np.zeros((D,), np.int32)
+                    tbl_arr = np.full((D, MB), NULL, np.int32)
+                    len_arr = np.zeros((D,), np.int32)
+                    for slot, seq in sched.running.items():
+                        tok_arr[slot] = seq.last_token
+                        tbl_arr[slot, :len(seq.blocks)] = seq.blocks
+                        len_arr[slot] = seq.kv_len
+                    toks_d, tbl_d, lens_d = (jnp.asarray(tok_arr),
+                                             jnp.asarray(tbl_arr),
+                                             jnp.asarray(len_arr))
+                active = len(sched.running)
+                with obs.span("serve.decode", run=self._runs,
+                              step=decode_steps + 1, active=active,
+                              kv_tokens=int(len_arr.sum()) + active):
+                    logits, cache = self.decode_fn(toks_d, cache, tbl_d,
+                                                   lens_d)
                 clock += self._decode_dt()
                 decode_steps += 1
                 for p, a in enumerate(sched.active_per_pod):
@@ -185,10 +222,11 @@ class ServeEngine:
                 block_util_peak = max(block_util_peak, util)
                 block_util_sum += util
                 util_samples += 1
-                logits_h = np.asarray(logits)
-                for slot, seq in list(sched.running.items()):
-                    seq.kv_len += 1
-                    emit(seq, int(np.argmax(logits_h[slot])), clock)
+                logits_h = fetch(logits)
+                with obs.span("serve.sample"):
+                    for slot, seq in list(sched.running.items()):
+                        seq.kv_len += 1
+                        emit(seq, int(np.argmax(logits_h[slot])), clock)
 
         wall = time.monotonic() - wall0
         self._assert_no_retrace()
@@ -218,6 +256,7 @@ class ServeEngine:
                                 if util_samples else 0.0),
             "attention_impl": self.cfg.attention_impl,
             "wall_seconds": wall,
+            **request_latency(obs.spans(), set(req_of.values())),
         }
         return ServeResult(tokens=tokens_out, stats=stats)
 
@@ -230,10 +269,12 @@ class ServeEngine:
             prompts[i, :len(seq.prompt)] = seq.prompt
             lens[i] = len(seq.prompt)
             tables[i, :len(seq.blocks)] = seq.blocks
-        logits, cache = self.prefill_fns[bucket](
-            jnp.asarray(prompts), jnp.asarray(lens), cache,
-            jnp.asarray(tables))
-        return cache, np.asarray(logits)
+        with obs.span("serve.prefill", bucket=bucket, rows=len(chunk),
+                      prompt_tokens=int(lens.sum())):
+            logits, cache = self.prefill_fns[bucket](
+                jnp.asarray(prompts), jnp.asarray(lens), cache,
+                jnp.asarray(tables))
+            return cache, fetch(logits)
 
     def _assert_no_retrace(self) -> None:
         """Fail loud if the decode step compiled more than once — a
@@ -244,6 +285,41 @@ class ServeEngine:
             raise RuntimeError(
                 f"paged decode step retraced: {n} compilations for one "
                 f"engine run (expected 1)")
+
+
+def request_latency(records: Sequence[obs.Span], reqs: Set[int]
+                    ) -> Dict[str, float]:
+    """Measured latency of the requests ``reqs`` (their ``request.*``
+    events on ``perf_counter_ns``), p50 and p99 over the requests in ms:
+    ``ttft_ms`` from submission to the first token, ``token_gap_ms``
+    each request's mean gap between its tokens. Requests whose events
+    the ring no longer holds are left out."""
+    events: Dict[int, Dict[str, obs.Span]] = {}
+    for r in records:
+        if r.name.startswith("request.") and r.attrs.get("req") in reqs:
+            events.setdefault(r.attrs["req"], {})[r.name] = r
+    ttft, gap = [], []
+    for ev in events.values():
+        sub = ev.get("request.submit")
+        first = ev.get("request.first_token")
+        last = ev.get("request.last_token")
+        if sub and first:
+            ttft.append((first.t0_ns - sub.t0_ns) * 1e-6)
+        if first and last and last.attrs["tokens"] > 1:
+            gap.append((last.t0_ns - first.t0_ns) * 1e-6
+                       / (last.attrs["tokens"] - 1))
+    out = {}
+    for name, xs in (("ttft_ms", ttft), ("token_gap_ms", gap)):
+        for q in (50, 99):
+            out[f"{name}_p{q}"] = float(np.percentile(xs, q)) if xs \
+                else 0.0
+    return out
+
+
+def fetch(logits) -> np.ndarray:
+    """Bring a step's logits to the host."""
+    with obs.span("serve.fetch", bytes=int(logits.nbytes)):
+        return np.asarray(logits)
 
 
 def _trace_count(fn) -> Optional[int]:
