@@ -152,63 +152,121 @@ def flash_attention_pallas(
 # paged flash decode (serving hot path: block-table gather INSIDE the kernel)
 # --------------------------------------------------------------------------
 
+CHUNK_TOKENS = 128   # tokens folded per unit of work: one MXU tile of rows
+LANES = 128          # a vreg's lanes; the head dim is padded to a multiple
 
-def _paged_decode_kernel(tables, lens, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale: float,
-                         block_size: int, max_blocks: int, null_block: int,
-                         kv_heads: int, q_per_kv: int):
-    """Grid (B, MB); j sequential. Step j DMAs sequence bi's j-th mapped
-    KV block straight from the pool (the block-table lookup happens in
-    the BlockSpec index_map via scalar prefetch — no materialized window
-    in HBM) and folds it into an fp32 online softmax whose state
-    (acc (H, D), m, l) persists in VMEM scratch across blocks.
 
-    Every matmul is 2-D: per kv head g, the (q_per_kv, D) query group
-    against the block's (bs, D) keys, then the (q_per_kv, bs)
-    probabilities against its values. Mosaic lowers at most one batch
-    dim per in-kernel matmul, and the scratch stays O(H * D) whatever
-    the window, so a 2048-token window fits scoped VMEM.
+def _paged_decode_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, slot_ref, *, scale: float,
+                         block_size: int, max_blocks: int, chunk_blocks: int,
+                         null_block: int, kv_heads: int, q_per_kv: int):
+    """Grid (B,), sequential; step b attends slot b's query to its context.
+
+    The K/V pools stay in HBM. Slot b needs ``nb = ceil(lens[b] / bs)``
+    blocks; they are walked in ``ceil(nb / P)`` chunks of P blocks by an
+    in-kernel loop, each block one async copy of its contiguous
+    (bs, Hkv, D) slab into a VMEM chunk buffer. The buffer is doubled:
+    the next chunk's copies (the next slot's first chunk after a slot's
+    last) start before the current chunk is folded. No entry at or past
+    ``nb`` is copied or computed; a NULL entry below ``nb`` is not copied
+    but zero-filled, as the reference's ``mode="fill"`` gather reads it.
+
+    A chunk is folded with one 2-D matmul per operand, every kv head at
+    once: the chunk viewed as (P*bs*Hkv, D) rows, one per (token, kv
+    head), against all H queries; a score whose row belongs to another
+    kv head than its query's, or to a position at or past ``lens[b]``,
+    is masked. Rows not copied hold stale or uninitialised VMEM, so V is
+    zeroed by position as well. Q.K runs in the promoted dtype of q and
+    the pool (exact products, f32 sums) and the scale is applied to the
+    f32 scores; probabilities, P.V and the online-softmax state
+    (acc (H, D), m, l) are f32.
     """
-    bi = pl.program_id(0)
-    j = pl.program_id(1)
+    b = pl.program_id(0)
+    bs, per_chunk = block_size, chunk_blocks
+    rows = per_chunk * bs * kv_heads        # chunk rows, (token, kv head)
+    h, d = q_ref.shape[2], q_ref.shape[3]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def n_blocks(i):
+        return jnp.minimum(pl.cdiv(lens[i], bs), max_blocks)
 
-    # NULL (unmapped) blocks were clamped to a real pool slot by the
-    # index_map; zero the tile as the reference's `mode="fill"` gather
-    # does (its positions are masked by kv_len anyway)
-    is_null = tables[bi, j] == null_block
-    kpos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (q_per_kv, block_size), 1)
-    valid = kpos < lens[bi]
-    for g in range(kv_heads):
-        rows = pl.ds(g * q_per_kv, q_per_kv)
-        q = q_ref[0, 0, rows, :].astype(jnp.float32) * scale
-        k = jnp.where(is_null, 0.0, k_ref[0, :, g, :].astype(jnp.float32))
-        v = jnp.where(is_null, 0.0, v_ref[0, :, g, :].astype(jnp.float32))
+    def fetch(i, c, slot, start):
+        """Start (or wait for) the copies of slot i's chunk c into slot."""
+        nb = n_blocks(i)
+        for p in range(per_chunk):
+            j = c * per_chunk + p
+            blk = tables[i, jnp.minimum(j, max_blocks - 1)]
+
+            @pl.when((j < nb) & (blk != null_block))
+            def _copy():
+                for kv, hbm, buf in ((0, k_hbm, k_buf), (1, v_hbm, v_buf)):
+                    cp = pltpu.make_async_copy(hbm.at[blk], buf.at[slot, p],
+                                               sems.at[kv, slot])
+                    if start:
+                        cp.start()
+                    else:
+                        cp.wait()
+
+            if start:
+                @pl.when((j < nb) & (blk == null_block))
+                def _zero():
+                    k_buf[slot, p] = jnp.zeros(k_buf.shape[2:], k_buf.dtype)
+                    v_buf[slot, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        fetch(0, 0, 0, start=True)
+
+    n_chunks = jnp.maximum(pl.cdiv(n_blocks(b), per_chunk), 1)
+    length = lens[b]
+    q = q_ref[0, 0]
+    dt = jnp.promote_types(q.dtype, k_buf.dtype)
+    q = q.astype(dt)
+    # row r of a chunk is kv head r % Hkv; query head i reads kv head
+    # i // q_per_kv
+    same_head = (jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1),
+                             kv_heads) ==
+                 jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (h, rows), 0),
+                             q_per_kv))
+
+    def fold(c, carry):
+        acc, m, l = carry
+        slot = slot_ref[0]
+
+        @pl.when(c + 1 < n_chunks)
+        def _next_chunk():
+            fetch(b, c + 1, 1 - slot, start=True)
+
+        @pl.when((c + 1 == n_chunks) & (b + 1 < pl.num_programs(0)))
+        def _next_slot():
+            fetch(b + 1, 0, 1 - slot, start=True)
+
+        fetch(b, c, slot, start=False)
+        slot_ref[0] = 1 - slot
+        # rows below `live` hold positions < lens[b]
+        live = (length - c * per_chunk * bs) * kv_heads
+        k = k_buf[slot].reshape(rows, d).astype(dt)
+        v = v_buf[slot].reshape(rows, d).astype(jnp.float32)
+        v = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < live, v, 0.0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32) * scale
+        valid = same_head & (
+            jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1) < live)
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[rows, :]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[rows, :] = (l_ref[rows, :] * corr +
-                          jnp.sum(p, axis=-1, keepdims=True))
-        acc_ref[rows, :] = (acc_ref[rows, :] * corr +
-                            jax.lax.dot_general(
-                                p, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32))
-        m_ref[rows, :] = m_new
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return acc, m_new, l
 
-    @pl.when(j == max_blocks - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    acc, _, l = jax.lax.fori_loop(
+        0, n_chunks, fold,
+        (jnp.zeros((h, d), jnp.float32), jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32)))
+    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_decode_paged_pallas(
@@ -230,43 +288,51 @@ def flash_decode_paged_pallas(
     ``mode="fill"`` and running ``ref.mha_dense(causal=False,
     kv_len=kv_lens)`` (a streaming softmax sums in a different order).
 
-    HBM traffic per step is ONE pass over the mapped window (the
-    index_map-driven DMA), vs the materialized path's gather-read +
-    window-write + attend-read — see benchmarks/serve_bench.py's decode
-    roofline for the byte model.
+    One pallas_call, grid (B,): the block tables and lengths are
+    scalar-prefetched, q and the output are (1, 1, H, D) blocks per
+    slot, and the pools stay in HBM. Each slot reads only its
+    ``ceil(kv_lens[i] / bs)`` mapped blocks, in chunks of
+    ``P = max(1, 128 // bs)`` blocks (at most MB), double-buffered in
+    VMEM (see ``_paged_decode_kernel``); its HBM traffic is its context
+    rounded up to a block, whatever the table's width.
     """
     b, sq, h, d = q.shape
     if sq != 1:
         raise ValueError(f"paged decode expects a single query, got {sq}")
+    scale = softmax_scale if softmax_scale is not None else d ** -0.5
+    pad = -d % LANES
+    if pad:
+        # Mosaic copies a slab out of an HBM array only at lane-aligned
+        # widths: zero lanes leave every score and output lane as it was
+        widen = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+        out = flash_decode_paged_pallas(
+            widen(q), widen(k_pool), widen(v_pool), block_tables, kv_lens,
+            softmax_scale=scale, interpret=interpret)
+        return out[..., :d]
     n_pool, bs, hkv, _ = k_pool.shape
     mb = block_tables.shape[1]
-    scale = softmax_scale if softmax_scale is not None else d ** -0.5
+    chunk = min(max(1, CHUNK_TOKENS // bs), mb)
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, block_size=bs, max_blocks=mb,
-        null_block=n_pool, kv_heads=hkv, q_per_kv=h // hkv)
+        chunk_blocks=chunk, null_block=n_pool, kv_heads=hkv,
+        q_per_kv=h // hkv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, mb),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, h, d),
-                         lambda bi, j, tbl, lens: (bi, 0, 0, 0)),
-            # block-table indirection lives HERE: the DMA source block is
-            # tbl[bi, j] (clamped for NULL; the kernel zeroes those tiles)
-            pl.BlockSpec((1, bs, hkv, d),
-                         lambda bi, j, tbl, lens: (
-                             jnp.minimum(tbl[bi, j], n_pool - 1), 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d),
-                         lambda bi, j, tbl, lens: (
-                             jnp.minimum(tbl[bi, j], n_pool - 1), 0, 0, 0)),
+            pl.BlockSpec((1, 1, h, d), lambda i, tbl, lens: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         out_specs=pl.BlockSpec((1, 1, h, d),
-                               lambda bi, j, tbl, lens: (bi, 0, 0, 0)),
+                               lambda i, tbl, lens: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),     # acc
-            pltpu.VMEM((h, 1), jnp.float32),     # running max
-            pltpu.VMEM((h, 1), jnp.float32),     # running sum
+            pltpu.VMEM((2, chunk, bs, hkv, d), k_pool.dtype),
+            pltpu.VMEM((2, chunk, bs, hkv, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),     # (K or V, buffer)
+            pltpu.SMEM((1,), jnp.int32),         # buffer of the next fold
         ],
     )
     return pl.pallas_call(
@@ -274,7 +340,7 @@ def flash_decode_paged_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
       q, k_pool, v_pool)

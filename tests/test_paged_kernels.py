@@ -142,6 +142,58 @@ def test_gqa_paged_null_sentinel_fully_masked(pallas_interpret):
                                rtol=0, atol=ATOL["float32"])
 
 
+@pytest.mark.parametrize("bs,mb,dtype", [
+    (8, 5, "float32"), (2, 9, "float32"), (16, 11, "float32"),
+    (32, 9, "bfloat16"), (4, 16, "bfloat16")])
+def test_gqa_paged_walk_stops_at_the_length(pallas_interpret, bs, mb, dtype):
+    """Nothing past a sequence's length is read: every pool block that no
+    sequence maps below its length holds NaN, one slot keeps stale
+    mapped entries past its length, and the output is finite and equal
+    to the reference on a copy of the pool with those blocks zeroed.
+    With more than 128 // bs table entries the walk takes several
+    chunks, the last one partial."""
+    rng = np.random.default_rng((bs, mb))
+    hkv, q_per_kv, d = 2, 3, 8
+    h, s_g = hkv * q_per_kv, mb * bs
+    # ragged lengths well below the table's width, on and off block edges
+    lens = np.asarray([1, bs, bs + 1, max(2, s_g * 2 // 5), 1], np.int32)
+    batch = len(lens)
+    n_pool = batch * mb + 3
+    perm = rng.permutation(n_pool)
+    tables = np.full((batch, mb), n_pool, np.int32)
+    used = 0
+    for i in range(batch - 1):          # the last slot is inactive: NULL
+        nb = -(-int(lens[i]) // bs)
+        tables[i, :nb] = perm[used:used + nb]
+        used += nb
+    live = perm[:used]
+    poisoned = perm[used:]
+    nb3 = -(-int(lens[3]) // bs)
+    tables[3, nb3] = poisoned[0]        # stale entries, mapped past the length
+    tables[3, mb - 1] = poisoned[1]
+    tables[0, 1] = poisoned[2]
+    q = jnp.asarray(rng.standard_normal((batch, 1, h, d)), dtype)
+    clean_k = rng.standard_normal((n_pool, bs, hkv, d))
+    clean_v = rng.standard_normal((n_pool, bs, hkv, d))
+    clean_k[poisoned] = 0.0
+    clean_v[poisoned] = 0.0
+    k_pool, v_pool = clean_k.copy(), clean_v.copy()
+    k_pool[poisoned] = np.nan
+    v_pool[poisoned] = np.nan
+    assert np.isfinite(k_pool[live]).all()
+    tables, kv_lens = jnp.asarray(tables), jnp.asarray(lens)
+    out_pal = attn_ops.flash_decode_paged(
+        q, jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype), tables,
+        kv_lens, impl="pallas", interpret=pallas_interpret)
+    out_ref = attn_ops.flash_decode_paged(
+        q, jnp.asarray(clean_k, dtype), jnp.asarray(clean_v, dtype), tables,
+        kv_lens, impl="reference")
+    out_pal = np.asarray(out_pal, np.float32)
+    assert np.isfinite(out_pal).all()
+    np.testing.assert_allclose(np.asarray(out_ref, np.float32), out_pal,
+                               rtol=0, atol=ATOL[dtype])
+
+
 def test_model_level_paged_decode_bitwise_fp32(pallas_interpret):
     """Full-model parity: decode_paged logits with attention_impl=
     'pallas' match the reference engine path in fp32 within tolerance

@@ -11,10 +11,15 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and it keeps the
 library until it exits.
 """
+import re
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+from jaxlib._jax import HloPrintOptions
 
 from repro.kernels.flash_attention.flash_attention import (
     flash_attention_pallas, flash_decode_paged_pallas)
@@ -64,6 +69,32 @@ def test_paged_gqa_decode_compiles_at_tinyllama_widths(one_chip):
         s((n_pool, bs, hkv, d), jnp.bfloat16),
         s((b, mb), jnp.int32), s((b,), jnp.int32))
     assert compiled.memory_analysis() is not None
+
+
+def test_paged_gqa_decode_compiles_at_phi4_mini_widths(one_chip):
+    # phi4-mini, as the serving benchmark runs it: 24 query heads over 8
+    # kv heads of 128, 16 decode slots, 16-token blocks, a 2048-token
+    # window over a 2048-block pool
+    b, h, hkv, d, bs, mb, n_pool = 16, 24, 8, 128, 16, 128, 2048
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = _compile(
+        flash_decode_paged_pallas,
+        s((b, 1, h, d), jnp.bfloat16),
+        s((n_pool, bs, hkv, d), jnp.bfloat16),
+        s((n_pool, bs, hkv, d), jnp.bfloat16),
+        s((b, mb), jnp.int32), s((b,), jnp.int32))
+    # the roofline metric finds the kernel in the device trace by its HLO
+    # line, operand shapes included: one query per slot out, the block
+    # table as the first operand
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    sys.path.insert(0, str(bench))
+    from benchlib import spec
+    kernel = spec.metric_reader("paged_attn_roofline.serve").KERNEL
+    shapes = HloPrintOptions()
+    shapes.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(shapes)
+    assert re.search(kernel, text), [
+        line for line in text.splitlines() if "custom-call" in line]
 
 
 def test_flash_prefill_compiles_at_olmo_widths(one_chip):
