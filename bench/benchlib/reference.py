@@ -20,6 +20,10 @@ weights bit for bit.
 ``precision="fp8"`` is the control: the same computation with every
 matmul operand rounded to float8 e4m3 with a per-tensor scale, the step
 below the bfloat16 the configurations compute in.
+
+The interface of a reference module is in ``spec.py``: this one also
+names what it does not model (``unmodelled``) and gives the dense FLOP
+counts of ``flops.py``.
 """
 from __future__ import annotations
 
@@ -31,9 +35,29 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchlib.flops import (decode_flops, prefill_flops,  # noqa: F401
+                            train_flops_per_token)
+
 HIGHEST = jax.lax.Precision.HIGHEST
 NORM_EPS = 1e-5
 FP8_MAX = 448.0        # largest finite float8 e4m3fn
+
+
+def unmodelled(mc) -> List[str]:
+    """What the program's ``ModelConfig`` would run that this dense,
+    tied, SwiGLU decoder does not model."""
+    off = {"logit_softcap": bool(mc.logit_softcap),
+           "qk_norm": mc.qk_norm,
+           "moe": mc.moe.enabled,
+           "mla": mc.mla.enabled,
+           "ssm": mc.ssm.enabled,
+           "xlstm": mc.xlstm.enabled,
+           "hybrid": mc.hybrid.enabled,
+           f"frontend {mc.frontend}": mc.frontend != "token",
+           "untied embeddings": not mc.tie_embeddings,
+           f"activation {mc.activation}": mc.activation != "swiglu",
+           f"norm {mc.norm}": mc.norm not in ("rmsnorm", "nonparam_ln")}
+    return [name for name, on in off.items() if on]
 
 
 def fake_fp8(x: jnp.ndarray) -> jnp.ndarray:

@@ -10,7 +10,8 @@ of the backlog run's first decode step and closes by raising from the
 decode wrapper. Then the engine is freed and the float32 reference
 scores a sample of the finished requests, drawn from the seed with the
 longest among them: for every served token, how far its reference logit
-lies below the reference's best at that position.
+lies below the reference's best at that position. The reference is the
+module the configuration names (``spec.reference``).
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from benchlib import check, device, program, reference, stats, traffic
+from benchlib import check, device, program, spec, stats, traffic
 from benchlib.window import Window, WindowClosed
 
 ITL_PERCENTILE = 95.0
@@ -185,8 +186,8 @@ def run(cfg: Dict, mix: Dict, seed: int, devs, window: Window,
     complete = all(len(f["served"]) == want[f["rid"]][1] for f in picked)
     seqs = [{"prompt": f["orig"], "served": f["served"]} for f in picked]
     t_ref = time.perf_counter()
-    gaps = reference.served_logit_gaps(cfg, s31, seqs,
-                                       ("float32",) + tuple(controls))
+    gaps = spec.reference(cfg).served_logit_gaps(
+        cfg, s31, seqs, ("float32",) + tuple(controls))
     readings = {"program": {"served_gap": float(np.max(gaps["served"]))
                             if len(gaps["served"]) else float("inf")},
                 "reference_s": time.perf_counter() - t_ref}

@@ -9,18 +9,43 @@ its own, named after it:
 - ``bench/limits/<workload>.json``  the correctness limits of the cell
 - ``bench/metrics/<metric>.py``     the reader of one per-layer metric
 
-so a later cell, mix or metric is added by adding files and entries.
+and each configuration names its architecture's plain reference, a
+module at ``cfg["reference"]`` (a path from the repository's root), so
+a later cell, mix, metric or architecture is added by adding files and
+entries.
+
+A reference module provides:
+
+- ``served_logit_gaps(cfg, seed, sequences, precisions)``, for serving:
+  for each sequence (``prompt`` and ``served`` token lists) and each
+  served position, the gap between the float32 reference's best logit
+  and its logit for the served token (key ``served``), and for each
+  other precision the gap of the token that precision puts first (key:
+  the precision);
+- ``train_reference(cfg, opt, seed, batches, precision=, devices=,
+  rows_of=)``, for training: the first ``len(batches)`` optimizer steps
+  from the seed's weights, as ``losses`` per step, ``first_grad`` and
+  ``delta`` (leaf name -> norm, named as the program's leaves are);
+- ``"fp8"`` among the precisions of both: the control, the same
+  computation one step below the bfloat16 the configurations compute in;
+- ``unmodelled(mc)``: the names of what the program's ``ModelConfig``
+  runs that the reference does not model (empty where it models all);
+- ``train_flops_per_token(cfg, seq_len)``, ``decode_flops(cfg,
+  contexts)`` and ``prefill_flops(cfg, prompt_lens)``: the model FLOPs
+  of the architecture, read by the ``mfu.*`` metrics.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from types import ModuleType
 from typing import Any, Dict, List
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+_REFERENCES: Dict[Path, ModuleType] = {}
 
 
 def _load_json(path: Path) -> Dict[str, Any]:
@@ -62,10 +87,27 @@ def metrics_for(workload_name: str, trace: bool) -> List[Dict[str, Any]]:
     return out
 
 
-def metric_reader(name: str) -> ModuleType:
-    path = BENCH / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+def _load_module(name: str, path: Path) -> ModuleType:
+    mod_spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[name] = mod     # as an import would (dataclasses need it)
     mod_spec.loader.exec_module(mod)
     return mod
+
+
+def metric_reader(name: str) -> ModuleType:
+    return _load_module("bench_metric_" + name.replace(".", "_"),
+                        BENCH / "metrics" / f"{name}.py")
+
+
+def reference(cfg: Dict[str, Any]) -> ModuleType:
+    """The plain reference module that configuration ``cfg`` names,
+    loaded once per process (its jitted functions keep their caches)."""
+    if "reference" not in cfg:
+        raise SystemExit(f"bench: configuration {cfg['name']!r} names no "
+                         f"reference module")
+    path = (ROOT / cfg["reference"]).resolve()
+    if path not in _REFERENCES:
+        _REFERENCES[path] = _load_module(
+            f"bench_reference_{len(_REFERENCES)}", path)
+    return _REFERENCES[path]

@@ -11,7 +11,8 @@ parameters' change over the checked steps (as step 4 receives them).
 The window opens at the completion of step ``WARM_STEPS`` and closes
 by raising from the wrapper, which ends the loop before ``train()``
 saves its final checkpoint. Then the program's state is freed and the
-float32 reference runs the checked steps on the same rows.
+float32 reference that the configuration names (``spec.reference``)
+runs the checked steps on the same rows.
 """
 from __future__ import annotations
 
@@ -24,7 +25,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from benchlib import check, device, program, reference
+from benchlib import check, device, program, spec
 from benchlib.window import Window, WindowClosed
 
 CHECKED_STEPS = 3
@@ -212,6 +213,7 @@ def run(cfg: Dict, mix: Dict, seed: int, devs, work: Path,
 
     prog = {"losses": rec.losses, "first_grad": rec.first_grad,
             "delta": rec.delta}
+    reference = spec.reference(cfg)
     t_ref = time.perf_counter()
     ref = reference.train_reference(cfg, opt, s31, rec.batches,
                                     devices=devs)
@@ -270,5 +272,5 @@ def fault_run(cfg, opt, s31, batches, devs, fault: str) -> Dict:
             rows.append(real[real < per_rank])
         else:
             raise ValueError(fault)
-    return reference.train_reference(cfg, opt, s31, batches, devices=devs,
-                                     rows_of=rows)
+    return spec.reference(cfg).train_reference(cfg, opt, s31, batches,
+                                               devices=devs, rows_of=rows)

@@ -7,8 +7,9 @@
 Runs the cell once per seed in one process (the set-up compiles once)
 and prints, per seed, one JSON line of readings: ``program`` (the timed
 path against the float32 reference) and, for the first
-``--control-seeds`` seeds, the fp8 control (the reference computed with
-fp8 matmuls in the program's place) and, for training cells, each
+``--control-seeds`` seeds, the fp8 control (the reference module that
+the configuration names, computed with fp8 matmuls, in the program's
+place) and, for training cells, each
 planted fault (half of each batch left out; on several chips the
 exchange left out), each also judged against the cell's committed
 limits as the program is (``stand_ins``: each must come out

@@ -5,6 +5,7 @@ that its logits spread as a real model's do (its top logits are not
 all within rounding of each other)."""
 from __future__ import annotations
 
+import functools
 import importlib.util
 import sys
 from pathlib import Path
@@ -18,6 +19,12 @@ for p in (str(BENCH), str(ROOT / "src")):
 from benchlib import spec  # noqa: E402
 
 SMALL = dict(num_layers=2, num_heads=4, d_ff=128)
+# the cells' own limits hold the full-size readings; the small
+# stand-ins read the float32 reference ~1e-3 off (grad_gap, delta_gap)
+# and ~1e-2 (served_gap), faults 3e-2 and more, and the serving
+# stand-in's fp8 control ~0.25
+TRAIN_LIMITS = {"grad_gap": 0.01, "delta_gap": 0.01}
+SERVE_LIMITS = {"served_gap": 0.08}
 
 
 def olmo():
@@ -45,6 +52,7 @@ def serve_mix():
                                "sigma": 0.5})
 
 
+@functools.lru_cache(maxsize=None)
 def harness():
     """bench/run.py as a module (its name would clash as ``run``)."""
     mod_spec = importlib.util.spec_from_file_location(
@@ -52,3 +60,18 @@ def harness():
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod
+
+
+def run(cfg, mix, limits, seed=2 ** 33 + 5, seconds=0.5, **kw):
+    """One run of a cell on the first CPU device, through the harness's
+    ``run_cell``, reporting the metrics of the cell of the mix's kind."""
+    import time
+
+    import jax
+
+    kind = "olmo1b-4l-train-1chip" if mix["kind"] == "train" \
+        else "phi4mini-decode-heavy"
+    return harness().run_cell(
+        {"name": "cpu-test", "chips": 1}, cfg, mix, limits,
+        spec.metrics_for(kind, False), seed, seconds, False,
+        jax.devices()[:1], time.perf_counter(), **kw)

@@ -8,7 +8,6 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
-import time
 from pathlib import Path
 
 import pytest
@@ -16,25 +15,10 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import bench_tiny  # noqa: E402
-from benchlib import spec  # noqa: E402
 
-R = bench_tiny.harness()
-# the cells' own limits hold the full-size readings; the small
-# stand-ins read the float32 reference ~1e-3 off (grad_gap, delta_gap)
-# and ~1e-2 (served_gap), faults 3e-2 and more, and the serving
-# stand-in's fp8 control ~0.25
-TRAIN_LIMITS = {"grad_gap": 0.01, "delta_gap": 0.01}
-SERVE_LIMITS = {"served_gap": 0.08}
-
-
-def _run(cfg, mix, limits, seed=2 ** 33 + 5, seconds=0.5, **kw):
-    import jax
-
-    kind = "olmo1b-4l-train-1chip" if mix["kind"] == "train" \
-        else "phi4mini-decode-heavy"
-    return R.run_cell({"name": "cpu-test", "chips": 1}, cfg, mix, limits,
-                      spec.metrics_for(kind, False), seed, seconds, False,
-                      jax.devices()[:1], time.perf_counter(), **kw)
+TRAIN_LIMITS = bench_tiny.TRAIN_LIMITS
+SERVE_LIMITS = bench_tiny.SERVE_LIMITS
+_run = bench_tiny.run
 
 
 def test_refuses_a_machine_without_a_tpu():

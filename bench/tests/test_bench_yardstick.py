@@ -11,9 +11,11 @@ from pathlib import Path
 import pytest
 
 BENCH = Path(__file__).resolve().parents[1]
-sys.path.insert(0, str(BENCH))
+for _p in (str(BENCH), str(BENCH.parent / "src")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
-from benchlib import check, flops, spec, stats, traffic  # noqa: E402
+from benchlib import check, flops, program, spec, stats, traffic  # noqa: E402
 from benchlib import trace as tr  # noqa: E402
 
 
@@ -256,7 +258,9 @@ def test_every_cell_finds_its_files_by_name():
     for m in names:
         assert hasattr(spec.metric_reader(m), "read")
     for w in bench["workloads"]:
-        spec.config(w["config"])
+        cfg = spec.config(w["config"])
+        ref = spec.reference(cfg)
+        assert ref.unmodelled(program.model_config(cfg)) == []
         assert spec.traffic(w["traffic"])["kind"] in ("train", "serve")
         lim = spec.limits(w["name"])
         assert lim and all(v > 0 for v in lim.values())
@@ -264,3 +268,23 @@ def test_every_cell_finds_its_files_by_name():
         assert spec.metrics_for(w["name"], True)
     with open(BENCH / "peaks.json") as f:
         assert "TPU v5 lite" in json.load(f)["devices"]
+
+
+def test_mfu_readers_count_the_references_flops_as_flops_py_does():
+    peaks = {"bf16_flops_per_s": 197e12}
+    serve = {"kind": "serve", "cfg": spec.config("phi4-mini-3.8b"),
+             "traced_contexts": [[100, 300], [101, 301, 7]],
+             "traced_prompts": [64, 500], "chips": 1, "peaks": peaks,
+             "window_s": 4.0}
+    cfg = serve["cfg"]
+    need = (flops.decode_flops(cfg, [100, 300])
+            + flops.decode_flops(cfg, [101, 301, 7])
+            + flops.prefill_flops(cfg, [64, 500]))
+    assert spec.metric_reader("mfu.serve").read(serve) == \
+        100.0 * need / (4.0 * 197e12)
+    train = {"kind": "train", "cfg": spec.config("olmo-1b-4l"),
+             "mix": {"seq_len": 2048}, "traced_tokens": 11 * 8192,
+             "chips": 1, "peaks": peaks, "window_s": 4.1}
+    need = 11 * 8192 * flops.train_flops_per_token(train["cfg"], 2048)
+    assert spec.metric_reader("mfu.train").read(train) == \
+        100.0 * need / (4.1 * 197e12)
