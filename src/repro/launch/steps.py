@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import (ModelConfig, OptimizerConfig, ShapeConfig,
                                 TrainConfig)
 from repro.core import accumulate as acc
@@ -75,6 +76,7 @@ from repro.launch.mesh import dp_axes as mesh_dp_axes, dp_size, tp_axis
 from repro.models.blocks import ParallelCtx
 from repro.models.model import Model
 from repro.optim import adam, lamb, schedules
+from repro.roofline.hlo import exchange_bytes
 
 logger = logging.getLogger(__name__)
 
@@ -1451,6 +1453,25 @@ def _fed_batch_specs(cfg: ModelConfig, tcfg: TrainConfig,
     return shr.batch_specs(cfg, mesh, rows)
 
 
+def _counted(jitted):
+    """The jitted train step, compiled ahead of its first call so that
+    the one executable the calls run is also the one whose collectives
+    are counted: ``train.exchange_bytes`` (``obs.gauge``), the bytes
+    they put out on one device in one step (0 on one device)."""
+    compiled = None
+
+    def step(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
+        nonlocal compiled
+        if compiled is None:
+            compiled = jitted.lower(state, batch).compile()
+            obs.gauge("train.exchange_bytes",
+                      exchange_bytes(compiled.as_text()))
+        return compiled(state, batch)
+
+    step.lower = jitted.lower
+    return step
+
+
 def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                      ) -> Callable[[TrainState, Dict], Tuple[TrainState,
                                                              Dict]]:
@@ -1494,13 +1515,13 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                                          layout=layout)
         specs = state_specs(model, tcfg, mesh)
         bspecs = _fed_batch_specs(cfg, tcfg, mesh)
-        return jax.jit(
+        return _counted(jax.jit(
             pipe_step,
             in_shardings=(shr.named(mesh, specs),
                           shr.named(mesh, bspecs)),
             out_shardings=(shr.named(mesh, specs), None),
             donate_argnums=(0,),
-        )
+        ))
 
     if overlap and tcfg.het.overlap == "backward":
         # staged layer-by-layer backward with in-backprop bucket
@@ -1513,13 +1534,13 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
             fused_stream=fused_stream)
         specs = state_specs(model, tcfg, mesh)
         bspecs = _fed_batch_specs(cfg, tcfg, mesh)
-        return jax.jit(
+        return _counted(jax.jit(
             bwd_step,
             in_shardings=(shr.named(mesh, specs),
                           shr.named(mesh, bspecs)),
             out_shardings=(shr.named(mesh, specs), None),
             donate_argnums=(0,),
-        )
+        ))
 
     # inside a manual region the manual axes must not appear in sharding
     # constraints — hierarchical keeps "data" automatic inside the pod
@@ -1809,12 +1830,12 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
 
     specs = state_specs(model, tcfg, mesh)
     bspecs = _fed_batch_specs(cfg, tcfg, mesh)
-    return jax.jit(
+    return _counted(jax.jit(
         train_step,
         in_shardings=(shr.named(mesh, specs), shr.named(mesh, bspecs)),
         out_shardings=(shr.named(mesh, specs), None),
         donate_argnums=(0,),
-    )
+    ))
 
 
 # --------------------------------------------------------------------------

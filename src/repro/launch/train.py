@@ -367,10 +367,19 @@ def train(args, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
                                     s.device.id: int(s.data.shape[0])
                                     for s in batch["inputs"]
                                     .addressable_shards}
-                            with obs.span("train.step",
-                                          step=step + 1) as timed:
+                            # rows: the real rows each rank takes
+                            # this step, as the plan in force deals them
+                            with obs.span(
+                                    "train.step", step=step + 1,
+                                    ranks=n_dp,
+                                    rows=plan.rows_per_rank.tolist()
+                                    ) as timed:
                                 state, metrics = jax.block_until_ready(
                                     step_fn(state, batch))
+                                # set when the step was compiled
+                                timed.attrs["exchange_bytes"] = \
+                                    obs.counters().get(
+                                        "train.exchange_bytes", 0)
                             dt = timed.seconds
                             step += 1
                             epoch, batch_in_epoch = b_epoch, b_index

@@ -12,7 +12,8 @@ serving engine's batch assembly, a fetch of logits) twice:
 
 Spans nest per thread; ``parent`` is the id of the enclosing span of
 the same thread, 0 at the top. :func:`event` records a point in time
-(a zero-length span), :func:`count` a named counter.
+(a zero-length span), :func:`count` a named counter, :func:`gauge` a
+named reading that each new one replaces.
 
 Every jaxpr trace and every executable build (a compile, or a load
 from the persistent compilation cache) of a jitted function is counted
@@ -118,6 +119,13 @@ def event(name: str, **attrs: Any) -> int:
 def count(name: str, n: int = 1) -> None:
     with _lock:
         _counters[name] += n
+
+
+def gauge(name: str, value: int) -> None:
+    """Set counter ``name`` to ``value``: a reading of the program as it
+    now is (the bytes a built step exchanges), not a tally."""
+    with _lock:
+        _counters[name] = value
 
 
 def spans() -> List[Span]:

@@ -20,7 +20,11 @@ traffic at all. This module rebuilds whole-program costs from
   * collectives: ``all-gather``/``all-reduce``/``reduce-scatter``/
     ``all-to-all``/``collective-permute`` result bytes scaled by the
     ring-model wire cost, split ICI vs DCN by whether the replica group
-    crosses a 256-chip pod boundary.
+    crosses a 256-chip pod boundary;
+  * exchange bytes (``exchange_bytes``, the train step's
+    ``train.exchange_bytes``): the same collectives' result bytes, each
+    computation counted once per call site and each while body once per
+    trip.
 """
 from __future__ import annotations
 
@@ -48,6 +52,7 @@ _OP_RE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
 _DOT_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
 _WHILE_RE = re.compile(r"condition=%?([\w.\-]+), body=%?([\w.\-]+)")
+_TO_APPLY_RE = re.compile(r"to_apply=%?([\w.\-]+)")
 
 _BYTE_SKIP_OPS = {
     "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
@@ -158,47 +163,58 @@ def _entry_name(hlo: str) -> Optional[str]:
     return None
 
 
+def _trip_count(comps: Dict[str, List[str]], cond: str) -> int:
+    """A scan's trip count: the largest constant its loop condition
+    compares the counter with (loops count up from 0)."""
+    consts = [int(x) for line in comps.get(cond, [])
+              for x in _CONST_RE.findall(line)]
+    return max(consts + [1])
+
+
 def _call_weights(hlo: str, comps: Dict[str, List[str]]
                   ) -> Tuple[Dict[str, float], Dict[str, bool]]:
-    """computation -> execution weight; computation -> is_fusion_body."""
-    edges: Dict[str, List[Tuple[str, float]]] = {}
+    """computation -> execution weight; computation -> is_fusion_body.
+
+    A computation's weight is the sum over its call sites of the
+    caller's weight, times the trip count where the site is a ``while``
+    body. Call sites are ``calls=`` (fusions, ``async-start``), a
+    ``call``'s ``to_apply=`` and a ``while``'s ``body=``; the
+    ``async-update``/``async-done`` halves name their ``async-start``'s
+    computation again and are not counted."""
+    callers: Dict[str, List[Tuple[str, float]]] = {}
     fusion_body: Dict[str, bool] = {}
 
     for name, lines in comps.items():
         for line in lines:
+            op = _line_op(line)
             wm = _WHILE_RE.search(line)
-            if " while(" in line and wm:
-                cond, body = wm.group(1), wm.group(2)
-                consts: List[int] = []
-                for cl in comps.get(cond, []):
-                    consts += [int(x) for x in _CONST_RE.findall(cl)]
-                trip = max(consts) if consts else 1
-                edges.setdefault(name, []).append((body, float(max(trip,
-                                                                   1))))
+            if op == "while" and wm:
+                callers.setdefault(wm.group(2), []).append(
+                    (name, float(_trip_count(comps, wm.group(1)))))
                 continue
-            cm = _CALLS_RE.search(line)
-            if cm:
-                edges.setdefault(name, []).append((cm.group(1), 1.0))
-                if " fusion(" in line:
-                    fusion_body[cm.group(1)] = True
+            if op in ("async-update", "async-done"):
+                continue
+            callees = _CALLS_RE.findall(line)
+            if op == "call":
+                callees += _TO_APPLY_RE.findall(line)
+            for callee in callees:
+                callers.setdefault(callee, []).append((name, 1.0))
+                if op == "fusion":
+                    fusion_body[callee] = True
 
     entry = _entry_name(hlo) or (list(comps)[-1] if comps else None)
-    weights: Dict[str, float] = {c: 0.0 for c in comps}
-    if entry in weights:
-        weights[entry] = 1.0
-    for _ in range(8):                    # nested loops: iterate to fixpoint
-        changed = False
-        for name in list(comps):
-            w = weights.get(name, 0.0)
-            if w <= 0:
-                continue
-            for callee, mult in edges.get(name, []):
-                if callee in weights and w * mult > weights[callee]:
-                    weights[callee] = w * mult
-                    changed = True
-        if not changed:
-            break
-    return weights, fusion_body
+    weights: Dict[str, float] = {}
+
+    def weight(name: str) -> float:
+        if name == entry:
+            return 1.0
+        if name not in weights:
+            weights[name] = 0.0           # a cycle adds nothing
+            weights[name] = sum(weight(c) * mult
+                                for c, mult in callers.get(name, []))
+        return weights[name]
+
+    return {c: weight(c) for c in comps}, fusion_body
 
 
 # --------------------------------------------------------------------------
@@ -393,3 +409,27 @@ def collective_stats(hlo: str, pod_size: int = 256) -> CollectiveStats:
             count += int(w)
     return CollectiveStats(bytes_by_type=by_type, ici_bytes=ici,
                            dcn_bytes=dcn, count=count)
+
+
+# --------------------------------------------------------------------------
+# exchange bytes: what the train step's collectives put out, per device
+# --------------------------------------------------------------------------
+
+# a collective's own opcode, or the ``-done`` half of an asynchronous one
+# (its ``-start`` half is not counted: its result carries the operand too)
+_EXCHANGE_OPS = frozenset(_COLLECTIVES) | frozenset(
+    f"{c}-done" for c in ("all-reduce", "all-gather", "collective-permute"))
+
+
+def exchange_bytes(hlo: str) -> int:
+    """Bytes the collectives of a compiled module put out on one device
+    in one execution: the result bytes of every ``all-gather``,
+    ``all-reduce``, ``reduce-scatter``, ``all-to-all`` and
+    ``collective-permute``, wherever it sits (fusions and called
+    computations included), weighted as :func:`_call_weights` weighs
+    its computation. A module on one device has none and reads 0."""
+    comps = _split_computations(hlo)
+    weights, _ = _call_weights(hlo, comps)
+    return int(sum(weights.get(name, 0.0) * _shape_bytes(_result_text(line))
+                   for name, lines in comps.items() for line in lines
+                   if _line_op(line) in _EXCHANGE_OPS))

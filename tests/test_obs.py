@@ -111,3 +111,86 @@ def test_a_retrace_is_counted_against_the_span_it_happened_in():
     c = obs.counters()
     assert c["jit.traces.scaled_sum"] == 2
     assert c["jit.compiles.scaled_sum"] == 2
+
+
+# -- the train step's exchange counter and span attributes ------------------
+
+TRAIN_ARGS = ["--arch", "olmo-1b", "--smoke", "--steps", "3",
+              "--global-batch", "8", "--seq-len", "16", "--warmup", "1",
+              "--log-every", "1000"]
+
+EXCHANGE_SCRIPT = r"""
+import json, sys
+from repro import obs
+from repro.launch import steps as steps_mod, train as train_mod
+from repro.roofline import hlo
+
+texts = []
+count = steps_mod.exchange_bytes
+
+
+def spy(text):
+    texts.append(text)
+    return count(text)
+
+
+steps_mod.exchange_bytes = spy
+args = train_mod.parse_args(json.loads(sys.argv[1]))
+train_mod.train(args)
+comps = hlo._split_computations(texts[0])
+once = sum(hlo._shape_bytes(hlo._result_text(line))
+           for lines in comps.values() for line in lines
+           if hlo._line_op(line) in hlo._EXCHANGE_OPS)
+print("RESULT", json.dumps({
+    "texts": len(texts), "counted": hlo.exchange_bytes(texts[0]),
+    "once": once, "whiles": texts[0].count(" while("),
+    "counters": obs.counters(),
+    "steps": [s.attrs for s in obs.spans() if s.name == "train.step"]}))
+"""
+
+
+def _train_in_child(tmp_path, devices):
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    argv = TRAIN_ARGS + ["--devices", devices,
+                         "--data-dir", str(tmp_path / "data"),
+                         "--ckpt-dir", str(tmp_path / "ckpt")]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", EXCHANGE_SCRIPT,
+                           json.dumps(argv)], cwd=tmp_path, env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT ")][-1]
+    return json.loads(line[len("RESULT "):])
+
+
+# 8 rows a step, dealt by the capacity plan: all 8 to one rank, or 2 to
+# each of four
+@pytest.mark.parametrize("devices,ranks,rows", [("4,1", 4, [2, 2, 2, 2]),
+                                                ("1,1", 1, [8])])
+def test_the_train_step_counts_its_exchange_once_per_build(
+        tmp_path, devices, ranks, rows):
+    got = _train_in_child(tmp_path, devices)
+    c = got["counters"]
+    # one trace and one executable build of the step, and the counter
+    # read from that executable's own HLO
+    assert got["texts"] == 1
+    assert c["jit.traces.train_step"] == c["jit.compiles.train_step"] == 1
+    assert c["train.exchange_bytes"] == got["counted"]
+    if ranks == 1:
+        assert got["counted"] == 0
+    else:
+        # the layer scan's collectives count once per layer
+        assert got["whiles"] >= 1
+        assert got["counted"] > got["once"] > 0
+    assert [s["step"] for s in got["steps"]] == [1, 2, 3]
+    for s in got["steps"]:
+        assert (s["ranks"], s["rows"]) == (ranks, rows)
+        assert s["exchange_bytes"] == got["counted"]

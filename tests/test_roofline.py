@@ -76,6 +76,90 @@ def test_collective_stats_and_pod_classification():
     assert cs.ici_bytes == 5 * ar_once
 
 
+ASYNC = textwrap.dedent("""\
+    HloModule jit_step, is_scheduled=true
+
+    %scatter (p1: f32[64]) -> f32[16] {
+      %p1 = f32[64]{0} parameter(0)
+      ROOT %rs = f32[16]{0} reduce-scatter(%p1), channel_id=5, replica_groups=[1,4]<=[4], dimensions={0}, to_apply=%add
+    }
+
+    %add (a: f32[], b: f32[]) -> f32[] {
+      %a = f32[] parameter(0)
+      %b = f32[] parameter(1)
+      ROOT %s = f32[] add(%a, %b)
+    }
+
+    %gather_fusion (p0: bf16[2,64]) -> bf16[8,64] {
+      %p0 = bf16[2,64]{1,0} parameter(0)
+      ROOT %ag = bf16[8,64]{1,0} all-gather(%p0), channel_id=3, replica_groups=[1,4]<=[4], dimensions={0}
+    }
+
+    ENTRY %main (arg: bf16[2,64]) -> (bf16[8,64], f32[16], f32[16]) {
+      %arg = bf16[2,64]{1,0} parameter(0)
+      %f = bf16[8,64]{1,0} fusion(%arg), kind=kCustom, calls=%gather_fusion
+      %x = f32[16]{0} constant({...})
+      %cps = (f32[16]{0}, f32[16]{0}, u32[], u32[]) collective-permute-start(%x), channel_id=4, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+      %cpd = f32[16]{0} collective-permute-done(%cps)
+      %y = f32[64]{0} constant({...})
+      %rss = ((f32[64]{0}), f32[16]{0}) async-start(%y), calls=%scatter
+      %rsd = f32[16]{0} async-done(%rss), calls=%scatter
+      ROOT %t = (bf16[8,64]{1,0}, f32[16]{0}, f32[16]{0}) tuple(%f, %cpd, %rsd)
+    }
+    """)
+
+
+def test_exchange_bytes_counts_collective_results_per_trip():
+    # the loop's all-reduce f32[8,16] (512 B) 5 times, the all-gather's
+    # f32[32,4] (512 B) once
+    assert H.exchange_bytes(SYNTH) == 5 * 512 + 512
+    # inside a fusion, and each asynchronous pair counted once, by its
+    # result: bf16[8,64] (1024 B), f32[16] (64 B) and the wrapped
+    # reduce-scatter's f32[16] (64 B)
+    assert H.exchange_bytes(ASYNC) == 1024 + 64 + 64
+    assert H.exchange_bytes(SYNTH.replace("all-reduce(", "add(")
+                            .replace("all-gather(", "add(")) == 0
+
+
+TWICE = textwrap.dedent("""\
+    HloModule jit_step, is_scheduled=true
+
+    %add (a: f32[], b: f32[]) -> f32[] {
+      %a = f32[] parameter(0)
+      %b = f32[] parameter(1)
+      ROOT %s = f32[] add(%a, %b)
+    }
+
+    %shared (p: f32[8,16]) -> f32[8,16] {
+      %p = f32[8,16]{1,0} parameter(0)
+      %w = f32[16,16]{1,0} constant({...})
+      %d = f32[8,16]{1,0} dot(%p, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+      ROOT %ar = f32[8,16]{1,0} all-reduce(%d), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add
+    }
+
+    ENTRY %main (arg: f32[8,16]) -> f32[8,16] {
+      %arg = f32[8,16]{1,0} parameter(0)
+      %c1 = f32[8,16]{1,0} call(%arg), to_apply=%shared
+      ROOT %c2 = f32[8,16]{1,0} call(%c1), to_apply=%shared
+    }
+    """)
+
+
+def test_call_weights_sum_over_call_sites():
+    # one computation called from two sites runs twice: its dot, its
+    # all-reduce and its exchange count twice, in every counter
+    comps = H._split_computations(TWICE)
+    weights, _ = H._call_weights(TWICE, comps)
+    assert (weights["main"], weights["shared"], weights["add"]) == (1, 2, 0)
+    assert H.program_costs(TWICE).flops == 2 * 4096
+    assert H.collective_stats(TWICE).bytes_by_type["all-reduce"] == \
+        2 * (2 * 512 * 3 // 4)
+    assert H.exchange_bytes(TWICE) == 2 * 512
+    # an asynchronous pair names its computation twice and runs it once
+    async_w, _ = H._call_weights(ASYNC, H._split_computations(ASYNC))
+    assert async_w["scatter"] == async_w["gather_fusion"] == 1
+
+
 def test_shape_bytes():
     assert H._shape_bytes("f32[8,16]") == 512
     assert H._shape_bytes("bf16[2,3] whatever pred[7]") == 12 + 7
