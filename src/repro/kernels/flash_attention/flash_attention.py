@@ -156,13 +156,14 @@ CHUNK_TOKENS = 128   # tokens folded per unit of work: one MXU tile of rows
 LANES = 128          # a vreg's lanes; the head dim is padded to a multiple
 
 
-def _paged_decode_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref,
+def _paged_decode_kernel(tables, lens, layer, q_ref, k_hbm, v_hbm, o_ref,
                          k_buf, v_buf, sems, slot_ref, *, scale: float,
                          block_size: int, max_blocks: int, chunk_blocks: int,
                          null_block: int, kv_heads: int, q_per_kv: int):
     """Grid (B,), sequential; step b attends slot b's query to its context.
 
-    The K/V pools stay in HBM. Slot b needs ``nb = ceil(lens[b] / bs)``
+    The layer-stacked K/V pools stay in HBM; only layer ``layer[0]``'s
+    blocks are read. Slot b needs ``nb = ceil(lens[b] / bs)``
     blocks; they are walked in ``ceil(nb / P)`` chunks of P blocks by an
     in-kernel loop, each block one async copy of its contiguous
     (bs, Hkv, D) slab into a VMEM chunk buffer. The buffer is doubled:
@@ -199,7 +200,8 @@ def _paged_decode_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref,
             @pl.when((j < nb) & (blk != null_block))
             def _copy():
                 for kv, hbm, buf in ((0, k_hbm, k_buf), (1, v_hbm, v_buf)):
-                    cp = pltpu.make_async_copy(hbm.at[blk], buf.at[slot, p],
+                    cp = pltpu.make_async_copy(hbm.at[layer[0], blk],
+                                               buf.at[slot, p],
                                                sems.at[kv, slot])
                     if start:
                         cp.start()
@@ -271,15 +273,17 @@ def _paged_decode_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref,
 
 def flash_decode_paged_pallas(
     q: jnp.ndarray,                      # (B, 1, H, D)
-    k_pool: jnp.ndarray,                 # (N, bs, Hkv, D)
+    k_pool: jnp.ndarray,                 # (L, N, bs, Hkv, D)
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,           # (B, MB) int32, NULL == N
     kv_lens: jnp.ndarray,                # (B,) int32 EFFECTIVE lengths
+    layer: jnp.ndarray,                  # () int32, the layer to attend
     *,
     softmax_scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Single-query GQA decode over a paged KV pool, gather in-kernel.
+    """Single-query GQA decode over layer ``layer`` of a layer-stacked
+    paged KV pool, gather in-kernel.
 
     ``kv_lens`` are the effective context lengths (positions
     ``>= kv_lens[i]`` are masked); the new token's K/V must already be
@@ -288,10 +292,11 @@ def flash_decode_paged_pallas(
     ``mode="fill"`` and running ``ref.mha_dense(causal=False,
     kv_len=kv_lens)`` (a streaming softmax sums in a different order).
 
-    One pallas_call, grid (B,): the block tables and lengths are
+    One pallas_call, grid (B,): the block tables, lengths and layer are
     scalar-prefetched, q and the output are (1, 1, H, D) blocks per
-    slot, and the pools stay in HBM. Each slot reads only its
-    ``ceil(kv_lens[i] / bs)`` mapped blocks, in chunks of
+    slot, and the whole pools stay in HBM, so a layer scan can carry
+    them and update them in place. Each slot reads only its
+    ``ceil(kv_lens[i] / bs)`` mapped blocks of the layer, in chunks of
     ``P = max(1, 128 // bs)`` blocks (at most MB), double-buffered in
     VMEM (see ``_paged_decode_kernel``); its HBM traffic is its context
     rounded up to a block, whatever the table's width.
@@ -303,13 +308,15 @@ def flash_decode_paged_pallas(
     pad = -d % LANES
     if pad:
         # Mosaic copies a slab out of an HBM array only at lane-aligned
-        # widths: zero lanes leave every score and output lane as it was
+        # widths: zero lanes leave every score and output lane as it was.
+        # Only the layer's slab is taken out and widened, not the pool
         widen = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
         out = flash_decode_paged_pallas(
-            widen(q), widen(k_pool), widen(v_pool), block_tables, kv_lens,
-            softmax_scale=scale, interpret=interpret)
+            widen(q), widen(k_pool[layer][None]), widen(v_pool[layer][None]),
+            block_tables, kv_lens, jnp.int32(0), softmax_scale=scale,
+            interpret=interpret)
         return out[..., :d]
-    n_pool, bs, hkv, _ = k_pool.shape
+    _, n_pool, bs, hkv, _ = k_pool.shape
     mb = block_tables.shape[1]
     chunk = min(max(1, CHUNK_TOKENS // bs), mb)
 
@@ -319,15 +326,16 @@ def flash_decode_paged_pallas(
         q_per_kv=h // hkv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, h, d), lambda i, tbl, lens: (i, 0, 0, 0)),
+            pl.BlockSpec((1, 1, h, d),
+                         lambda i, tbl, lens, lyr: (i, 0, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.HBM),
             pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         out_specs=pl.BlockSpec((1, 1, h, d),
-                               lambda i, tbl, lens: (i, 0, 0, 0)),
+                               lambda i, tbl, lens, lyr: (i, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, chunk, bs, hkv, d), k_pool.dtype),
             pltpu.VMEM((2, chunk, bs, hkv, d), v_pool.dtype),
@@ -343,4 +351,4 @@ def flash_decode_paged_pallas(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q, k_pool, v_pool)

@@ -48,23 +48,27 @@ def flash_attention(
 
 def flash_decode_paged(
     q: jnp.ndarray,                      # (B, 1, H, D)
-    k_pool: jnp.ndarray,                 # (N, bs, Hkv, D)
+    k_pool: jnp.ndarray,                 # (L, N, bs, Hkv, D)
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,           # (B, MB) int32, NULL == N
     kv_lens: jnp.ndarray,                # (B,) int32 effective lengths
+    layer: jnp.ndarray,                  # () int32
     *,
     softmax_scale: Optional[float] = None,
     impl: str = "reference",
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Single-query GQA decode over a paged pool (serving hot path).
+    """Single-query GQA decode over layer ``layer`` of a layer-stacked
+    paged pool (serving hot path).
 
     ``impl``:
       * "reference"/"dense" — materialize-then-attend: gather each
-        sequence's mapped blocks into a dense (B, MB*bs, Hkv, D) window
-        in HBM (NULL blocks fill with zeros) and run ``ref.mha_dense``.
-      * "pallas" — in-kernel block gather: the block-table lookup drives
-        the kernel's DMA index_map, so no window is ever materialized.
+        sequence's mapped blocks of the layer into a dense
+        (B, MB*bs, Hkv, D) window in HBM (NULL blocks fill with zeros)
+        and run ``ref.mha_dense``.
+      * "pallas" — in-kernel block gather: the kernel reads the layer's
+        blocks through the block table straight from the whole pool in
+        HBM, so no window and no per-layer slice is ever materialized.
         Within compute-dtype tolerance of the reference path.
 
     ``kv_lens`` are effective context lengths: positions >= kv_lens[i]
@@ -73,10 +77,10 @@ def flash_decode_paged(
     """
     if impl in ("reference", "dense"):
         b = q.shape[0]
-        k_g = k_pool.at[block_tables].get(
-            mode="fill", fill_value=0).reshape(b, -1, *k_pool.shape[2:])
-        v_g = v_pool.at[block_tables].get(
-            mode="fill", fill_value=0).reshape(b, -1, *v_pool.shape[2:])
+        k_g = k_pool.at[layer, block_tables].get(
+            mode="fill", fill_value=0).reshape(b, -1, *k_pool.shape[3:])
+        v_g = v_pool.at[layer, block_tables].get(
+            mode="fill", fill_value=0).reshape(b, -1, *v_pool.shape[3:])
         return ref.mha_dense(q, k_g, v_g, causal=False,
                              softmax_scale=softmax_scale, kv_len=kv_lens)
     if impl == "pallas":
@@ -84,6 +88,6 @@ def flash_decode_paged(
             flash_decode_paged_pallas,
         )
         return flash_decode_paged_pallas(
-            q, k_pool, v_pool, block_tables, kv_lens,
+            q, k_pool, v_pool, block_tables, kv_lens, layer,
             softmax_scale=softmax_scale, interpret=interpret)
     raise ValueError(f"unknown attention impl '{impl}'")

@@ -118,17 +118,17 @@ def mla_decode_pallas(q_abs, q_r, ckv, kr, kv_len, scale,
 # --------------------------------------------------------------------------
 
 
-def _paged_kernel(tables, lens, qa_ref, qr_ref, ckv_ref, kr_ref, out_ref,
-                  acc_ref, m_ref, l_ref, *, scale, block_size, max_blocks,
-                  null_block, heads):
+def _paged_kernel(tables, lens, layer, qa_ref, qr_ref, ckv_ref, kr_ref,
+                  out_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
+                  max_blocks, null_block, heads):
     """Grid (B, MB); j sequential. The chunk axis of the contiguous
     kernel becomes the sequence's logical block axis: each step's
-    (bs, r) latent tile is DMA'd straight from the pool block named by
-    the block table (scalar-prefetch index_map) — NULL blocks arrive
-    clamped and are zeroed, then fully masked by kv_len. The fp32
-    online-softmax state persists in scratch; the VMEM-resident ckv
-    tile is reused for both the score and the value matmul, preserving
-    the one-HBM-pass property on the paged pool.
+    (bs, r) latent tile is DMA'd straight from the pool block of layer
+    ``layer[0]`` named by the block table (scalar-prefetch index_map) —
+    NULL blocks arrive clamped and are zeroed, then fully masked by
+    kv_len. The fp32 online-softmax state persists in scratch; the
+    VMEM-resident ckv tile is reused for both the score and the value
+    matmul, preserving the one-HBM-pass property on the paged pool.
     """
     bi = pl.program_id(0)
     j = pl.program_id(1)
@@ -174,38 +174,42 @@ def _paged_kernel(tables, lens, qa_ref, qr_ref, ckv_ref, kr_ref, out_ref,
 
 
 def mla_decode_paged_pallas(q_abs, q_r, ckv_pool, kr_pool, block_tables,
-                            kv_lens, scale, *, interpret: bool = False):
-    """Absorbed-MLA decode over a paged latent pool, gather in-kernel.
+                            kv_lens, layer, scale, *,
+                            interpret: bool = False):
+    """Absorbed-MLA decode over layer ``layer`` of a layer-stacked paged
+    latent pool, gather in-kernel.
 
-    q_abs (B, H, r); q_r (B, H, Dr); ckv_pool (N, bs, r); kr_pool
-    (N, bs, Dr); block_tables (B, MB) int32 with NULL == N; kv_lens (B,)
-    int32 EFFECTIVE lengths (positions >= kv_lens[i] masked). Returns
+    q_abs (B, H, r); q_r (B, H, Dr); ckv_pool (L, N, bs, r); kr_pool
+    (L, N, bs, Dr); block_tables (B, MB) int32 with NULL == N; kv_lens
+    (B,) int32 EFFECTIVE lengths (positions >= kv_lens[i] masked); layer
+    () int32, scalar-prefetched with the tables and lengths, so the
+    index maps read the layer's blocks out of the whole pool. Returns
     (B, H, r) fp32 attention output in latent space, within compute-
     dtype tolerance of the materialize-then-attend reference.
     """
     b, h, r = q_abs.shape
     dr = q_r.shape[-1]
-    n_pool, bs, _ = ckv_pool.shape
+    _, n_pool, bs, _ = ckv_pool.shape
     mb = block_tables.shape[1]
+
+    def pool_block(bi, j, tbl, lens, lyr):
+        return lyr[0], jnp.minimum(tbl[bi, j], n_pool - 1), 0, 0
 
     kernel = functools.partial(
         _paged_kernel, scale=float(scale), block_size=bs, max_blocks=mb,
         null_block=n_pool, heads=h)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((1, h, r), lambda bi, j, tbl, lens: (bi, 0, 0)),
-            pl.BlockSpec((1, h, dr), lambda bi, j, tbl, lens: (bi, 0, 0)),
-            pl.BlockSpec((1, bs, r),
-                         lambda bi, j, tbl, lens: (
-                             jnp.minimum(tbl[bi, j], n_pool - 1), 0, 0)),
-            pl.BlockSpec((1, bs, dr),
-                         lambda bi, j, tbl, lens: (
-                             jnp.minimum(tbl[bi, j], n_pool - 1), 0, 0)),
+            pl.BlockSpec((1, h, r), lambda bi, j, tbl, lens, lyr: (bi, 0, 0)),
+            pl.BlockSpec((1, h, dr),
+                         lambda bi, j, tbl, lens, lyr: (bi, 0, 0)),
+            pl.BlockSpec((pl.squeezed, 1, bs, r), pool_block),
+            pl.BlockSpec((pl.squeezed, 1, bs, dr), pool_block),
         ],
         out_specs=pl.BlockSpec((1, h, r),
-                               lambda bi, j, tbl, lens: (bi, 0, 0)),
+                               lambda bi, j, tbl, lens, lyr: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, r), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -220,4 +224,5 @@ def mla_decode_paged_pallas(q_abs, q_r, ckv_pool, kr_pool, block_tables,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q_abs, q_r, ckv_pool, kr_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q_abs, q_r, ckv_pool,
+      kr_pool)

@@ -76,7 +76,7 @@ from repro.launch.mesh import dp_axes as mesh_dp_axes, dp_size, tp_axis
 from repro.models.blocks import ParallelCtx
 from repro.models.model import Model
 from repro.optim import adam, lamb, schedules
-from repro.roofline.hlo import exchange_bytes
+from repro.roofline.hlo import exchange_bytes, pool_move_bytes
 
 logger = logging.getLogger(__name__)
 
@@ -1453,22 +1453,29 @@ def _fed_batch_specs(cfg: ModelConfig, tcfg: TrainConfig,
     return shr.batch_specs(cfg, mesh, rows)
 
 
-def _counted(jitted):
-    """The jitted train step, compiled ahead of its first call so that
-    the one executable the calls run is also the one whose collectives
-    are counted: ``train.exchange_bytes`` (``obs.gauge``), the bytes
-    they put out on one device in one step (0 on one device)."""
+def _counted(jitted, gauge: str, count: Callable[[str], int]):
+    """The jitted step, compiled ahead of its first call so that the one
+    executable the calls run is also the one that is counted: ``gauge``
+    (``obs.gauge``) is set to ``count`` of its optimized HLO text.
+
+    A call with other argument types than the first goes through the
+    jit, which traces and compiles for them; ``_cache_size()`` counts
+    those executables with the first, as a jit's own does, so that the
+    serving engine's retrace guard still sees a second compile."""
     compiled = None
 
-    def step(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
+    def step(*args):
         nonlocal compiled
         if compiled is None:
-            compiled = jitted.lower(state, batch).compile()
-            obs.gauge("train.exchange_bytes",
-                      exchange_bytes(compiled.as_text()))
-        return compiled(state, batch)
+            compiled = jitted.lower(*args).compile()
+            obs.gauge(gauge, count(compiled.as_text()))
+        try:
+            return compiled(*args)
+        except TypeError:          # argument types it was not built for
+            return jitted(*args)
 
     step.lower = jitted.lower
+    step._cache_size = lambda: (compiled is not None) + jitted._cache_size()
     return step
 
 
@@ -1521,7 +1528,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                           shr.named(mesh, bspecs)),
             out_shardings=(shr.named(mesh, specs), None),
             donate_argnums=(0,),
-        ))
+        ), "train.exchange_bytes", exchange_bytes)
 
     if overlap and tcfg.het.overlap == "backward":
         # staged layer-by-layer backward with in-backprop bucket
@@ -1540,7 +1547,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                           shr.named(mesh, bspecs)),
             out_shardings=(shr.named(mesh, specs), None),
             donate_argnums=(0,),
-        ))
+        ), "train.exchange_bytes", exchange_bytes)
 
     # inside a manual region the manual axes must not appear in sharding
     # constraints — hierarchical keeps "data" automatic inside the pod
@@ -1835,7 +1842,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
         in_shardings=(shr.named(mesh, specs), shr.named(mesh, bspecs)),
         out_shardings=(shr.named(mesh, specs), None),
         donate_argnums=(0,),
-    ))
+    ), "train.exchange_bytes", exchange_bytes)
 
 
 # --------------------------------------------------------------------------
@@ -1975,8 +1982,15 @@ def build_paged_decode_step(model: Model, mesh: Mesh, layout,
 
     One fixed shape for the whole serve loop — per-sequence depth lives
     in ``kv_lens``, membership in the block tables — so the engine can
-    assert the function never retraces. The pool is donated (argnum 2):
-    decode updates it in place, no per-step full-cache copy.
+    assert the function never retraces. The pool is donated (argnum 2)
+    and the output pool aliases it; the layer scan carries the whole
+    pool and each layer scatters its tokens into it in place
+    (``transformer.decode_step_paged``), so no layer's pool is sliced,
+    restacked or copied. The step is compiled ahead of its first call
+    (``_counted``) and ``serve.pool_move_bytes`` (``obs.gauge``) set to
+    the bytes that executable moves in copies, slices and updates that
+    hold one layer's pool slab or more (``roofline.hlo.pool_move_bytes``):
+    0 when the pool stays in place.
     """
     cfg = model.cfg
     ctx = make_parallel_ctx(mesh)
@@ -1993,17 +2007,22 @@ def build_paged_decode_step(model: Model, mesh: Mesh, layout,
     cspecs = shr.paged_cache_specs(cfg, cache_shape, mesh)
     logit_spec = shr.fit_spec((slots, cfg.vocab_size), P(bspec, "model"),
                               mesh)
-    return jax.jit(
+    cshard = shr.named(mesh, cspecs)
+    # one layer's slab of each pool leaf, on one device
+    slabs = [sh.shard_shape(leaf.shape)[1:]
+             for leaf, sh in zip(jax.tree.leaves(cache_shape),
+                                 jax.tree.leaves(cshard))]
+    return _counted(jax.jit(
         paged_decode_step,
         in_shardings=(shr.named(mesh, pspecs),
                       NamedSharding(mesh, P(bspec)),
-                      shr.named(mesh, cspecs),
+                      cshard,
                       NamedSharding(mesh, P(bspec, None)),
                       NamedSharding(mesh, P(bspec))),
-        out_shardings=(NamedSharding(mesh, logit_spec),
-                       shr.named(mesh, cspecs)),
+        out_shardings=(NamedSharding(mesh, logit_spec), cshard),
         donate_argnums=(2,),
-    )
+    ), "serve.pool_move_bytes",
+        functools.partial(pool_move_bytes, slabs=slabs))
 
 
 # --------------------------------------------------------------------------

@@ -14,7 +14,11 @@ stack in core/buckets.py — and each sequence owns a *block table*
 mapping its logical block j to a physical pool slot:
   GQA : k/v (L, N, bs, Hkv, Dh)
   MLA : c_kv (L, N, bs, r) + k_rope (L, N, bs, Dr)
-where N = pool blocks and bs = block size. Decode takes a per-sequence
+where N = pool blocks and bs = block size. The layer scan of a decode
+step carries the whole layer-stacked pool: each layer writes its new
+token at ``[layer, block, offset]`` and its attention reads the layer's
+blocks out of the whole pool, so nothing slices a layer out or stacks
+it back. Decode takes a per-sequence
 ``kv_lens`` vector instead of the scalar ``pos``: every sequence in the
 batch sits at its own depth, so long and short requests share one
 decode step without padding to the global max. Writes at out-of-pool
@@ -264,29 +268,32 @@ def write_prefill_blocks(paged: Dict[str, jnp.ndarray],
 
 def attention_decode_paged(params, x: jnp.ndarray, cfg: ModelConfig,
                            ctx: ParallelCtx, k_cache: jnp.ndarray,
-                           v_cache: jnp.ndarray,
+                           v_cache: jnp.ndarray, layer: jnp.ndarray,
                            block_tables: jnp.ndarray,
                            kv_lens: jnp.ndarray):
-    """One-token attention over a paged pool, one depth per sequence.
+    """One-token attention of layer ``layer`` over the layer-stacked
+    paged pool, one depth per sequence.
 
-    x (B, 1, d); caches (N, bs, Hkv, Dh); block_tables (B, MB) int32;
-    kv_lens (B,) int32 — tokens already cached per sequence (the new
-    token is written at position kv_lens[i] and attended to, so the
-    effective context is kv_lens + 1). Sequences whose current block
-    is NULL (inactive slots) write nowhere, gather zeros, and produce
-    garbage the caller discards.
-    Returns (y (B, 1, d), (k_cache, v_cache) updated).
+    x (B, 1, d); caches (L, N, bs, Hkv, Dh); layer () int32;
+    block_tables (B, MB) int32; kv_lens (B,) int32 — tokens already
+    cached per sequence (the new token is written at position kv_lens[i]
+    and attended to, so the effective context is kv_lens + 1). Sequences
+    whose current block is NULL (inactive slots) write nowhere, gather
+    zeros, and produce garbage the caller discards.
+    Returns (y (B, 1, d), (k_cache, v_cache) updated): one scatter of
+    B tokens into each whole pool, which a scan carrying the pools
+    applies in place.
     """
     b = x.shape[0]
-    bs = k_cache.shape[1]
+    bs = k_cache.shape[2]
     positions = kv_lens[:, None]                        # (B, 1)
     q, k, v = attention_qkv(params, x, cfg, positions)
     blk = jnp.take_along_axis(
         block_tables, (kv_lens // bs)[:, None], axis=1)[:, 0]
     off = kv_lens % bs
-    k_cache = k_cache.at[blk, off].set(
+    k_cache = k_cache.at[layer, blk, off].set(
         k[:, 0].astype(k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[blk, off].set(
+    v_cache = v_cache.at[layer, blk, off].set(
         v[:, 0].astype(v_cache.dtype), mode="drop")
     # attention over the pool, per cfg.attention_impl: the reference
     # path gathers each sequence's mapped blocks back into a dense view
@@ -297,7 +304,7 @@ def attention_decode_paged(params, x: jnp.ndarray, cfg: ModelConfig,
     # compute-dtype tolerance of the reference, and runs interpreted
     # with a loud warning where the backend can't compile Pallas.
     out = attn_ops.flash_decode_paged(
-        q, k_cache, v_cache, block_tables, kv_lens + 1,
+        q, k_cache, v_cache, block_tables, kv_lens + 1, layer,
         impl=cfg.attention_impl,
         interpret=(cfg.attention_impl == "pallas" and
                    compat.pallas_interpret_fallback(
@@ -314,13 +321,17 @@ def attention_decode_paged(params, x: jnp.ndarray, cfg: ModelConfig,
 
 def mla_decode_paged(params, x: jnp.ndarray, cfg: ModelConfig,
                      ctx: ParallelCtx, ckv_cache: jnp.ndarray,
-                     kr_cache: jnp.ndarray, block_tables: jnp.ndarray,
-                     kv_lens: jnp.ndarray):
-    """Paged twin of :func:`mla_decode`.
+                     kr_cache: jnp.ndarray, layer: jnp.ndarray,
+                     block_tables: jnp.ndarray, kv_lens: jnp.ndarray):
+    """Paged twin of :func:`mla_decode`, for layer ``layer`` of the
+    layer-stacked latent pool.
 
-    x (B, 1, d); ckv_cache (N, bs, r); kr_cache (N, bs, Dr);
-    block_tables (B, MB); kv_lens (B,). Same absorbed formulation —
-    scores against the gathered latent view, mask positions >= kv_len+1.
+    x (B, 1, d); ckv_cache (L, N, bs, r); kr_cache (L, N, bs, Dr);
+    layer () int32; block_tables (B, MB); kv_lens (B,). Same absorbed
+    formulation — scores against the gathered latent view, mask
+    positions >= kv_len+1. The new token is written at
+    ``[layer, block, offset]`` of the whole pool, as in
+    :func:`attention_decode_paged`.
 
     ``cfg.attention_impl="pallas"`` replaces the materialized gather
     with the in-kernel block-table stream
@@ -332,16 +343,16 @@ def mla_decode_paged(params, x: jnp.ndarray, cfg: ModelConfig,
     b = x.shape[0]
     m, h = cfg.mla, cfg.num_heads
     cdt = cfg.compute_dtype
-    bs = ckv_cache.shape[1]
+    bs = ckv_cache.shape[2]
     positions = kv_lens[:, None]                        # (B, 1)
     q_nope, q_rope = mla_queries(params, x, cfg, positions)  # (B,1,H,*)
     c_kv, k_r = mla_latent(params, x, cfg, positions)   # (B,1,r),(B,1,Dr)
     blk = jnp.take_along_axis(
         block_tables, (kv_lens // bs)[:, None], axis=1)[:, 0]
     off = kv_lens % bs
-    ckv_cache = ckv_cache.at[blk, off].set(
+    ckv_cache = ckv_cache.at[layer, blk, off].set(
         c_kv[:, 0].astype(ckv_cache.dtype), mode="drop")
-    kr_cache = kr_cache.at[blk, off].set(
+    kr_cache = kr_cache.at[layer, blk, off].set(
         k_r[:, 0].astype(kr_cache.dtype), mode="drop")
     w_uk = _cast(params["w_uk"], cdt).reshape(
         m.kv_lora_rank, h, m.nope_head_dim)
@@ -351,13 +362,13 @@ def mla_decode_paged(params, x: jnp.ndarray, cfg: ModelConfig,
     if cfg.attention_impl == "pallas":
         out_lat = mla_ops.mla_decode_paged_attention(
             q_abs, q_rope[:, 0].astype(cdt), ckv_cache, kr_cache,
-            block_tables, kv_lens + 1, scale, impl="pallas",
+            block_tables, kv_lens + 1, layer, scale, impl="pallas",
             interpret=compat.pallas_interpret_fallback(
                 "paged MLA decode (attention_impl='pallas')"))
     else:
-        ckv_g = ckv_cache.at[block_tables].get(
+        ckv_g = ckv_cache.at[layer, block_tables].get(
             mode="fill", fill_value=0).reshape(b, -1, m.kv_lora_rank)
-        kr_g = kr_cache.at[block_tables].get(
+        kr_g = kr_cache.at[layer, block_tables].get(
             mode="fill", fill_value=0).reshape(b, -1, m.rope_head_dim)
         scores = (jnp.einsum("bhr,bsr->bhs", q_abs, ckv_g,
                              preferred_element_type=jnp.float32) +

@@ -147,20 +147,22 @@ def apply_uniform_layer_decode(p, x, cfg, ctx, cache_l, pos):
     return x + m, new_cache
 
 
-def apply_uniform_layer_decode_paged(p, x, cfg, ctx, cache_l,
+def apply_uniform_layer_decode_paged(p, x, cfg, ctx, pool, layer,
                                      block_tables, kv_lens):
-    """Paged twin of apply_uniform_layer_decode: per-layer pool caches
-    (N, bs, ...) addressed through per-sequence block tables + kv_lens
-    instead of a contiguous (B, S_max, ...) slab and a scalar pos."""
+    """Paged twin of apply_uniform_layer_decode: layer ``layer`` of the
+    whole layer-stacked pool (L, N, bs, ...), addressed through
+    per-sequence block tables + kv_lens instead of a contiguous
+    (B, S_max, ...) slab and a scalar pos. Returns the pool with the
+    layer's new token written."""
     h = apply_norm(p["ln1"], x, cfg)
     if cfg.mla.enabled:
         a, new_cache = mla_decode_paged(p["attn"], h, cfg, ctx,
-                                        cache_l["c_kv"], cache_l["k_rope"],
-                                        block_tables, kv_lens)
+                                        pool["c_kv"], pool["k_rope"],
+                                        layer, block_tables, kv_lens)
         new_cache = {"c_kv": new_cache[0], "k_rope": new_cache[1]}
     else:
         a, new_cache = attention_decode_paged(p["attn"], h, cfg, ctx,
-                                              cache_l["k"], cache_l["v"],
+                                              pool["k"], pool["v"], layer,
                                               block_tables, kv_lens)
         new_cache = {"k": new_cache[0], "v": new_cache[1]}
     x = x + a
@@ -766,14 +768,22 @@ def decode_step_paged(params, embeds: jnp.ndarray, cfg: ModelConfig,
     embeds (B, 1, d); block_tables (B, MB) int32; kv_lens (B,) int32 —
     each sequence attends to its own kv_lens[i] cached tokens plus the
     new one. Returns (hidden (B, 1, d), cache).
+
+    The layer scan carries the whole layer-stacked pool with the hidden
+    state and runs over the layers' parameters and indices: each layer
+    writes its tokens into the carried pool in place and its attention
+    reads its own blocks out of it, so no layer's pool is sliced out or
+    stacked back.
     """
     if stack_plan(cfg) != "uniform":
         raise ValueError("decode_step_paged requires the uniform stack")
 
-    def body(xc, inp):
-        lp, cache_l = inp
-        x2, new_cache = apply_uniform_layer_decode_paged(
-            lp, xc, cfg, ctx, cache_l, block_tables, kv_lens)
-        return x2, new_cache
-    x, new_cache = jax.lax.scan(body, embeds, (params["layers"], cache))
+    def body(carry, inp):
+        xc, pool = carry
+        lp, layer = inp
+        return apply_uniform_layer_decode_paged(
+            lp, xc, cfg, ctx, pool, layer, block_tables, kv_lens), None
+    (x, new_cache), _ = jax.lax.scan(
+        body, (embeds, cache),
+        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     return apply_norm(params["final_norm"], x, cfg), new_cache

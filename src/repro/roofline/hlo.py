@@ -24,13 +24,16 @@ traffic at all. This module rebuilds whole-program costs from
   * exchange bytes (``exchange_bytes``, the train step's
     ``train.exchange_bytes``): the same collectives' result bytes, each
     computation counted once per call site and each while body once per
-    trip.
+    trip;
+  * pool-move bytes (``pool_move_bytes``, the paged decode step's
+    ``serve.pool_move_bytes``): bytes of the copies, slices and updates
+    that hold one layer's KV pool slab or more, weighted the same way.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -433,3 +436,62 @@ def exchange_bytes(hlo: str) -> int:
     return int(sum(weights.get(name, 0.0) * _shape_bytes(_result_text(line))
                    for name, lines in comps.items() for line in lines
                    if _line_op(line) in _EXCHANGE_OPS))
+
+
+# --------------------------------------------------------------------------
+# pool-move bytes: whole-slab data movement in the paged decode step
+# --------------------------------------------------------------------------
+
+_MOVE_RESULT_OPS = ("copy", "dynamic-slice")
+_MOVE_UPDATE_OPS = ("dynamic-update-slice", "scatter")
+
+
+def _update_operands(op: str, operands: List[str]) -> List[str]:
+    """The operands an in-place update writes: a dynamic-update-slice's
+    update (its second operand), a scatter's updates (the last N of its
+    N buffers, one index array, N updates)."""
+    if op == "dynamic-update-slice":
+        return operands[1:2]
+    return operands[(len(operands) - 1) // 2 + 1:]
+
+
+def pool_move_bytes(hlo: str, slabs: Sequence[Tuple[int, ...]]) -> int:
+    """Bytes a compiled module moves in one execution in ops that hold at
+    least one layer's pool slab: a shape ending in one of ``slabs`` (a
+    layer's (blocks, block size, ...) of each pool leaf, on one device),
+    as a layer's slab, a stack of layers or the whole pool has. Moved
+    are the result of a ``copy`` or ``dynamic-slice`` and the update
+    operands of a ``dynamic-update-slice`` or ``scatter``, wherever they
+    sit (fusions included), each weighted as :func:`_call_weights` weighs
+    its computation. Shapes, not sizes, pick the ops: a model's per-layer
+    weight slices may be as large as a slab. A decode step that carries
+    its pool through the layer scan and writes each token in place reads
+    0."""
+    slabs = [tuple(sl) for sl in slabs]
+
+    def holds_a_slab(text: str) -> bool:
+        dims = _first_shape_dims(text)
+        return any(dims[len(dims) - len(sl):] == sl for sl in slabs
+                   if len(dims) >= len(sl))
+
+    comps = _split_computations(hlo)
+    weights, _ = _call_weights(hlo, comps)
+    total = 0.0
+    for name, lines in comps.items():
+        w = weights.get(name, 0.0)
+        if w <= 0:
+            continue
+        table = None
+        for line in lines:
+            op = _line_op(line)
+            if op in _MOVE_RESULT_OPS:
+                moved = [_result_text(line)]
+            elif op in _MOVE_UPDATE_OPS:
+                table = table or _symbol_table(lines)
+                moved = [table.get(o, "") for o in
+                         _update_operands(op, _operand_names(line))]
+            else:
+                continue
+            total += w * sum(_shape_bytes(t) for t in moved
+                             if holds_a_slab(t))
+    return int(total)

@@ -28,7 +28,8 @@ active_p/speed_p is the HetSeq capacity argument on the serving side).
 (``repro/obs.py``): each iteration is a ``serve.iteration`` holding
 ``serve.admit`` (arrivals, admission, bucketing), ``serve.prefill``
 (with ``serve.fetch`` of its logits), ``serve.prepare`` (block tables
-and the step's inputs, uploaded), ``serve.decode`` (the step call),
+and the step's inputs, uploaded), ``serve.decode`` (the step call;
+its ``pool_move_bytes`` is the step's ``serve.pool_move_bytes`` gauge),
 ``serve.fetch`` (logits to the host) and ``serve.sample`` (argmax and
 emit). Each request's submission, admissions, first and last token
 are ``request.*`` events sharing one ``req`` id; ``stats`` reports the
@@ -211,9 +212,13 @@ class ServeEngine:
                 active = len(sched.running)
                 with obs.span("serve.decode", run=self._runs,
                               step=decode_steps + 1, active=active,
-                              kv_tokens=int(len_arr.sum()) + active):
+                              kv_tokens=int(len_arr.sum()) + active
+                              ) as decoded:
                     logits, cache = self.decode_fn(toks_d, cache, tbl_d,
                                                    lens_d)
+                    # set when the step was compiled
+                    decoded.attrs["pool_move_bytes"] = obs.counters().get(
+                        "serve.pool_move_bytes", 0)
                 clock += self._decode_dt()
                 decode_steps += 1
                 for p, a in enumerate(sched.active_per_pod):

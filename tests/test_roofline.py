@@ -160,6 +160,75 @@ def test_call_weights_sum_over_call_sites():
     assert async_w["scatter"] == async_w["gather_fusion"] == 1
 
 
+POOL = textwrap.dedent("""\
+    HloModule jit_decode, is_scheduled=true
+
+    %body (p: (s32[], f32[4,8,16])) -> (s32[], f32[4,8,16]) {
+      %p = (s32[], f32[4,8,16]{2,1,0}) parameter(0)
+      %i = s32[] get-tuple-element(%p), index=0
+      %pool = f32[4,8,16]{2,1,0} get-tuple-element(%p), index=1
+      %z = s32[] constant(0)
+      %slab = f32[1,8,16]{2,1,0} dynamic-slice(%pool, %i, %z, %z), dynamic_slice_sizes={1,8,16}
+      %weights = f32[3,16,8]{2,1,0} constant({...})
+      %layer_w = f32[1,16,8]{2,1,0} dynamic-slice(%weights, %i, %z, %z), dynamic_slice_sizes={1,16,8}
+      %one = s32[] constant(1)
+      %i2 = s32[] add(%i, %one)
+      ROOT %t = (s32[], f32[4,8,16]{2,1,0}) tuple(%i2, %pool)
+    }
+
+    %cond (p: (s32[], f32[4,8,16])) -> pred[] {
+      %p = (s32[], f32[4,8,16]{2,1,0}) parameter(0)
+      %i = s32[] get-tuple-element(%p), index=0
+      %n = s32[] constant(3)
+      ROOT %lt = pred[] compare(%i, %n), direction=LT
+    }
+
+    %dus_fusion (p0: f32[4,8,16], p1: f32[8,16], p2: s32[]) -> f32[4,8,16] {
+      %p0 = f32[4,8,16]{2,1,0} parameter(0)
+      %p1 = f32[8,16]{1,0} parameter(1)
+      %p2 = s32[] parameter(2)
+      %up = f32[1,8,16]{2,1,0} bitcast(%p1)
+      %z = s32[] constant(0)
+      ROOT %dus = f32[4,8,16]{2,1,0} dynamic-update-slice(%p0, %up, %p2, %z, %z)
+    }
+
+    %set (a: f32[], b: f32[]) -> f32[] {
+      %a = f32[] parameter(0)
+      ROOT %b = f32[] parameter(1)
+    }
+
+    ENTRY %main (arg: f32[4,8,16], row: f32[8,16], tok: f32[2,16], at: s32[2,3]) -> f32[4,8,16] {
+      %arg = f32[4,8,16]{2,1,0} parameter(0)
+      %row = f32[8,16]{1,0} parameter(1)
+      %tok = f32[2,16]{1,0} parameter(2)
+      %at = s32[2,3]{1,0} parameter(3)
+      %moved = f32[4,8,16]{2,1,0} copy(%arg)
+      %small = f32[2,16]{1,0} copy(%tok)
+      %zero = s32[] constant(0)
+      %init = (s32[], f32[4,8,16]{2,1,0}) tuple(%zero, %moved)
+      %wh = (s32[], f32[4,8,16]{2,1,0}) while(%init), condition=%cond, body=%body
+      %after = f32[4,8,16]{2,1,0} get-tuple-element(%wh), index=1
+      %upd = f32[4,8,16]{2,1,0} fusion(%after, %row, %zero), kind=kLoop, calls=%dus_fusion
+      ROOT %tokens = f32[4,8,16]{2,1,0} scatter(%upd, %at, %small), update_window_dims={1}, inserted_window_dims={0,1}, scatter_dims_to_operand_dims={0,1,2}, index_vector_dim=1, to_apply=%set
+    }
+    """)
+
+
+def test_pool_move_bytes_counts_slab_moves_per_trip():
+    # a layer's slab of the pool is f32[8,16] (512 B): the whole pool's
+    # copy (2048 B) once, the loop's slab slice 3 times, the fused
+    # dynamic-update-slice's slab update once; the small copy, the
+    # two-token scatter (f32[2,16] updates) and the loop's weight slice
+    # (512 B too, but not a slab of the pool) do not count
+    assert H.pool_move_bytes(POOL, [(8, 16)]) == 2048 + 3 * 512 + 512
+    # a (4, 8, 16) slab is held by the pool's copy alone, a (2, 16) one
+    # by the small copy and the scatter's updates
+    assert H.pool_move_bytes(POOL, [(4, 8, 16)]) == 2048
+    assert H.pool_move_bytes(POOL, [(2, 16)]) == 2 * 128
+    # nothing holds a slab when the pool stays in place
+    assert H.pool_move_bytes(POOL, [(5, 8, 16)]) == 0
+
+
 def test_shape_bytes():
     assert H._shape_bytes("f32[8,16]") == 512
     assert H._shape_bytes("bf16[2,3] whatever pred[7]") == 12 + 7

@@ -265,6 +265,35 @@ def _engine_run(impl):
     return eng, res
 
 
+@pytest.mark.parametrize("arch", PAGED_ARCHS)
+def test_decode_step_counts_its_pool_moves_once_per_build(arch):
+    """The decode step is compiled once, ahead of its first call, and
+    ``serve.pool_move_bytes`` read from that executable: the layer scan
+    carries the pool (GQA and MLA alike), so nothing a layer's slab in
+    size is copied, sliced or restacked, and every ``serve.decode`` span
+    carries the gauge."""
+    from repro import obs
+
+    cfg, model, _ = _model(arch, compute_dtype="float32")
+    mesh = make_mesh((1, 1), ("data", "model"))
+    params = steps_mod.init_params_sharded(model, mesh,
+                                           jax.random.PRNGKey(0))
+    layout = PagedLayout(block_size=4, num_blocks=12, max_blocks_per_seq=4)
+    obs.reset()
+    with jax.set_mesh(mesh):
+        eng = serve_mod.build_engine(model, params, mesh, layout, slots=2,
+                                     prefill_batch=2, pod_speeds=[1.0])
+        res = eng.run(list(_PR9_REQS))
+    c = obs.counters()
+    assert c["jit.traces.paged_decode_step"] == 1
+    assert c["jit.compiles.paged_decode_step"] == 1
+    assert _trace_count(eng.decode_fn) == 1
+    assert c["serve.pool_move_bytes"] == 0
+    moved = [sp.attrs["pool_move_bytes"] for sp in obs.spans()
+             if sp.name == "serve.decode"]
+    assert moved == [0] * res.stats["decode_steps"]
+
+
 @pytest.mark.pallas_interpret
 def test_engine_pallas_token_identical_to_reference():
     """A full compile-once engine run with attention_impl='pallas'
