@@ -1,8 +1,7 @@
 """Emit the §Roofline table from dry-run artifacts (artifacts/dryrun).
 
 Reads every <cell>.json the dry-run produced, computes the three-term
-roofline (TPU v5e constants) and prints the markdown table used in
-EXPERIMENTS.md §Roofline.
+roofline (TPU v5e constants) and prints it as a markdown table.
 """
 from __future__ import annotations
 

@@ -5,7 +5,7 @@ vocab=32000; 128 experts top-2 + dense residual
 Arctic's dense-MoE hybrid: every layer runs a small dense FFN residual
 branch *in parallel* with the 128-expert top-2 MoE (``dense_residual``).
 Optimizer-state dtype is reduced (bf16 m) so ZeRO-1-sharded Adam state
-fits 16 GB HBM on the single-pod mesh — noted in EXPERIMENTS.md.
+fits 16 GB HBM on the single-pod mesh.
 """
 from repro.configs.base import ModelConfig, MoEConfig, register
 

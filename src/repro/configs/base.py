@@ -192,7 +192,7 @@ def shape_applicable(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]
     if shape.name == "long_500k" and not model.sub_quadratic:
         return False, (
             "long_500k requires sub-quadratic attention; "
-            f"{model.name} is pure full-attention (skip noted in DESIGN.md)"
+            f"{model.name} is pure full-attention"
         )
     return True, ""
 
@@ -515,7 +515,7 @@ def accum_for(model: ModelConfig, multi_pod: bool = False) -> int:
 
     NOTE: the CPU dry-run backend legalizes bf16 GEMMs to f32 (operand
     copies), inflating measured activation memory ~2x vs real TPU; the
-    accum chosen here fits even that pessimistic bound (EXPERIMENTS.md).
+    accum chosen here fits even that pessimistic bound.
     """
     policy = {
         "chameleon-34b": (4, 4),         # (single-pod, multi-pod)
@@ -534,9 +534,9 @@ def optimizer_for(model: ModelConfig, **overrides) -> OptimizerConfig:
 
     The two MoE giants cannot hold fp32 Adam moments on 256 x 16 GB:
       arctic-480b      : 480e9 x 12 B (fp32 p+m+v) / 256 = 22.5 GB/chip.
-                         bf16 p+m+v => 11.25 GB/chip (documented in
-                         EXPERIMENTS.md; stochastic-rounding-free bf16 m/v
-                         is the standard large-MoE compromise).
+                         bf16 p+m+v => 11.25 GB/chip
+                         (stochastic-rounding-free bf16 m/v is the
+                         standard large-MoE compromise).
       deepseek-v2-236b : fp32 params + bf16 m/v => 7.4 GB/chip.
     Everything else keeps full fp32 state.
     """

@@ -121,7 +121,7 @@ def analyze(lowered, meta: Dict[str, Any], pod_size: int = 256
     meta["fits_16gb_cpu_measured"] = \
         meta["memory"]["peak_device_bytes"] < 16e9
     # TPU-true estimate: exact state + temp/2 (undo the CPU backend's
-    # bf16->f32 GEMM-operand legalization, documented in EXPERIMENTS.md)
+    # bf16->f32 GEMM-operand legalization, which doubles temp bytes)
     meta["memory"]["tpu_estimate_bytes"] = int(
         ma.argument_size_in_bytes + ma.temp_size_in_bytes / 2)
     meta["fits_16gb"] = meta["memory"]["tpu_estimate_bytes"] < 16e9

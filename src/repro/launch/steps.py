@@ -1,53 +1,44 @@
 """Step builders: jitted train / prefill / decode with explicit shardings.
 
-``build_train_step`` assembles the full HetSeq step:
-  1. weighted objective over the packed (dummy-padded) global batch —
-     per-token weights make heterogeneous capacity exact (core M1/M3);
-  2. optional gradient accumulation scan (core M4, shared scan core in
-     core/accumulate.py);
-  3. gradient reduction, selected by ``HetConfig.grad_reduction`` and
-     ``HetConfig.bucket_mb``:
-       * "allreduce"    — paper-faithful: XLA's automatic reduction from
-         the shardings (FSDP => reduce-scatter + all-gather);
-       * "bucketed_allreduce" — explicit flat-buffer reduction: grads
-         are packed into fixed-size f32 buckets (core/buckets.py) and
-         reduced with ONE psum_scatter + ONE all_gather over the whole
-         DP axis set, instead of XLA's per-leaf collectives;
-       * "hierarchical" — beyond-paper: params replicated over "pod",
-         FSDP over "data"; in-pod reduction stays automatic (ICI), the
-         cross-pod leg is an explicit shard_map(axis_names={"pod"})
-         collective, optionally int8-compressed with error feedback.
-         With ``bucket_mb > 0`` the cross-pod leg runs the bucketed
-         engine: two collectives per step total, error feedback held
-         in ONE flat (pods, num_buckets, bucket_elems) array; with
-         ``bucket_mb == 0`` the legacy per-leaf walk (one quantize +
-         one gather per leaf) is kept for comparison;
-  4. AdamW update (optimizer state sharded like params = ZeRO-1).
+Training has one skeleton. ``train_schedule`` decides, once for each
+(model, config, mesh), which schedule the cell runs and what follows
+from it: the reduction axes, hierarchy and error feedback, the bucket
+layout, the stage plan and the state layout. The state functions
+(``state_shapes``, ``state_specs``, ``init_train_state``,
+``checkpoint_format``) and the step builders read that record.
+``build_train_step`` validates, decides, builds the schedule's step and
+hands it to the one jit wrapper, ``_jit_train_step``. Every schedule
+takes the packed (dummy-padded) global batch with per-token weights,
+which make heterogeneous capacity exact (core M1/M3):
 
-``HetConfig.overlap="buckets"`` (both explicit reduction modes)
-replaces steps 3+4 with the fused double-buffered pipeline: the
-per-bucket exchange (core/buckets.py::exchange_buckets_overlapped)
-overlaps bucket k+1's quantize/pack with bucket k's in-flight
-collective, and the flat-view optimizer update
-(optim/adam.py::apply_update_flat) for bucket k is applied the moment
-its reduced payload lands — the optimizer moments then live packed as
-one (num_buckets, bucket_elems) array in TrainState, replicated over
-the reduction axes. In the backward-overlap flush pipeline LAMB
-streams too: its moment updates and per-leaf norm partials land per
-bucket, with only the trust-ratio application deferred to one trailing
-elementwise pass (optim/lamb.py; the after-backward bucket engine
-keeps LAMB's whole-stack barrier — see the rationale there).
-Global-norm clipping keeps the pipelined exchange but updates behind a
-barrier (the clip factor needs every bucket before the first moment
-update).
+  * ``allreduce`` — XLA reduces the grads from the shardings (FSDP =>
+    reduce-scatter + all-gather); accumulation (core/accumulate.py);
+    tree AdamW / LAMB, its state sharded like the params (ZeRO-1);
+  * ``bucketed_allreduce`` — grads packed into fixed-size f32 buckets
+    (core/buckets.py), ONE psum_scatter + ONE all_gather over the DP
+    axes;
+  * ``hierarchical`` — params replicated over "pod", FSDP over "data";
+    the cross-pod leg an explicit shard_map(axis_names={"pod"})
+    collective, optionally int8 with error feedback: the bucketed
+    engine with ``bucket_mb > 0`` (one flat error array), the legacy
+    per-leaf walk with ``bucket_mb == 0`` (a per-leaf error mirror);
+  * ``overlap_buckets`` — after the backward, the double-buffered
+    per-bucket exchange with each bucket's flat-view optimizer update
+    fused in as it lands; the moments packed (num_buckets,
+    bucket_elems), replicated over the reduction axes;
+  * ``overlap_backward`` — the same packed state, the buckets flushed
+    DURING a staged layer-by-layer backward (LAMB streams there too);
+  * ``pipeline`` — contiguous capacity-sized layer stages
+    (core/pipeline.py), the accumulation microbatches streamed through
+    them in 1F1B (or GPipe) order; grads reduced by XLA, or per stage
+    through the bucket engine with ``bucketed_allreduce``;
+  * ``canonical`` — per-row grads summed with one fixed reduction tree,
+    bit-identical across capacity replans.
 
-``HetConfig.pipeline_stages > 1`` adds the pipe dimension: the uniform
-layer stack is cut into contiguous capacity-sized stages
-(core/pipeline.py StagePlan) and the accumulation microbatches stream
-through them in 1F1B program order — per-stage VJP segments exchanged
-through send/recv regions, grads reduced per-stage through the bucket
-engine when ``grad_reduction="bucketed_allreduce"``
-(_build_pipeline_step).
+The overlap schedules share the per-bucket AdamW hook and the
+packed-moment result; ``overlap_backward`` and the bucketed pipeline
+share the staged plumbing (leaf pieces, stream-buffer scatter,
+rank-major microbatch split, embedding VJP, flush legs).
 
 ``input_specs`` provides ShapeDtypeStruct stand-ins for every cell of
 the (architecture x shape) grid — the dry-run lowers against these, no
@@ -92,7 +83,7 @@ def make_parallel_ctx(mesh: Optional[Mesh]) -> ParallelCtx:
 
 
 # --------------------------------------------------------------------------
-# train state
+# the schedule decision and the train state
 # --------------------------------------------------------------------------
 
 
@@ -100,35 +91,87 @@ class TrainState(NamedTuple):
     params: Any
     opt: adam.AdamState
     err: Any                       # error-feedback state or () when unused
-    # bucketed reduction: ONE flat (pods, num_buckets, bucket_elems) f32
-    # array; legacy per-leaf reduction: a (pods, *leaf) pytree mirror
-    # overlap="buckets": opt.m / opt.v are packed
-    # (num_buckets, bucket_elems) arrays (core/buckets.py layout),
-    # replicated over the reduction axes, NOT pytree mirrors
+    # layouts: TrainSchedule.packed (opt.m / opt.v) and .err (err)
 
 
-def _err_enabled(tcfg: TrainConfig, mesh: Mesh) -> bool:
-    return (tcfg.het.grad_reduction == "hierarchical"
-            and tcfg.het.compression != "none"
-            and tcfg.het.error_feedback
-            and "pod" in mesh.axis_names)
+SCHEDULES = ("allreduce", "bucketed_allreduce", "hierarchical",
+             "overlap_buckets", "overlap_backward", "pipeline",
+             "canonical")
 
 
-def _overlap_enabled(tcfg: TrainConfig, mesh: Mesh) -> bool:
-    """Whether this config runs a fused per-bucket pipeline
-    (``overlap`` in {"buckets", "backward"}).
+class TrainSchedule(NamedTuple):
+    """What ``train_schedule`` decided for one (model, config, mesh)."""
+    kind: str                      # one of SCHEDULES
+    hier: bool                     # hierarchical, on a mesh with "pod"
+    # hier or bucketed_allreduce: the gradient exchange is ours, over
+    # `axes`, and the params are replicated across those axes
+    explicit: bool
+    axes: Tuple[str, ...]          # the explicit exchange's manual axes
+    ranks: int                     # ranks of that exchange
+    inner_dp: int                  # DP ranks inside one of those ranks
+    compress: str                  # the exchange's wire format
+    err: Optional[str]             # error state: "flat" | "per_leaf" | None
+    packed: bool                   # opt.m / opt.v packed per bucket
+    # the overlap schedules stream the optimizer per bucket; global-norm
+    # clipping needs every bucket first, so it updates behind a barrier
+    stream: bool
+    layout: Optional[bkt.BucketLayout]
+    splan: Optional[pipe.StagePlan]
+    params_shape: Any
 
-    Overlap is a schedule of the bucketed engine, so it needs an
-    explicit reduction mode with a bucket layout to pipeline over
-    (``HetConfig.validate`` raises on misconfiguration); a mesh with
-    no reduction axes silently falls back to the non-overlap path.
+    @property
+    def axis(self):
+        """The exchange's axis name(s), as a collective takes them."""
+        return self.axes[0] if len(self.axes) == 1 else self.axes
+
+
+def train_schedule(model: Model, tcfg: TrainConfig,
+                   mesh: Mesh) -> TrainSchedule:
+    """Decide which train schedule this cell runs, and what follows.
+
+    The one place the decision is made. Two fallbacks are silent by
+    design: ``hierarchical`` on a mesh without a "pod" axis runs the
+    plain ``allreduce`` schedule, and ``overlap`` on a mesh with no
+    reduction axes runs its reduction's schedule without overlap.
+    Mesh-independent rules are checked here (``HetConfig.validate``);
+    ``validate_train_config`` adds the model and mesh rules.
     """
-    tcfg.het.validate()
-    if tcfg.het.overlap == "none":
-        return False
-    if not _reduce_axes(tcfg, mesh):
-        return False               # no reduction axes on this mesh
-    return True
+    het = tcfg.het.validate()
+    hier = het.grad_reduction == "hierarchical" \
+        and "pod" in mesh.axis_names
+    bucketed = het.grad_reduction == "bucketed_allreduce"
+    axes = ("pod",) if hier else (mesh_dp_axes(mesh) if bucketed else ())
+    ranks = math.prod(mesh.shape[a] for a in axes)
+    params_shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    layout = None
+    if axes and het.bucket_mb > 0:
+        # buckets divide into per-rank shards of whole quantization
+        # blocks (ranks * _BLOCK)
+        layout = bkt.build_layout(params_shape, bucket_mb=het.bucket_mb,
+                                  multiple_of=ranks * _BLOCK)
+    if het.weighting == "canonical":
+        kind = "canonical"
+    elif het.pipeline_stages > 1:
+        kind = "pipeline"
+    elif het.overlap != "none" and axes:
+        kind = "overlap_" + het.overlap
+    elif hier:
+        kind = "hierarchical"
+    elif bucketed:
+        kind = "bucketed_allreduce"
+    else:
+        kind = "allreduce"
+    packed = kind.startswith("overlap_")
+    err = None
+    if hier and het.compression != "none" and het.error_feedback:
+        err = "flat" if layout is not None else "per_leaf"
+    return TrainSchedule(
+        kind=kind, hier=hier, explicit=hier or bucketed, axes=axes,
+        ranks=ranks, inner_dp=dp_size(mesh) // ranks,
+        compress=het.compression if hier else "none", err=err,
+        packed=packed, stream=packed and tcfg.optimizer.grad_clip <= 0,
+        layout=layout, splan=stage_plan_for(model, tcfg),
+        params_shape=params_shape)
 
 
 def validate_train_config(model: Model, tcfg: TrainConfig,
@@ -152,41 +195,29 @@ def validate_train_config(model: Model, tcfg: TrainConfig,
         raise ValueError(
             "grad_reduction='bucketed_allreduce' needs a mesh with "
             f"data-parallel axes; got {mesh.axis_names}")
-    if het.overlap == "backward":
-        # model rules checked UNCONDITIONALLY: a mesh with no reduction
-        # axes falls back to the non-overlap schedule, but an
-        # unsupported stack plan used to ride that fallback silently and
-        # then blow up the moment the same config met a real mesh —
-        # supports_staged_backward drives a loud build-time error either
-        # way (tests/test_overlap.py regression)
-        if not tr.supports_staged_backward(model.cfg):
+    # the staged schedules run per-layer VJP segments over the uniform
+    # block stack. Checked whatever the mesh: overlap on a mesh with no
+    # reduction axes falls back to the plain schedule, and an unsupported
+    # model must not ride that fallback silently and then fail the
+    # moment the same config meets a real mesh (tests/test_overlap.py)
+    for staged, on, hint in (
+            ("overlap='backward'", het.overlap == "backward",
+             " — use overlap='buckets'"),
+            ("pipeline_stages > 1", het.pipeline_stages > 1, "")):
+        if on and not tr.supports_staged_backward(model.cfg):
             raise ValueError(
-                "HetConfig.overlap='backward' stages the backward over "
-                "the uniform block stack (dense | moe | mla); stack "
-                f"plan '{tr.stack_plan(model.cfg)}' of "
-                f"'{model.cfg.name}' is not supported — use "
-                "overlap='buckets'")
-        if model.cfg.scan_layers:
+                f"HetConfig.{staged} stages the backward over the uniform "
+                f"block stack (dense | moe | mla); stack plan "
+                f"'{tr.stack_plan(model.cfg)}' of '{model.cfg.name}' is "
+                f"not supported{hint}")
+        if on and model.cfg.scan_layers:
             raise ValueError(
-                "HetConfig.overlap='backward' needs ModelConfig."
-                "scan_layers=False: the staged layer-by-layer backward "
-                "is an unrolled program, and bit-exactness with the "
-                "monolithic path requires the monolithic stack "
-                "unrolled too (launch/train.py: --no-scan-layers)")
+                f"HetConfig.{staged} needs ModelConfig.scan_layers=False: "
+                "its per-layer VJP segments are an unrolled program, and "
+                "bit-exactness with the monolithic path requires the "
+                "monolithic stack unrolled too (launch/train.py: "
+                "--no-scan-layers)")
     if het.pipeline_stages > 1:
-        if not tr.supports_staged_backward(model.cfg):
-            raise ValueError(
-                "HetConfig.pipeline_stages > 1 cuts the uniform block "
-                "stack (dense | moe | mla) into contiguous stages; "
-                f"stack plan '{tr.stack_plan(model.cfg)}' of "
-                f"'{model.cfg.name}' is not supported")
-        if model.cfg.scan_layers:
-            raise ValueError(
-                "HetConfig.pipeline_stages > 1 needs ModelConfig."
-                "scan_layers=False: the per-stage VJP segments are an "
-                "unrolled program, and bit-exactness with pure DP "
-                "requires the monolithic stack unrolled too "
-                "(launch/train.py: --no-scan-layers)")
         if model.cfg.num_layers < het.pipeline_stages:
             raise ValueError(
                 f"pipeline_stages={het.pipeline_stages} exceeds the "
@@ -199,43 +230,6 @@ def validate_train_config(model: Model, tcfg: TrainConfig,
                 f"HetConfig.pipeline_stages={het.pipeline_stages} — "
                 "build the mesh with pipe=pipeline_stages "
                 "(launch/mesh.py)")
-
-
-def _flat_barrier_update(pb, red, m, v, lr_step, ocfg, lr, *, inv_w,
-                         dmask, segs, n_leaves):
-    """Whole-stack flat optimizer update behind the barrier.
-
-    Shared by the after-backward ("buckets") and backward-overlap
-    pipelines for configs whose statistics need every reduced bucket
-    BEFORE the first moment update (global-norm clipping), and by the
-    after-backward engine for ALL of LAMB (the backward-overlap flush
-    pipeline streams LAMB instead — optim/lamb.py has the full
-    exactness rationale). Returns
-    (new_pb, new_m, new_v, gnorm, mean trust ratio).
-    """
-    gsc = red * inv_w
-    gnorm = jnp.sqrt(jnp.sum(gsc * gsc))
-    cs = (jnp.minimum(1.0, ocfg.grad_clip /
-                      jnp.maximum(gnorm, 1e-9))
-          if ocfg.grad_clip > 0 else None)
-    if ocfg.name == "lamb":
-        new_pb, new_m, new_v, trust = lamb.apply_update_flat(
-            pb, gsc, m, v, lr_step, ocfg, lr,
-            decay_mask=dmask, seg_ids=segs,
-            num_leaves=n_leaves, clip_scale=cs)
-    else:
-        new_pb, new_m, new_v = adam.apply_update_flat(
-            pb, gsc, m, v, lr_step, ocfg, lr,
-            decay_mask=dmask, clip_scale=cs)
-        trust = jnp.ones((), jnp.float32)
-    return new_pb, new_m, new_v, gnorm, trust
-
-
-def _reduce_axes(tcfg: TrainConfig, mesh: Mesh) -> Tuple[str, ...]:
-    """The mesh axes the explicit bucketed reduction runs over."""
-    if tcfg.het.grad_reduction == "bucketed_allreduce":
-        return mesh_dp_axes(mesh)
-    return ("pod",) if "pod" in mesh.axis_names else ()
 
 
 def stage_plan_for(model: Model,
@@ -260,29 +254,16 @@ def stage_plan_for(model: Model,
 
 def bucket_layout(model: Model, tcfg: TrainConfig,
                   mesh: Mesh) -> Optional[bkt.BucketLayout]:
-    """The gradient bucket grid for this (model, config, mesh) cell.
-
-    The bucket size is rounded so every bucket divides into per-rank
-    shards of whole quantization blocks (ranks * _BLOCK).
-    """
-    if tcfg.het.bucket_mb <= 0:
-        return None
-    axes = _reduce_axes(tcfg, mesh)
-    if not axes:
-        return None
-    ranks = 1
-    for a in axes:
-        ranks *= mesh.shape[a]
-    params_shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    return bkt.build_layout(params_shape, bucket_mb=tcfg.het.bucket_mb,
-                            multiple_of=ranks * _BLOCK)
+    """The gradient bucket grid of this cell's explicit exchange (None
+    when the cell has none)."""
+    return train_schedule(model, tcfg, mesh).layout
 
 
 def checkpoint_format(model: Model, tcfg: TrainConfig, mesh: Mesh) -> Dict:
     """The checkpoint ``"format"`` meta block for this config cell.
 
     Records how this cell lays TrainState out on disk: which fields are
-    saved packed (``overlap="buckets"`` stores the optimizer moments as
+    saved packed (the overlap schedules store the optimizer moments as
     one (num_buckets, bucket_elems) stack) and the versioned
     ``BucketLayout`` record + fingerprint describing that grid, so a
     restore into ANY other cell can translate through the flat stream
@@ -293,6 +274,7 @@ def checkpoint_format(model: Model, tcfg: TrainConfig, mesh: Mesh) -> Dict:
     """
     from repro.checkpoint import repack
 
+    sched = train_schedule(model, tcfg, mesh)
     hosts = int(mesh.shape["pod"]) if "pod" in mesh.axis_names else 1
     fmt: Dict[str, Any] = {"version": repack.FORMAT_VERSION,
                            "state": "pytree", "packed_fields": [],
@@ -310,16 +292,14 @@ def checkpoint_format(model: Model, tcfg: TrainConfig, mesh: Mesh) -> Dict:
                            # record exists so restore can LOG the plan
                            # change, and repack.py can validate it
                            "pipeline": None}
-    splan = stage_plan_for(model, tcfg)
-    if splan is not None:
-        fmt["pipeline"] = pipe.stage_record(splan)
-    if _overlap_enabled(tcfg, mesh):
-        lo = bucket_layout(model, tcfg, mesh)
-        params_shape = jax.eval_shape(model.init_params,
-                                      jax.random.PRNGKey(0))
+    if sched.splan is not None:
+        fmt["pipeline"] = pipe.stage_record(sched.splan)
+    if sched.packed:
         paths = [repack.path_key(p) for p, _ in
-                 jax.tree_util.tree_flatten_with_path(params_shape)[0]]
-        rec = bkt.layout_record(lo, leaf_paths=paths, hosts=hosts)
+                 jax.tree_util.tree_flatten_with_path(
+                     sched.params_shape)[0]]
+        rec = bkt.layout_record(sched.layout, leaf_paths=paths,
+                                hosts=hosts)
         fmt.update(state="packed",
                    packed_fields=["opt/m", "opt/v"],
                    layout=rec,
@@ -328,30 +308,34 @@ def checkpoint_format(model: Model, tcfg: TrainConfig, mesh: Mesh) -> Dict:
 
 
 def state_shapes(model: Model, tcfg: TrainConfig, mesh: Mesh):
-    params_shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    if _overlap_enabled(tcfg, mesh):
-        # fused per-bucket pipeline: moments live packed in the flat
-        # bucket layout (NOTE: layout depends on the mesh's reduction
-        # ranks — re-meshing an overlap checkpoint needs a repack)
-        lo = bucket_layout(model, tcfg, mesh)
-        opt_shape = jax.eval_shape(functools.partial(
-            adam.init_state_flat, lo.num_buckets, lo.bucket_elems,
-            tcfg.optimizer))
-    else:
-        opt_shape = jax.eval_shape(
-            functools.partial(adam.init_state, cfg=tcfg.optimizer),
+    return _state_shapes(tcfg, mesh, train_schedule(model, tcfg, mesh))
+
+
+def _init_opt(params, tcfg: TrainConfig, sched: TrainSchedule):
+    """The step-0 optimizer state: moments packed per bucket, or a
+    pytree like the params."""
+    if sched.packed:
+        # the layout depends on the mesh's reduction ranks: re-meshing a
+        # packed checkpoint goes through checkpoint/repack.py
+        lo = sched.layout
+        return adam.init_state_flat(lo.num_buckets, lo.bucket_elems,
+                                    tcfg.optimizer)
+    return adam.init_state(params, tcfg.optimizer)
+
+
+def _state_shapes(tcfg: TrainConfig, mesh: Mesh,
+                  sched: TrainSchedule) -> TrainState:
+    params_shape = sched.params_shape
+    opt_shape = jax.eval_shape(
+        lambda p: _init_opt(p, tcfg, sched), params_shape)
+    if sched.err == "flat":
+        err_shape: Any = jax.ShapeDtypeStruct(
+            sched.layout.error_shape(mesh.shape["pod"]), jnp.float32)
+    elif sched.err == "per_leaf":
+        err_shape = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct((mesh.shape["pod"],) + p.shape,
+                                           jnp.float32),
             params_shape)
-    if _err_enabled(tcfg, mesh):
-        pods = mesh.shape["pod"]
-        layout = bucket_layout(model, tcfg, mesh)
-        if layout is not None:
-            err_shape: Any = jax.ShapeDtypeStruct(
-                layout.error_shape(pods), jnp.float32)
-        else:
-            err_shape = jax.tree.map(
-                lambda p: jax.ShapeDtypeStruct((pods,) + p.shape,
-                                               jnp.float32),
-                params_shape)
     else:
         err_shape = ()
     return TrainState(params=params_shape, opt=opt_shape, err=err_shape)
@@ -370,41 +354,40 @@ def _strip_axes(spec: P, drop: Tuple[str, ...]) -> P:
 
 
 def state_specs(model: Model, tcfg: TrainConfig, mesh: Mesh) -> TrainState:
-    shapes = state_shapes(model, tcfg, mesh)
-    hier = (tcfg.het.grad_reduction == "hierarchical"
-            and "pod" in mesh.axis_names)
-    bucketed_ar = tcfg.het.grad_reduction == "bucketed_allreduce"
-    pspecs = shr.param_specs(model.cfg, shapes.params, mesh)
-    if hier or bucketed_ar:
-        # explicit-reduction modes: params replicated across the manual
-        # reduction axes so the gradient leg is ours to schedule
-        # (hierarchical keeps FSDP over "data"; bucketed_allreduce
-        # replicates over the whole DP set)
-        drop = ("pod",) if hier else _reduce_axes(tcfg, mesh)
-        pspecs = jax.tree.map(lambda s: _strip_axes(s, drop), pspecs,
+    return _state_specs(model, mesh, train_schedule(model, tcfg, mesh))
+
+
+def _state_specs(model: Model, mesh: Mesh,
+                 sched: TrainSchedule) -> TrainState:
+    pspecs = shr.param_specs(model.cfg, sched.params_shape, mesh)
+    if sched.explicit:
+        # params replicated across the manual reduction axes so the
+        # gradient leg is ours to schedule (hierarchical keeps FSDP over
+        # "data"; bucketed_allreduce replicates over the whole DP set)
+        pspecs = jax.tree.map(lambda s: _strip_axes(s, sched.axes), pspecs,
                               is_leaf=lambda x: isinstance(x, P))
         # token-embedding gathers with a sharded vocab dim hit an XLA
         # SPMD-partitioner bug inside partially-manual regions; shard the
         # table on d_model only (gather pass-through dim) in this mode
         if isinstance(pspecs, dict) and "embed" in pspecs:
             tp = "model" if "model" in mesh.axis_names else None
-            vshape = shapes.params["embed"].shape
+            vshape = sched.params_shape["embed"].shape
             pspecs = dict(pspecs)
             pspecs["embed"] = shr.fit_spec(vshape, P(None, tp), mesh)
-    if _overlap_enabled(tcfg, mesh):
+    if sched.packed:
         # packed moments: replicated over the reduction axes (the flat
         # stack mixes every leaf's sharding — the ZeRO-1 mirror does
         # not apply; documented trade in ROADMAP.md)
         ospecs = adam.AdamState(step=P(), m=P(), v=P())
     else:
         ospecs = adam.AdamState(step=P(), m=pspecs, v=pspecs)
-    if shapes.err == ():
-        especs: Any = ()
-    elif isinstance(shapes.err, jax.ShapeDtypeStruct):
-        especs = P("pod")              # flat bucketed error state
-    else:
+    if sched.err == "flat":
+        especs: Any = P("pod")
+    elif sched.err == "per_leaf":
         especs = jax.tree.map(lambda s: P("pod", *s), pspecs,
                               is_leaf=lambda x: isinstance(x, P))
+    else:
+        especs = ()
     return TrainState(params=pspecs, opt=ospecs, err=especs)
 
 
@@ -412,22 +395,15 @@ def init_train_state(model: Model, tcfg: TrainConfig, mesh: Mesh,
                      key) -> TrainState:
     """Initialize on-device with the right shardings (M8: same init
     everywhere — a single global RNG key IS the broadcast)."""
-    specs = state_specs(model, tcfg, mesh)
-    shapes = state_shapes(model, tcfg, mesh)
+    sched = train_schedule(model, tcfg, mesh)
+    specs = _state_specs(model, mesh, sched)
+    shapes = _state_shapes(tcfg, mesh, sched)
 
     def init(k):
         params = model.init_params(k)
-        if _overlap_enabled(tcfg, mesh):
-            lo = bucket_layout(model, tcfg, mesh)
-            opt = adam.init_state_flat(lo.num_buckets, lo.bucket_elems,
-                                       tcfg.optimizer)
-        else:
-            opt = adam.init_state(params, tcfg.optimizer)
-        if shapes.err == ():
-            err: Any = ()
-        else:
-            err = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), shapes.err)
+        opt = _init_opt(params, tcfg, sched)
+        err = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                           shapes.err)
         return TrainState(params=params, opt=opt, err=err)
 
     with jax.set_mesh(mesh):
@@ -456,7 +432,7 @@ def init_cache_sharded(model: Model, shape: ShapeConfig, mesh: Mesh):
 
 
 # --------------------------------------------------------------------------
-# gradient reduction modes
+# gradient reduction and optimizer pieces
 # --------------------------------------------------------------------------
 
 
@@ -552,10 +528,9 @@ def _reduce_bucketed(
     Packs the whole gradient pytree into the fixed-size bucket stack,
     runs the monolithic two-collective exchange, and unpacks. ``err``
     is this rank's (1, num_buckets, bucket_elems) slice of the flat
-    error state, or None when error feedback is off. The overlap mode
-    does NOT go through here — its fused reduce+optimizer pipeline
-    never materializes the unpacked gradient tree (see
-    build_train_step's overlap branch).
+    error state, or None when error feedback is off. The overlap
+    schedules do NOT go through here — their fused reduce+optimizer
+    pipelines never materialize the unpacked gradient tree.
     """
     flat = bkt.pack_buckets(grads, layout)
     e = (err.reshape(layout.num_buckets, layout.bucket_elems)
@@ -570,8 +545,338 @@ def _reduce_bucketed(
     return out, new_e.reshape(1, layout.num_buckets, layout.bucket_elems)
 
 
+def _carry_dtype(p):
+    """Gradient-accumulation carry dtype: fp32, except when params are
+    stored bf16 (arctic/deepseek giants), where an fp32 carry alone
+    would blow the 16 GB budget — a bf16 carry there."""
+    return p.dtype if p.dtype == jnp.bfloat16 else jnp.float32
+
+
+def _tree_update(params, grads, opt, ocfg: OptimizerConfig, lr):
+    """The tree-form optimizer step, LAMB or AdamW as ``ocfg`` names:
+    (params', opt', metrics)."""
+    apply = lamb.apply_update if ocfg.name == "lamb" else adam.apply_update
+    return apply(params, grads, opt, ocfg, lr)
+
+
+def _flat_views(layout: bkt.BucketLayout, ocfg: OptimizerConfig):
+    """The packed layout's weight-decay mask, LAMB's per-element leaf
+    ids (None for AdamW) and its leaf count."""
+    dmask = bkt.decay_mask(layout)
+    segs = bkt.segment_ids(layout) if ocfg.name == "lamb" else None
+    return dmask, segs, len(layout.sizes)
+
+
+def _flat_barrier_update(pb, red, m, v, lr_step, ocfg, lr, *, inv_w,
+                         dmask, segs, n_leaves):
+    """Whole-stack flat optimizer update behind the barrier.
+
+    Shared by both overlap schedules for configs whose statistics need
+    every reduced bucket BEFORE the first moment update (global-norm
+    clipping), and by ``overlap_buckets`` for ALL of LAMB
+    (``overlap_backward`` streams LAMB instead — optim/lamb.py has the
+    full exactness rationale). Returns
+    (new_pb, new_m, new_v, gnorm, mean trust ratio).
+    """
+    gsc = red * inv_w
+    gnorm = jnp.sqrt(jnp.sum(gsc * gsc))
+    cs = (jnp.minimum(1.0, ocfg.grad_clip /
+                      jnp.maximum(gnorm, 1e-9))
+          if ocfg.grad_clip > 0 else None)
+    if ocfg.name == "lamb":
+        new_pb, new_m, new_v, trust = lamb.apply_update_flat(
+            pb, gsc, m, v, lr_step, ocfg, lr,
+            decay_mask=dmask, seg_ids=segs,
+            num_leaves=n_leaves, clip_scale=cs)
+    else:
+        new_pb, new_m, new_v = adam.apply_update_flat(
+            pb, gsc, m, v, lr_step, ocfg, lr,
+            decay_mask=dmask, clip_scale=cs)
+        trust = jnp.ones((), jnp.float32)
+    return new_pb, new_m, new_v, gnorm, trust
+
+
+def _adamw_bucket(ssq, g_k, rows, lr_step, ocfg, lr):
+    """The per-bucket AdamW hook both overlap schedules stream: bucket
+    k's scaled reduced gradient ``g_k`` applied to its flat ``rows``
+    (params, m, v, decay mask) the moment it lands. ``ssq`` carries the
+    squared gradient norm; returns (ssq', (params', m', v'))."""
+    p_k, m_k, v_k, dm_k = rows
+    out = adam.apply_update_flat(p_k, g_k, m_k, v_k, lr_step, ocfg, lr,
+                                 decay_mask=dm_k)
+    return ssq + jnp.sum(g_k * g_k), out
+
+
+def _packed_result(params, m, v, err, o, w, gnorm, trust, lr_step, lr,
+                   ocfg: OptimizerConfig):
+    """The overlap schedules' (TrainState, metrics): packed moments and
+    the streamed or barrier gradient norm and trust ratio."""
+    loss = weighting.finalize(o, w)
+    metrics = {"loss": loss, "weight": w, "grad_norm": gnorm, "lr": lr}
+    if ocfg.name == "lamb":
+        metrics["trust_ratio"] = trust
+    new_state = TrainState(
+        params=params, opt=adam.AdamState(step=lr_step, m=m, v=v),
+        err=err)
+    return new_state, metrics
+
+
 # --------------------------------------------------------------------------
-# backward-overlap step (HetConfig.overlap="backward")
+# monolithic schedules: allreduce, bucketed_allreduce, hierarchical,
+# overlap_buckets, canonical
+# --------------------------------------------------------------------------
+
+
+def _inner_ctx(mesh: Mesh, sched: TrainSchedule) -> ParallelCtx:
+    """The model's ParallelCtx inside one rank of the explicit exchange:
+    the manual axes must not appear in sharding constraints there —
+    hierarchical keeps "data" automatic inside the pod region,
+    bucketed_allreduce makes the whole DP set manual."""
+    if not sched.explicit:
+        return make_parallel_ctx(mesh)
+    return ParallelCtx(mesh=mesh, dp_axes=("data",) if sched.hier else (),
+                       tp_axis=tp_axis(mesh))
+
+
+def _grads_fn(model: Model, tcfg: TrainConfig, mesh: Mesh,
+              sched: TrainSchedule):
+    """``compute_grads(params, batch) -> (grad_of_sums, obj_sum,
+    weight_sum)``, unscaled, over the rows of one rank of the schedule's
+    exchange, with gradient accumulation."""
+    inner_ctx = _inner_ctx(mesh, sched)
+    accum = max(1, tcfg.het.accum_steps)
+    pspecs = _state_specs(model, mesh, sched).params if sched.hier else None
+
+    def objective(p, b):
+        o, w, _ = model.loss_fn(
+            p, b, inner_ctx, label_smoothing=tcfg.label_smoothing)
+        return o, w
+
+    def grad_fn(p, b):
+        # value_and_grad, split so that the device trace names the
+        # forward and the backward pass apart
+        with jax.named_scope("forward"):
+            o, pullback, w = jax.vjp(lambda q: objective(q, b), p,
+                                     has_aux=True)
+        with jax.named_scope("backward"):
+            g, = pullback(jnp.ones_like(o))
+        return (o, w), g
+
+    def compute_grads(params, batch):
+        if accum == 1:
+            (o, w), g = grad_fn(params, batch)
+        else:
+            mbs = acc.split_microbatches(batch, accum,
+                                         num_ranks=sched.inner_dp)
+            # unrolled-program class (scan_layers=False, required by
+            # overlap="backward"): keep the accumulation unrolled too
+            # so the staged backward stays bit-identical at accum > 1
+            accumulate = (acc.scan_accumulate if model.cfg.scan_layers
+                          else acc.unrolled_accumulate)
+            g, o, w = accumulate(grad_fn, params, mbs,
+                                 carry_dtype=_carry_dtype)
+        if sched.hier:
+            # inside the partially-manual region XLA's sharding
+            # propagation can lose the (data, model) layout of the
+            # gradients; re-pin them to the param specs so the pod
+            # exchange moves shards, not replicated leaves
+            g = jax.tree.map(jax.lax.with_sharding_constraint, g, pspecs)
+        return g, o, w
+
+    return compute_grads
+
+
+def _build_reduced_step(model: Model, tcfg: TrainConfig, mesh: Mesh,
+                        sched: TrainSchedule):
+    """``allreduce``, ``bucketed_allreduce`` and ``hierarchical``: the
+    whole gradient, reduced, then one tree-form optimizer step."""
+    ocfg = tcfg.optimizer
+    compute_grads = _grads_fn(model, tcfg, mesh, sched)
+    layout, use_err = sched.layout, sched.err is not None
+    q_impl = tcfg.het.quantize_impl
+
+    if sched.hier:
+        err_spec = P("pod") if use_err else P()
+
+        def pod_local(params, b, err):
+            g, o, w = compute_grads(params, b)
+            with jax.named_scope("exchange"):
+                # the cross-pod leg: bucketed engine or legacy walk
+                if layout is not None:
+                    g, ne = _reduce_bucketed(
+                        g, err if use_err else None, axis="pod",
+                        axis_size=sched.ranks, compress=sched.compress,
+                        layout=layout, impl=q_impl)
+                    ne = ne if ne is not None else ()
+                else:
+                    g, ne = _cross_pod_reduce(g, err, sched.compress,
+                                              sched.ranks)
+                return g, jax.lax.psum(o, "pod"), \
+                    jax.lax.psum(w, "pod"), ne
+
+        def reduced(state, batch):
+            return jax.shard_map(
+                pod_local, mesh=mesh,
+                in_specs=(P(), P("pod"), err_spec),
+                out_specs=(P(), P(), P(), err_spec),
+                axis_names={"pod"}, check_vma=False,
+            )(state.params, batch, state.err)
+    elif sched.explicit:
+        dp = sched.axes
+
+        def dp_local(params, b):
+            g, o, w = compute_grads(params, b)
+            with jax.named_scope("exchange"):
+                g, _ = _reduce_bucketed(g, None, axis=sched.axis,
+                                        axis_size=sched.ranks,
+                                        compress="none", layout=layout,
+                                        impl=q_impl)
+                return g, jax.lax.psum(o, dp), jax.lax.psum(w, dp)
+
+        def reduced(state, batch):
+            grads, o, w = jax.shard_map(
+                dp_local, mesh=mesh,
+                in_specs=(P(), P(dp)),
+                out_specs=(P(), P(), P()),
+                axis_names=set(dp), check_vma=False,
+            )(state.params, batch)
+            return grads, o, w, state.err
+    else:
+        def reduced(state, batch):
+            grads, o, w = compute_grads(state.params, batch)
+            return grads, o, w, state.err
+
+    def train_step(state: TrainState, batch: Dict
+                   ) -> Tuple[TrainState, Dict]:
+        grads, o, w, new_err = reduced(state, batch)
+        with jax.named_scope("exchange"):
+            # the weighted mean over every rank's rows (with plain
+            # allreduce the partitioner places the sums' collectives)
+            loss = weighting.finalize(o, w)
+            grads = weighting.scale_grads(grads, w)
+        with jax.named_scope("optimizer"):
+            lr = schedules.learning_rate(ocfg, state.opt.step + 1)
+            params, opt, met = _tree_update(state.params, grads,
+                                            state.opt, ocfg, lr)
+        metrics = {"loss": loss, "weight": w, **met}
+        return TrainState(params=params, opt=opt, err=new_err), metrics
+
+    return train_step
+
+
+def _build_overlap_buckets_step(model: Model, tcfg: TrainConfig,
+                                mesh: Mesh, sched: TrainSchedule):
+    """``overlap_buckets``: the optimizer moves INSIDE the manual region.
+
+    The per-bucket pipeline exchanges bucket k while bucket k+1
+    quantizes, and the flat-view AdamW update for bucket k runs the
+    moment it lands. The packed moments enter/leave the region
+    replicated over the reduction axes; every rank computes the
+    identical update.
+    """
+    ocfg = tcfg.optimizer
+    compute_grads = _grads_fn(model, tcfg, mesh, sched)
+    layout, use_err = sched.layout, sched.err is not None
+    dmask, segs, n_leaves = _flat_views(layout, ocfg)
+
+    def local(params, b, err, m, v, lr_step, lr):
+        """One rank of the reduction: its grads, reduced bucket by bucket
+        with each bucket's update fused, on its (nb, be) error slice."""
+        g, o, w = compute_grads(params, b)
+        o = jax.lax.psum(o, sched.axis)
+        w = jax.lax.psum(w, sched.axis)
+        e = (err.reshape(layout.num_buckets, layout.bucket_elems)
+             if use_err else None)
+        gb = bkt.pack_buckets(g, layout)
+        pb = bkt.pack_buckets(params, layout)
+        inv_w = 1.0 / jnp.maximum(w, 1e-9)
+        kwargs = dict(axis=sched.axis, axis_size=sched.ranks,
+                      compress=(sched.compress != "none"),
+                      block_size=_BLOCK, impl=tcfg.het.quantize_impl)
+        if sched.stream and ocfg.name != "lamb":
+            outs, new_e, ssq = bkt.exchange_buckets_overlapped(
+                gb, e, fn_carry=jnp.zeros((), jnp.float32),
+                bucket_fn=lambda ssq, red_k, xs_k, k: _adamw_bucket(
+                    ssq, red_k * inv_w, xs_k, lr_step, ocfg, lr),
+                bucket_xs=(pb, m, v, dmask), **kwargs)
+            new_pb, new_m, new_v = outs
+            gnorm = jnp.sqrt(ssq)
+            trust = jnp.ones((), jnp.float32)
+        else:
+            # clip barrier, and ALL of LAMB in this after-backward
+            # engine: fusing LAMB's hook into the per-bucket scan
+            # deterministically perturbs how XLA compiles the
+            # whole-module gradient/reduction program (~0.4% of
+            # reduced-grad elements move 1 ulp, measured across every
+            # hook/optimization_barrier variant), which breaks the
+            # backward==buckets bitwise contract (tests/test_overlap.py).
+            # overlap_backward streams LAMB bitwise-safely; here the
+            # barrier form is the bit-exact choice — and the exchange is
+            # already fully overlapped bucket-to-bucket, so only the
+            # optimizer pass trails.
+            red, new_e, _ = bkt.exchange_buckets_overlapped(
+                gb, e, **kwargs)
+            new_pb, new_m, new_v, gnorm, trust = _flat_barrier_update(
+                pb, red, m, v, lr_step, ocfg, lr, inv_w=inv_w,
+                dmask=dmask, segs=segs, n_leaves=n_leaves)
+        new_params = bkt.unpack_buckets(new_pb, layout)
+        if use_err:
+            err = new_e.reshape(1, layout.num_buckets, layout.bucket_elems)
+        return new_params, new_m, new_v, err, o, w, gnorm, trust
+
+    def train_step(state: TrainState, batch: Dict
+                   ) -> Tuple[TrainState, Dict]:
+        lr_step = state.opt.step + 1
+        lr = schedules.learning_rate(ocfg, lr_step)
+        err_spec = P("pod") if use_err else P()
+        batch_spec = P("pod") if sched.hier else P(sched.axes)
+        (new_params, new_m, new_v, new_err, o, w, gnorm,
+         trust) = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(), batch_spec, err_spec, P(), P(), P(), P()),
+            out_specs=(P(), P(), P(), err_spec, P(), P(), P(), P()),
+            axis_names=set(sched.axes), check_vma=False,
+        )(state.params, batch, state.err if use_err else (), state.opt.m,
+          state.opt.v, lr_step, lr)
+        return _packed_result(
+            new_params, new_m, new_v, new_err if use_err else state.err,
+            o, w, gnorm, trust, lr_step, lr, ocfg)
+
+    return train_step
+
+
+def _build_canonical_step(model: Model, tcfg: TrainConfig, mesh: Mesh,
+                          sched: TrainSchedule):
+    """``canonical``: the order-canonical executor (core/weighting.py).
+
+    Per-row vmapped grads summed along the global-row axis with ONE
+    fixed reduction tree. The row->rank partition drops out of the
+    float math entirely, so two runs consuming the same global rows are
+    bit-identical whatever capacity replans happened in between —
+    provided the sampler emits rows in canonical global order
+    (HetSampler(canonical_order=True)).
+    """
+    ocfg = tcfg.optimizer
+
+    def row_loss(p, b):
+        return model.loss_fn(p, b, label_smoothing=tcfg.label_smoothing)
+
+    def train_step(state: TrainState, batch: Dict
+                   ) -> Tuple[TrainState, Dict]:
+        (o_r, w_r), g_r = weighting.per_row_values(
+            row_loss, state.params, batch)
+        loss, grads, _, w = weighting.canonical_aggregate(o_r, w_r, g_r)
+        lr = schedules.learning_rate(ocfg, state.opt.step + 1)
+        params, opt, met = _tree_update(state.params, grads, state.opt,
+                                        ocfg, lr)
+        metrics = {"loss": loss, "weight": w, **met}
+        return TrainState(params=params, opt=opt, err=state.err), metrics
+
+    return train_step
+
+
+# --------------------------------------------------------------------------
+# staged plumbing: overlap_backward and the bucketed pipeline
 # --------------------------------------------------------------------------
 
 
@@ -583,15 +888,17 @@ def _path_top(entry) -> str:
     return str(entry)
 
 
-def _staged_leaf_pieces(params_shape: Any, cfg: ModelConfig):
-    """Per-leaf ``(offset_within_leaf, n, backward_stage)`` pieces.
+def _leaf_pieces(params_shape: Any, cfg: ModelConfig,
+                 layer_stage: Callable[[int], int]):
+    """Per-leaf ``(offset_within_leaf, n, stage)`` pieces: the model's
+    layer partition mapped onto the flat gradient stream, for
+    ``core/buckets.py::bucket_readiness``.
 
-    The model's layer partition mapped onto the flat stream: stacked
-    ``layers`` leaves split into per-layer slices landing back to
-    front (layer *l* at stage ``L - l``), the head leaves at stage 0,
-    the embedding table last (stage ``L + 1`` — a tied table also
-    receives a head-stage contribution, so its grad is only final at
-    the end). Feeds ``core/buckets.py::bucket_readiness``.
+    Stacked ``layers`` leaves split into per-layer slices, layer *l*
+    landing at ``layer_stage(l)``; the head leaves land at stage 0 and
+    the embedding table one stage after layer 0, last (a tied table
+    also receives a head-stage contribution, so its grad is only final
+    at the end).
     """
     from repro.models import transformer as tr
 
@@ -608,25 +915,178 @@ def _staged_leaf_pieces(params_shape: Any, cfg: ModelConfig):
                     f"stacked leaf {jax.tree_util.keystr(path)} of "
                     f"{n} elements does not split into {L} layers")
             per = n // L
-            pieces.append([(l * per, per, L - l) for l in range(L)])
+            pieces.append([(l * per, per, layer_stage(l))
+                           for l in range(L)])
         elif top == "embed":
-            pieces.append([(0, n, L + 1)])
+            pieces.append([(0, n, layer_stage(0) + 1)])
         elif top in head_keys:
             pieces.append([(0, n, 0)])
         else:
             raise ValueError(
-                f"overlap='backward': unexpected param subtree "
-                f"'{top}' (uniform stack expects embed / final_norm / "
-                f"lm_head / layers)")
+                f"staged train step: unexpected param subtree '{top}' "
+                f"(uniform stack expects embed / final_norm / lm_head / "
+                f"layers)")
     return pieces
 
 
+def _stream_scatter(sched: TrainSchedule, num_layers: int):
+    """``scatter(buf, top, grads, layers=None)``: scatter-add a landed
+    grad subtree into the (ranks, padded_total) f32 stream buffer;
+    ``layers=(r0, r1)`` places a stacked subtree holding layers r0..r1
+    (exclusive) within each of its leaves."""
+    slots: Dict[str, list] = {}
+    for (path, _), off, size in zip(
+            jax.tree_util.tree_flatten_with_path(sched.params_shape)[0],
+            sched.layout.offsets, sched.layout.sizes):
+        slots.setdefault(_path_top(path[0]), []).append((off, size))
+
+    def scatter(buf, top, grads, layers=None):
+        leaves = jax.tree.leaves(grads)
+        # zero-leaf subtrees (non-parametric norms) never reach the
+        # stream
+        got = slots.get(top, [])
+        assert len(leaves) == len(got), (top, len(leaves), len(got))
+        for g, (off, size) in zip(leaves, got):
+            if layers is not None:
+                r0, r1 = layers
+                per = size // num_layers
+                off, size = off + r0 * per, (r1 - r0) * per
+            buf = buf.at[:, off:off + size].add(
+                g.reshape(sched.ranks, size).astype(jnp.float32))
+        return buf
+
+    return scatter
+
+
+def _rank_spec(sched: TrainSchedule) -> P:
+    """The spec of a rank-major (ranks, rows per rank, ...) array."""
+    return P("pod", "data") if sched.hier else P(sched.axes)
+
+
+def _rank_microbatches(batch: Dict, sched: TrainSchedule, accum: int):
+    """The batch reshaped rank-major and cut into ``accum``
+    microbatches with the row assignment of the monolithic
+    ``acc.split_microbatches`` (inner-rank-major, so every microbatch
+    takes an equal slice of every inner DP rank's buffer)."""
+    ranks, inner = sched.ranks, sched.inner_dp
+    sb = jax.tree.map(
+        lambda v: jax.lax.with_sharding_constraint(
+            v.reshape(ranks, v.shape[0] // ranks, *v.shape[1:]),
+            _rank_spec(sched)), batch)
+    if accum == 1:
+        return [sb]
+
+    def split(a):
+        b, tail = a.shape[1], a.shape[2:]
+        if b % (inner * accum):
+            raise ValueError(
+                f"rows {b} per reduction rank not divisible by "
+                f"accum {accum} x inner ranks {inner}")
+        if inner > 1:
+            a = jnp.swapaxes(a.reshape(ranks, inner, accum,
+                                       b // inner // accum, *tail), 1, 2)
+        return a.reshape(ranks, accum, b // accum, *tail)
+
+    s = {k: split(v) for k, v in sb.items()}
+    return [jax.tree.map(lambda a: a[:, i], s) for i in range(accum)]
+
+
+def _embed_grads(embed_fn, emb_p, inputs, x_cot):
+    """The token table's gradient from its output's cotangent, per
+    reduction rank."""
+    def embed_stage(ep, i, xc):
+        _, vjp = jax.vjp(lambda q: embed_fn(q, i), ep)
+        return vjp(xc)[0]
+
+    return jax.vmap(embed_stage, in_axes=(None, 0, 0))(
+        emb_p, inputs, x_cot)["embed"]
+
+
+def _flush_pipeline(mesh: Mesh, sched: TrainSchedule, readiness,
+                    q_impl: str, err_in=None, **hook):
+    """``(pipeline, flush)``: a ``core/buckets.py::BucketFlushPipeline``
+    over the stream buffer, and ``flush(stage, buf)``, which feeds it
+    every bucket that is ready at ``stage``.
+
+    Bucket k's send-side leg quantizes/packs per rank at the top level
+    (no collectives — it overlaps the previous bucket's in-flight
+    exchange); its link and receive legs run in a small manual region
+    of their own — the only collectives in the program, so they
+    interleave with the staged backward in program order. ``err_in`` is
+    the flat (ranks, num_buckets, bucket_elems) error state, or None;
+    ``hook`` the pipeline's ``bucket_fn`` and ``fn_carry``.
+    """
+    ranks, red_axis = sched.ranks, sched.axis
+    shard = sched.layout.bucket_elems // ranks
+    compress = sched.compress != "none"
+    use_err = err_in is not None
+    axis_set = set(sched.axes)
+    buf_spec = P(red_axis)
+
+    def prep(k, raw_k):
+        x_k = raw_k.reshape(ranks, ranks, shard)
+        if not compress:
+            return x_k, None
+        e_k = (err_in[:, k].reshape(ranks, ranks, shard)
+               if use_err else None)
+        pv = jax.vmap(
+            lambda xk, ek: bkt.prepare_bucket(
+                xk, ek, compress=True, block_size=_BLOCK,
+                key=None, impl=q_impl, interpret=False),
+            in_axes=(0, 0 if use_err else None))
+        return pv(x_k, e_k)
+
+    def exchange(k, prepared):
+        payload, resid1 = prepared
+        if compress and use_err:
+            def region(pl, rs):
+                onehot = bkt.rank_onehot(red_axis, ranks)
+                red, ne = bkt.exchange_prepared_bucket(
+                    pl[0], rs[0], axis=red_axis, axis_size=ranks,
+                    compress=True, block_size=_BLOCK, impl=q_impl,
+                    interpret=False, onehot=onehot)
+                return red, ne[None]
+
+            return jax.shard_map(
+                region, mesh=mesh, in_specs=(buf_spec, buf_spec),
+                out_specs=(P(), buf_spec), axis_names=axis_set,
+                check_vma=False)(payload, resid1)
+
+        def region(pl):
+            onehot = bkt.rank_onehot(red_axis, ranks)
+            red, _ = bkt.exchange_prepared_bucket(
+                pl[0], None, axis=red_axis, axis_size=ranks,
+                compress=compress, block_size=_BLOCK,
+                impl=q_impl, interpret=False, onehot=onehot)
+            return red
+
+        red = jax.shard_map(
+            region, mesh=mesh, in_specs=buf_spec, out_specs=P(),
+            axis_names=axis_set, check_vma=False)(payload)
+        return red, None
+
+    pipeline = bkt.BucketFlushPipeline(readiness, prep, exchange, **hook)
+    be = sched.layout.bucket_elems
+
+    def flush(stage, buf):
+        pipeline.flush_ready_buckets(
+            stage, lambda k: buf[:, k * be:(k + 1) * be])
+
+    return pipeline, flush
+
+
+def _stream_buffer(sched: TrainSchedule):
+    """The zeroed (ranks, padded_total) f32 gradient stream, one row per
+    reduction rank."""
+    return jax.lax.with_sharding_constraint(
+        jnp.zeros((sched.ranks, sched.layout.padded_total), jnp.float32),
+        P(sched.axis))
+
+
 def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
-                                 mesh: Mesh, *, layout: bkt.BucketLayout,
-                                 hier: bool, compress: str,
-                                 use_err: bool, fused_stream: bool):
-    """The ``overlap="backward"`` train step: flush gradient buckets
-    DURING backprop instead of after it.
+                                 mesh: Mesh, sched: TrainSchedule):
+    """``overlap_backward``: flush gradient buckets DURING backprop
+    instead of after it.
 
     Structure: the batch is reshaped rank-major and every backward stage
     is a vmapped per-layer VJP in plain SPMD at the TOP level of the
@@ -638,10 +1098,7 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
     (core/buckets.py::BucketFlushPipeline, readiness derived from the
     layer partition). The program-order interleaving of exchange
     regions with the remaining backward stages is what hands the
-    runtime the overlap; the CPU host mesh executes collectives
-    eagerly, so the modeled bwd+link timeline in
-    benchmarks/overlap_bench.py is the claim — exactly as for
-    ``overlap="buckets"``.
+    runtime the overlap.
 
     Exactness: fp32 with ``grad_clip=0`` is bit-identical to the
     monolithic path (same config, ``overlap="none"``) — per-bucket
@@ -651,69 +1108,28 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
     one trailing trust pass (optim/lamb.py — bitwise-equal to the
     barrier form by construction); global-norm clip keeps the
     in-backward pipelined exchange but applies the flat update behind
-    a barrier. Gradient accumulation
-    stages every microbatch's backward and flushes only during the
-    last one (the bucket is final only then); the accumulator is the
-    fp32 stream buffer, so bf16-carry configs differ from the
-    monolithic bf16 carry by that last rounding step (documented
-    trade).
+    a barrier. Gradient accumulation stages every microbatch's backward
+    and flushes only during the last one (the bucket is final only
+    then); the accumulator is the fp32 stream buffer, so bf16-carry
+    configs differ from the monolithic bf16 carry by that last rounding
+    step (documented trade).
     """
     from repro.models import transformer as tr
 
     cfg = model.cfg
     ocfg = tcfg.optimizer
     accum = max(1, tcfg.het.accum_steps)
-    q_impl = tcfg.het.quantize_impl
-    dp = mesh_dp_axes(mesh)
-    n_dp = dp_size(mesh)
-    n_pods = mesh.shape["pod"] if "pod" in mesh.axis_names else 1
     L = cfg.num_layers
-    ranks = n_pods if hier else n_dp
-    inner_dp = (n_dp // n_pods) if hier else 1
-    red_axis: Any = "pod" if hier else (dp if len(dp) > 1 else dp[0])
-    axis_set = {"pod"} if hier else set(dp)
-    rank_spec = P("pod", "data") if hier else P(dp)
-    buf_spec = P("pod") if hier else P(dp if len(dp) > 1 else dp[0])
-    nb, be = layout.num_buckets, layout.bucket_elems
-    shard = be // ranks
-    compress_flag = compress != "none"
-    dmask = bkt.decay_mask(layout)
-    segs = bkt.segment_ids(layout) if ocfg.name == "lamb" else None
-    n_leaves = len(layout.sizes)
-    params_shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    readiness = bkt.bucket_readiness(
-        layout, _staged_leaf_pieces(params_shape, cfg))
+    layout, use_err = sched.layout, sched.err is not None
+    dmask, segs, n_leaves = _flat_views(layout, ocfg)
+    readiness = bkt.bucket_readiness(layout, _leaf_pieces(
+        sched.params_shape, cfg, lambda l: L - l))
+    scatter = _stream_scatter(sched, L)
     token_frontend = cfg.frontend == "token"
-    inner_ctx = ParallelCtx(mesh=mesh,
-                            dp_axes=("data",) if hier else (),
-                            tp_axis=tp_axis(mesh))
     seg = tr.staged_uniform_segments(
-        cfg, inner_ctx, label_smoothing=tcfg.label_smoothing)
+        cfg, _inner_ctx(mesh, sched), label_smoothing=tcfg.label_smoothing)
     embed_fn, layer_fn = seg["embed_fn"], seg["layer_fn"]
     head_fn, head_keys = seg["head_fn"], seg["head_keys"]
-
-    # stream-offset bookkeeping per top-level subtree, flatten order
-    subtree_slots: Dict[str, list] = {}
-    for (path, _), off, size in zip(
-            jax.tree_util.tree_flatten_with_path(params_shape)[0],
-            layout.offsets, layout.sizes):
-        subtree_slots.setdefault(_path_top(path[0]), []).append(
-            (off, size))
-
-    def scatter_subtree(buf, top, grads, layer=None):
-        """Scatter-add a landed grad subtree into the stream buffer."""
-        leaves = jax.tree.leaves(grads)
-        # zero-leaf subtrees (non-parametric norms) never reach the
-        # stream
-        slots = subtree_slots.get(top, [])
-        assert len(leaves) == len(slots), (top, len(leaves), len(slots))
-        for g, (off, size) in zip(leaves, slots):
-            if layer is not None:
-                per = size // L
-                off, size = off + layer * per, per
-            buf = buf.at[:, off:off + size].add(
-                g.reshape(ranks, size).astype(jnp.float32))
-        return buf
 
     def staged_microbatch(params, lps, mb, buf, flush=None,
                           on_loss=None):
@@ -759,7 +1175,7 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
         if on_loss is not None:
             on_loss(o, w)
         for key in head_keys:
-            buf = scatter_subtree(buf, key, g_hp[key])
+            buf = scatter(buf, key, g_hp[key])
         if flush is not None:
             flush(0, buf)
         w_sg = jax.lax.stop_gradient(w)
@@ -773,112 +1189,32 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
             g_lp, x_cot = jax.vmap(
                 layer_stage, in_axes=(None, 0, 0, 0))(
                 lps[l], xs[l], x_cot, w_sg)
-            buf = scatter_subtree(buf, "layers", g_lp, layer=l)
+            buf = scatter(buf, "layers", g_lp, layers=(l, l + 1))
             if flush is not None:
                 flush(L - l, buf)
         if token_frontend:
-            def embed_stage(ep, i, xc):
-                _, vjp = jax.vjp(lambda q: embed_fn(q, i), ep)
-                return vjp(xc)[0]
-
-            g_emb = jax.vmap(embed_stage, in_axes=(None, 0, 0))(
-                emb_p, mb["inputs"], x_cot)
-            buf = scatter_subtree(buf, "embed", g_emb["embed"])
+            buf = scatter(buf, "embed", _embed_grads(
+                embed_fn, emb_p, mb["inputs"], x_cot))
         if flush is not None:
             flush(L + 1, buf)
         return buf, o, w
 
-    def split_rank_microbatches(sb):
-        """Per-rank accumulation split, matching the monolithic
-        acc.split_microbatches row assignment (inner-rank-major, so
-        every microbatch takes an equal slice of every inner DP
-        rank's buffer)."""
-        if accum == 1:
-            return [sb]
-
-        def split(a):
-            b = a.shape[1]
-            if b % (inner_dp * accum):
-                raise ValueError(
-                    f"rows {b} per reduction rank not divisible by "
-                    f"accum {accum} x inner ranks {inner_dp}")
-            a2 = a.reshape(ranks, inner_dp, accum,
-                           b // inner_dp // accum, *a.shape[2:])
-            a2 = jnp.swapaxes(a2, 1, 2)
-            return a2.reshape(ranks, accum, b // accum, *a.shape[2:])
-
-        s = {k: split(v) for k, v in sb.items()}
-        return [jax.tree.map(lambda a: a[:, i], s) for i in range(accum)]
-
-    def step(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
+    def train_step(state: TrainState, batch: Dict
+                   ) -> Tuple[TrainState, Dict]:
         lr_step = state.opt.step + 1
         lr = schedules.learning_rate(ocfg, lr_step)
         params = state.params
-        sb = jax.tree.map(
-            lambda v: jax.lax.with_sharding_constraint(
-                v.reshape(ranks, v.shape[0] // ranks, *v.shape[1:]),
-                rank_spec), batch)
-        mbs = split_rank_microbatches(sb)
+        mbs = _rank_microbatches(batch, sched, accum)
         lps = [jax.tree.map(lambda a: a[l], params["layers"])
                for l in range(L)]
         pb = bkt.pack_buckets(params, layout)
-        err_in = state.err if use_err else None   # (pods, nb, be)
-
-        def prep(k, raw_k):
-            """Send-side leg for bucket k: quantize/pack per rank at
-            the top level (no collectives — it overlaps the previous
-            bucket's in-flight exchange)."""
-            x_k = raw_k.reshape(ranks, ranks, shard)
-            if not compress_flag:
-                return x_k, None
-            e_k = (err_in[:, k].reshape(ranks, ranks, shard)
-                   if use_err else None)
-            pv = jax.vmap(
-                lambda xk, ek: bkt.prepare_bucket(
-                    xk, ek, compress=True, block_size=_BLOCK,
-                    key=None, impl=q_impl, interpret=False),
-                in_axes=(0, 0 if use_err else None))
-            return pv(x_k, e_k)
-
-        def exchange(k, prepared):
-            """Link + receive legs for ONE bucket, in its own small
-            manual region — the only collectives in the program, so
-            they interleave with the staged backward in program
-            order."""
-            payload, resid1 = prepared
-            if compress_flag and use_err:
-                def region(pl, rs):
-                    onehot = bkt.rank_onehot(red_axis, ranks)
-                    red, ne = bkt.exchange_prepared_bucket(
-                        pl[0], rs[0], axis=red_axis, axis_size=ranks,
-                        compress=True, block_size=_BLOCK, impl=q_impl,
-                        interpret=False, onehot=onehot)
-                    return red, ne[None]
-
-                return jax.shard_map(
-                    region, mesh=mesh, in_specs=(buf_spec, buf_spec),
-                    out_specs=(P(), buf_spec), axis_names=axis_set,
-                    check_vma=False)(payload, resid1)
-
-            def region(pl):
-                onehot = bkt.rank_onehot(red_axis, ranks)
-                red, _ = bkt.exchange_prepared_bucket(
-                    pl[0], None, axis=red_axis, axis_size=ranks,
-                    compress=compress_flag, block_size=_BLOCK,
-                    impl=q_impl, interpret=False, onehot=onehot)
-                return red
-
-            red = jax.shard_map(
-                region, mesh=mesh, in_specs=buf_spec, out_specs=P(),
-                axis_names=axis_set, check_vma=False)(payload)
-            return red, None
-
         cell: Dict[str, Any] = {}
-        if fused_stream:
+        hook: Dict[str, Any] = {}
+        if sched.stream:
             if ocfg.name == "lamb":
                 # stream moments + per-leaf norm partials per bucket;
                 # the trust-scaled step itself trails (finish below)
-                def hook(ssq, red_k, k):
+                def bucket_fn(ssq, red_k, k):
                     g_k = red_k * cell["inv_w"]
                     pf, upd, mf, vf = adam.flat_adamw_terms(
                         pb[k], g_k, state.opt.m[k], state.opt.v[k],
@@ -888,29 +1224,21 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
                     return (ssq + jnp.sum(g_k * g_k),
                             (pf, upd, mf, vf, psq, usq))
             else:
-                def hook(ssq, red_k, k):
-                    g_k = red_k * cell["inv_w"]
-                    out = adam.apply_update_flat(
-                        pb[k], g_k, state.opt.m[k], state.opt.v[k],
-                        lr_step, ocfg, lr, decay_mask=dmask[k])
-                    return ssq + jnp.sum(g_k * g_k), out
+                def bucket_fn(ssq, red_k, k):
+                    return _adamw_bucket(
+                        ssq, red_k * cell["inv_w"],
+                        (pb[k], state.opt.m[k], state.opt.v[k],
+                         dmask[k]), lr_step, ocfg, lr)
 
-            pipeline = bkt.BucketFlushPipeline(
-                readiness, prep, exchange, bucket_fn=hook,
-                fn_carry=jnp.zeros((), jnp.float32))
-        else:
-            pipeline = bkt.BucketFlushPipeline(readiness, prep,
-                                               exchange)
+            hook = dict(bucket_fn=bucket_fn,
+                        fn_carry=jnp.zeros((), jnp.float32))
+        pipeline, flush = _flush_pipeline(
+            mesh, sched, readiness, tcfg.het.quantize_impl,
+            state.err if use_err else None, **hook)
 
-        def flush(stage, buf):
-            pipeline.flush_ready_buckets(
-                stage, lambda k: buf[:, k * be:(k + 1) * be])
-
-        buf = jax.lax.with_sharding_constraint(
-            jnp.zeros((ranks, layout.padded_total), jnp.float32),
-            buf_spec)
-        o_acc = jnp.zeros((ranks,), jnp.float32)
-        w_acc = jnp.zeros((ranks,), jnp.float32)
+        buf = _stream_buffer(sched)
+        o_acc = jnp.zeros((sched.ranks,), jnp.float32)
+        w_acc = jnp.zeros((sched.ranks,), jnp.float32)
         for i, mb in enumerate(mbs):
             if i == accum - 1:
                 def on_loss(o_mb, w_mb, _oa=o_acc, _wa=w_acc):
@@ -930,7 +1258,7 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
 
         outs, errs, fc = pipeline.finish()
         o, w = jnp.sum(cell["o"]), cell["w_glob"]
-        if fused_stream and ocfg.name == "lamb":
+        if sched.stream and ocfg.name == "lamb":
             # finish() hands outs back in BUCKET-INDEX order whatever
             # order the buckets flushed in — so the partial-norm
             # combination below is the canonical one apply_update_flat
@@ -948,7 +1276,7 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
                 [row[3] for row in outs]).astype(state.opt.v.dtype)
             gnorm = jnp.sqrt(fc)
             trust = jnp.mean(trust_v[:n_leaves])
-        elif fused_stream:
+        elif sched.stream:
             new_pb = jnp.stack([row[0] for row in outs])
             new_m = jnp.stack([row[1] for row in outs])
             new_v = jnp.stack([row[2] for row in outs])
@@ -963,23 +1291,15 @@ def _build_backward_overlap_step(model: Model, tcfg: TrainConfig,
         new_params = bkt.unpack_buckets(new_pb, layout)
         new_err = state.err
         if use_err and errs is not None:
-            new_err = jnp.stack(errs, axis=1).reshape(ranks, nb, be)
-        loss = weighting.finalize(o, w)
-        metrics = {"loss": loss, "weight": w, "grad_norm": gnorm,
-                   "lr": lr}
-        if ocfg.name == "lamb":
-            metrics["trust_ratio"] = trust
-        new_state = TrainState(
-            params=new_params,
-            opt=adam.AdamState(step=lr_step, m=new_m, v=new_v),
-            err=new_err)
-        return new_state, metrics
+            new_err = jnp.stack(errs, axis=1).reshape(state.err.shape)
+        return _packed_result(new_params, new_m, new_v, new_err, o, w,
+                              gnorm, trust, lr_step, lr, ocfg)
 
-    return step
+    return train_step
 
 
 # --------------------------------------------------------------------------
-# pipeline-parallel step (HetConfig.pipeline_stages > 1)
+# pipeline-parallel schedule (HetConfig.pipeline_stages > 1)
 # --------------------------------------------------------------------------
 
 
@@ -1005,56 +1325,11 @@ def _pipe_send(x: jnp.ndarray, mesh: Mesh, spec: P,
         axis_names={"pipe"}, check_vma=False)(x)
 
 
-def _pipeline_leaf_pieces(params_shape: Any, cfg: ModelConfig,
-                          splan: pipe.StagePlan):
-    """Per-leaf ``(offset_within_leaf, n, flush_stage)`` pieces for the
-    pipeline's bucket engine (cf. ``_staged_leaf_pieces``).
-
-    Flush stages follow the LAST microbatch's backward completion
-    order: the head lands first (flush stage 0), layer ``l`` at the B
-    event of its pipeline stage (flush stage ``S - 1 -
-    stage_of_layer(l)``), the embedding table last (flush stage ``S`` —
-    a tied table also receives a head-stage contribution, so its grad
-    is only final at the end). Feeds
-    ``core/buckets.py::bucket_readiness``.
-    """
-    from repro.models import transformer as tr
-
-    L = cfg.num_layers
-    S = splan.num_stages
-    head_keys = set(tr.head_param_keys(cfg))
-    pieces = []
-    for path, leaf in jax.tree_util.tree_flatten_with_path(
-            params_shape)[0]:
-        n = int(math.prod(leaf.shape)) if leaf.shape else 1
-        top = _path_top(path[0])
-        if top == "layers":
-            if n % L:
-                raise ValueError(
-                    f"stacked leaf {jax.tree_util.keystr(path)} of "
-                    f"{n} elements does not split into {L} layers")
-            per = n // L
-            pieces.append([(l * per, per,
-                            S - 1 - splan.stage_of_layer(l))
-                           for l in range(L)])
-        elif top == "embed":
-            pieces.append([(0, n, S)])
-        elif top in head_keys:
-            pieces.append([(0, n, 0)])
-        else:
-            raise ValueError(
-                f"pipeline_stages > 1: unexpected param subtree "
-                f"'{top}' (uniform stack expects embed / final_norm / "
-                f"lm_head / layers)")
-    return pieces
-
-
-def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
-                         splan: pipe.StagePlan,
-                         layout: Optional[bkt.BucketLayout]):
-    """The pipelined train step: capacity-sized contiguous stages, the
-    accumulation microbatches streamed through them in 1F1B (or GPipe)
-    program order.
+def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh,
+                         sched: TrainSchedule):
+    """``pipeline``: capacity-sized contiguous stages, the accumulation
+    microbatches streamed through them in 1F1B (or GPipe) program
+    order.
 
     The step emits one deterministic global sequence of per-stage VJP
     segments (core/pipeline.py::program_order): each F event runs one
@@ -1069,17 +1344,17 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
     config (``pipeline_stages=1``), whatever the stage partition
     (BENCH_pipeline.json invariant).
 
-    Reduction: with ``grad_reduction="allreduce"`` (``layout`` None)
+    Reduction: with ``grad_reduction="allreduce"`` (no bucket layout)
     XLA reduces from the shardings exactly as the monolithic path;
     with ``"bucketed_allreduce"`` the grads live in the flat (ranks,
     padded_total) stream and each stage's buckets flush through their
     own small exchange regions the moment the last microbatch's B event
-    for that stage lands (readiness from ``_pipeline_leaf_pieces``) —
-    per-stage reduction overlapping the remaining drain, mirroring
-    ``overlap="backward"``'s engine. The tree-form optimizer runs after
-    the drain (``overlap`` must be "none" with pipelining —
-    HetConfig.validate), so moments stay a pytree and checkpoints
-    restore bit-exactly across stage plans, including pure DP.
+    for that stage lands — per-stage reduction overlapping the
+    remaining drain, with the staged plumbing of ``overlap_backward``.
+    The tree-form optimizer runs after the drain (``overlap`` must be
+    "none" with pipelining — HetConfig.validate), so moments stay a
+    pytree and checkpoints restore bit-exactly across stage plans,
+    including pure DP.
 
     Exactness on the bucketed path: losses are bit-identical to the
     stages=1 bucketed step, but parameters can drift by 1-2 ulp — XLA
@@ -1095,30 +1370,24 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
     cfg = model.cfg
     ocfg = tcfg.optimizer
     M = max(1, tcfg.het.accum_steps)
+    splan = sched.splan
     S = splan.num_stages
     ranges = splan.stage_ranges()
     events = pipe.program_order(S, M, schedule=tcfg.het.pipeline_schedule)
-    dp = mesh_dp_axes(mesh)
-    n_dp = dp_size(mesh)
     token_frontend = cfg.frontend == "token"
-    L = cfg.num_layers
+    seg = tr.pipeline_stage_fns(cfg, _inner_ctx(mesh, sched), ranges,
+                                label_smoothing=tcfg.label_smoothing)
+    embed_fn, head_fn = seg["embed_fn"], seg["head_fn"]
+    head_keys, stage_fwd = seg["head_keys"], seg["stage_fwd"]
 
-    def carry_dtype(p):
-        # same bf16 passthrough as compute_grads' accumulation carry
-        return p.dtype if p.dtype == jnp.bfloat16 else jnp.float32
-
-    if layout is None:
+    if sched.layout is None:
         # ---- plain-SPMD path (grad_reduction="allreduce") ------------
-        ctx = make_parallel_ctx(mesh)
-        seg = tr.pipeline_stage_fns(cfg, ctx, ranges,
-                                    label_smoothing=tcfg.label_smoothing)
-        embed_fn, head_fn = seg["embed_fn"], seg["head_fn"]
-        head_keys, stage_fwd = seg["head_keys"], seg["stage_fwd"]
+        n_dp = dp_size(mesh)
         act_spec = shr.stage_activation_spec(
             mesh, tcfg.shape.global_batch // M)
 
-        def step(state: TrainState, batch: Dict
-                 ) -> Tuple[TrainState, Dict]:
+        def train_step(state: TrainState, batch: Dict
+                       ) -> Tuple[TrainState, Dict]:
             lr_step = state.opt.step + 1
             lr = schedules.learning_rate(ocfg, lr_step)
             params = state.params
@@ -1129,7 +1398,7 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
             emb_p = {"embed": params["embed"]} if token_frontend else {}
             hp = {k: params[k] for k in head_keys}
             g_acc = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, carry_dtype(p)), params)
+                lambda p: jnp.zeros(p.shape, _carry_dtype(p)), params)
             o_acc = jnp.zeros((), jnp.float32)
             w_acc = jnp.zeros((), jnp.float32)
             x_in: Dict = {}
@@ -1211,120 +1480,38 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
                             g_emb.astype(g_acc["embed"].dtype)
             loss = weighting.finalize(o_acc, w_acc)
             grads = weighting.scale_grads(g_acc, w_acc)
-            opt_apply = (lamb.apply_update if ocfg.name == "lamb"
-                         else adam.apply_update)
-            new_params, opt, met = opt_apply(params, grads, state.opt,
-                                             ocfg, lr)
+            new_params, opt, met = _tree_update(params, grads, state.opt,
+                                                ocfg, lr)
             metrics = {"loss": loss, "weight": w_acc, **met}
             return TrainState(params=new_params, opt=opt,
                               err=state.err), metrics
 
-        return step
+        return train_step
 
     # ---- bucketed path (grad_reduction="bucketed_allreduce") ---------
     # rank-major vmapped stage VJPs with the flat f32 gradient stream;
     # per-stage bucket flushes through small manual exchange regions
-    # (cf. _build_backward_overlap_step — same engine, pipeline order)
-    inner_ctx = ParallelCtx(mesh=mesh, dp_axes=(), tp_axis=tp_axis(mesh))
-    seg = tr.pipeline_stage_fns(cfg, inner_ctx, ranges,
-                                label_smoothing=tcfg.label_smoothing)
-    embed_fn, head_fn = seg["embed_fn"], seg["head_fn"]
-    head_keys, stage_fwd = seg["head_keys"], seg["stage_fwd"]
-    ranks = n_dp
-    red_axis: Any = dp if len(dp) > 1 else dp[0]
-    axis_set = set(dp)
-    rank_spec = P(dp)
-    buf_spec = P(dp if len(dp) > 1 else dp[0])
-    be = layout.bucket_elems
-    shard = be // ranks
-    q_impl = tcfg.het.quantize_impl
-    params_shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    readiness = bkt.bucket_readiness(
-        layout, _pipeline_leaf_pieces(params_shape, cfg, splan))
-    subtree_slots: Dict[str, list] = {}
-    for (path, _), off, size in zip(
-            jax.tree_util.tree_flatten_with_path(params_shape)[0],
-            layout.offsets, layout.sizes):
-        subtree_slots.setdefault(_path_top(path[0]), []).append(
-            (off, size))
+    layout = sched.layout
+    rank_spec = _rank_spec(sched)
+    readiness = bkt.bucket_readiness(layout, _leaf_pieces(
+        sched.params_shape, cfg, lambda l: S - 1 - splan.stage_of_layer(l)))
+    scatter = _stream_scatter(sched, cfg.num_layers)
 
-    def scatter_subtree(buf, top, grads, layers=None):
-        """Scatter-add a landed grad subtree into the stream buffer
-        (stage slices index a contiguous per-layer region)."""
-        leaves = jax.tree.leaves(grads)
-        slots = subtree_slots.get(top, [])
-        assert len(leaves) == len(slots), (top, len(leaves), len(slots))
-        for g, (off, size) in zip(leaves, slots):
-            if layers is not None:
-                r0, r1 = layers
-                per = size // L
-                off, size = off + r0 * per, (r1 - r0) * per
-            buf = buf.at[:, off:off + size].add(
-                g.reshape(ranks, size).astype(jnp.float32))
-        return buf
-
-    def split_rank_microbatches(sb):
-        """Per-rank accumulation split (inner_dp == 1 counterpart of
-        the backward-overlap splitter — rows per rank cut into M equal
-        contiguous microbatch slices)."""
-        if M == 1:
-            return [sb]
-
-        def split(a):
-            b = a.shape[1]
-            if b % M:
-                raise ValueError(
-                    f"rows {b} per reduction rank not divisible by "
-                    f"accum {M}")
-            return a.reshape(ranks, M, b // M, *a.shape[2:])
-
-        s = {k: split(v) for k, v in sb.items()}
-        return [jax.tree.map(lambda a: a[:, i], s) for i in range(M)]
-
-    def step(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
+    def train_step(state: TrainState, batch: Dict
+                   ) -> Tuple[TrainState, Dict]:
         lr_step = state.opt.step + 1
         lr = schedules.learning_rate(ocfg, lr_step)
         params = state.params
-        sb = jax.tree.map(
-            lambda v: jax.lax.with_sharding_constraint(
-                v.reshape(ranks, v.shape[0] // ranks, *v.shape[1:]),
-                rank_spec), batch)
-        mbs = split_rank_microbatches(sb)
+        mbs = _rank_microbatches(batch, sched, M)
         slices = [jax.tree.map(lambda a: a[r0:r1], params["layers"])
                   for (r0, r1) in ranges]
         emb_p = {"embed": params["embed"]} if token_frontend else {}
         hp = {k: params[k] for k in head_keys}
-
-        def prep(k, raw_k):
-            return raw_k.reshape(ranks, ranks, shard), None
-
-        def exchange(k, prepared):
-            payload, _ = prepared
-
-            def region(pl):
-                onehot = bkt.rank_onehot(red_axis, ranks)
-                red, _ = bkt.exchange_prepared_bucket(
-                    pl[0], None, axis=red_axis, axis_size=ranks,
-                    compress=False, block_size=_BLOCK, impl=q_impl,
-                    interpret=False, onehot=onehot)
-                return red
-
-            red = jax.shard_map(
-                region, mesh=mesh, in_specs=buf_spec, out_specs=P(),
-                axis_names=axis_set, check_vma=False)(payload)
-            return red, None
-
-        pipeline_fl = bkt.BucketFlushPipeline(readiness, prep, exchange)
-
-        def flush(stage, buf):
-            pipeline_fl.flush_ready_buckets(
-                stage, lambda k: buf[:, k * be:(k + 1) * be])
-
-        buf = jax.lax.with_sharding_constraint(
-            jnp.zeros((ranks, layout.padded_total), jnp.float32),
-            buf_spec)
-        o_acc = jnp.zeros((ranks,), jnp.float32)
-        w_acc = jnp.zeros((ranks,), jnp.float32)
+        pipeline_fl, flush = _flush_pipeline(mesh, sched, readiness,
+                                             tcfg.het.quantize_impl)
+        buf = _stream_buffer(sched)
+        o_acc = jnp.zeros((sched.ranks,), jnp.float32)
+        w_acc = jnp.zeros((sched.ranks,), jnp.float32)
         x_in: Dict = {}
         stage_in: Dict = {}
         head_in: Dict = {}
@@ -1337,7 +1524,7 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
                 if s == 0:
                     x0 = jax.vmap(embed_fn, in_axes=(None, 0))(
                         emb_p, mb["inputs"])
-                    xa = (x0, jnp.zeros((ranks,), jnp.float32))
+                    xa = (x0, jnp.zeros((sched.ranks,), jnp.float32))
                 else:
                     xa = x_in.pop((s, m))
                 stage_in[(s, m)] = xa
@@ -1377,7 +1564,7 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
                             # stage-0 B event (see the allreduce path)
                             head_emb[m] = g_hp["embed"]
                             continue
-                        buf = scatter_subtree(buf, key, g_hp[key])
+                        buf = scatter(buf, key, g_hp[key])
                     cot = (x_cot, w_sgs[m])
                 else:
                     cot = cots.pop((s, m))
@@ -1394,8 +1581,7 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
                 g_sl, x_cot, a_cot = jax.vmap(
                     stage_bwd, in_axes=(None, 0, 0, 0, 0))(
                     slices[s], xa[0], xa[1], cot[0], cot[1])
-                buf = scatter_subtree(buf, "layers", g_sl,
-                                      layers=ranges[s])
+                buf = scatter(buf, "layers", g_sl, layers=ranges[s])
                 if m == M - 1:
                     flush(S - 1 - s, buf)
                 if s > 0:
@@ -1403,17 +1589,11 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
                         _pipe_send(x_cot, mesh, rank_spec, -1), a_cot)
                 else:
                     if token_frontend:
-                        def embed_stage(ep, i, xc):
-                            _, vjp = jax.vjp(
-                                lambda q: embed_fn(q, i), ep)
-                            return vjp(xc)[0]
-
-                        g_emb = jax.vmap(
-                            embed_stage, in_axes=(None, 0, 0))(
-                            emb_p, mb["inputs"], x_cot)["embed"]
+                        g_emb = _embed_grads(embed_fn, emb_p,
+                                             mb["inputs"], x_cot)
                         if m in head_emb:
                             g_emb = g_emb + head_emb.pop(m)
-                        buf = scatter_subtree(buf, "embed", g_emb)
+                        buf = scatter(buf, "embed", g_emb)
                     if m == M - 1:
                         flush(S, buf)
         outs, _, _ = pipeline_fl.finish()
@@ -1422,35 +1602,18 @@ def _build_pipeline_step(model: Model, tcfg: TrainConfig, mesh: Mesh, *,
         o, w = jnp.sum(o_acc), jnp.sum(w_acc)
         loss = weighting.finalize(o, w)
         grads = weighting.scale_grads(grads, w)
-        opt_apply = (lamb.apply_update if ocfg.name == "lamb"
-                     else adam.apply_update)
-        new_params, opt, met = opt_apply(params, grads, state.opt,
-                                         ocfg, lr)
+        new_params, opt, met = _tree_update(params, grads, state.opt,
+                                            ocfg, lr)
         metrics = {"loss": loss, "weight": w, **met}
         return TrainState(params=new_params, opt=opt,
                           err=state.err), metrics
 
-    return step
+    return train_step
 
 
 # --------------------------------------------------------------------------
 # train step
 # --------------------------------------------------------------------------
-
-
-def _fed_batch_specs(cfg: ModelConfig, tcfg: TrainConfig,
-                     mesh: Mesh) -> Dict[str, P]:
-    """Specs of the batch ``launch/train.py`` feeds the step.
-
-    Packed batches hold one capacity-plan buffer per DP rank
-    (``buffer_rows * dp_size`` rows), so they always shard over the DP
-    axes — even when the global batch does not divide the DP size, as
-    uneven row shares make common. Canonical batches are the
-    ``global_batch`` rows in global order.
-    """
-    rows = (tcfg.shape.global_batch if tcfg.het.weighting == "canonical"
-            else dp_size(mesh))
-    return shr.batch_specs(cfg, mesh, rows)
 
 
 def _counted(jitted, gauge: str, count: Callable[[str], int]):
@@ -1479,370 +1642,42 @@ def _counted(jitted, gauge: str, count: Callable[[str], int]):
     return step
 
 
+def _jit_train_step(train_step, model: Model, tcfg: TrainConfig,
+                    mesh: Mesh, sched: TrainSchedule):
+    """The one jit wrapper of every schedule: the state in and out with
+    the schedule's specs and donated, the batch as ``launch/train.py``
+    feeds it, compiled ahead and counted (``train.exchange_bytes``).
+
+    Packed batches hold one capacity-plan buffer per DP rank
+    (``buffer_rows * dp_size`` rows), so they always shard over the DP
+    axes — even when the global batch does not divide the DP size, as
+    uneven row shares make common. Canonical batches are the
+    ``global_batch`` rows in global order.
+    """
+    specs = shr.named(mesh, _state_specs(model, mesh, sched))
+    rows = (tcfg.shape.global_batch if sched.kind == "canonical"
+            else dp_size(mesh))
+    bspecs = shr.named(mesh, shr.batch_specs(model.cfg, mesh, rows))
+    return _counted(jax.jit(
+        train_step,
+        in_shardings=(specs, bspecs),
+        out_shardings=(specs, None),
+        donate_argnums=(0,),
+    ), "train.exchange_bytes", exchange_bytes)
+
+
 def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh
                      ) -> Callable[[TrainState, Dict], Tuple[TrainState,
                                                              Dict]]:
     validate_train_config(model, tcfg, mesh)
-    cfg = model.cfg
-    ctx = make_parallel_ctx(mesh)
-    ocfg = tcfg.optimizer
-    accum = max(1, tcfg.het.accum_steps)
-    hier = (tcfg.het.grad_reduction == "hierarchical"
-            and "pod" in mesh.axis_names)
-    bucketed_ar = tcfg.het.grad_reduction == "bucketed_allreduce"
-    compress = tcfg.het.compression if hier else "none"
-    layout = bucket_layout(model, tcfg, mesh) if (hier or bucketed_ar) \
-        else None
-    # bucketed_ar always has a layout here: validate_train_config
-    # raised on a missing DP axis, HetConfig.validate on bucket_mb <= 0
-    use_err = _err_enabled(tcfg, mesh)
-    q_impl = tcfg.het.quantize_impl
-    n_dp = dp_size(mesh)
-    dp = mesh_dp_axes(mesh)
-    n_pods = mesh.shape["pod"] if "pod" in mesh.axis_names else 1
-    overlap = _overlap_enabled(tcfg, mesh)
-    if overlap and layout is None:
-        raise ValueError("HetConfig.overlap='buckets' needs a bucket "
-                         "layout (bucket_mb > 0 and reduction axes)")
-    # the fused per-bucket pipeline can stream the optimizer as each
-    # bucket lands — AdamW entirely, LAMB up to one trailing
-    # trust-ratio pass (optim/lamb.py); global-norm clipping needs
-    # every bucket BEFORE the first moment update, so it keeps the
-    # pipelined exchange but updates behind a barrier
-    fused_stream = overlap and ocfg.grad_clip <= 0
-
-    if tcfg.het.pipeline_stages > 1:
-        # capacity-sized pipeline stages with 1F1B microbatching.
-        # HetConfig.validate pinned overlap="none" and reduction to
-        # allreduce / bucketed_allreduce, so `layout` is exactly the
-        # bucket grid for the per-stage flushes (or None for plain
-        # allreduce) and the optimizer state stays a pytree
-        splan = stage_plan_for(model, tcfg)
-        pipe_step = _build_pipeline_step(model, tcfg, mesh, splan=splan,
-                                         layout=layout)
-        specs = state_specs(model, tcfg, mesh)
-        bspecs = _fed_batch_specs(cfg, tcfg, mesh)
-        return _counted(jax.jit(
-            pipe_step,
-            in_shardings=(shr.named(mesh, specs),
-                          shr.named(mesh, bspecs)),
-            out_shardings=(shr.named(mesh, specs), None),
-            donate_argnums=(0,),
-        ), "train.exchange_bytes", exchange_bytes)
-
-    if overlap and tcfg.het.overlap == "backward":
-        # staged layer-by-layer backward with in-backprop bucket
-        # flushes — built as its own step function (the schedule is a
-        # top-level interleaving of vmapped VJP stages and per-bucket
-        # exchange regions, not a shard_map-wrapped monolith)
-        bwd_step = _build_backward_overlap_step(
-            model, tcfg, mesh, layout=layout, hier=hier,
-            compress=compress, use_err=use_err,
-            fused_stream=fused_stream)
-        specs = state_specs(model, tcfg, mesh)
-        bspecs = _fed_batch_specs(cfg, tcfg, mesh)
-        return _counted(jax.jit(
-            bwd_step,
-            in_shardings=(shr.named(mesh, specs),
-                          shr.named(mesh, bspecs)),
-            out_shardings=(shr.named(mesh, specs), None),
-            donate_argnums=(0,),
-        ), "train.exchange_bytes", exchange_bytes)
-
-    # inside a manual region the manual axes must not appear in sharding
-    # constraints — hierarchical keeps "data" automatic inside the pod
-    # region; bucketed_allreduce makes the whole DP set manual
-    if hier:
-        inner_ctx = ParallelCtx(mesh=mesh, dp_axes=("data",),
-                                tp_axis=tp_axis(mesh))
-        inner_dp = n_dp // n_pods
-    elif bucketed_ar:
-        inner_ctx = ParallelCtx(mesh=mesh, dp_axes=(),
-                                tp_axis=tp_axis(mesh))
-        inner_dp = 1
-    else:
-        inner_ctx = ctx
-        inner_dp = n_dp
-
-    def compute_grads(params, batch):
-        """Returns (grad_of_sums, obj_sum, weight_sum) — unscaled."""
-        def objective(p, b):
-            o, w, _ = model.loss_fn(
-                p, b, inner_ctx, label_smoothing=tcfg.label_smoothing)
-            return o, w
-
-        def grad_fn(p, b):
-            # value_and_grad, split so that the device trace names the
-            # forward and the backward pass apart
-            with jax.named_scope("forward"):
-                o, pullback, w = jax.vjp(lambda q: objective(q, b), p,
-                                         has_aux=True)
-            with jax.named_scope("backward"):
-                g, = pullback(jnp.ones_like(o))
-            return (o, w), g
-
-        if accum == 1:
-            (o, w), g = grad_fn(params, batch)
-            return g, o, w
-        mbs = acc.split_microbatches(batch, accum, num_ranks=inner_dp)
-
-        # accumulation carry dtype: fp32, except when params are stored
-        # bf16 (arctic/deepseek giants) where an fp32 carry alone would
-        # blow the 16 GB budget — bf16 carry, documented in EXPERIMENTS
-        def carry_dtype(p):
-            return p.dtype if p.dtype == jnp.bfloat16 else jnp.float32
-
-        if not cfg.scan_layers:
-            # unrolled-program class (scan_layers=False, required by
-            # overlap="backward"): keep the accumulation unrolled too
-            # so the staged backward stays bit-identical at accum > 1
-            return acc.unrolled_accumulate(grad_fn, params, mbs,
-                                           carry_dtype=carry_dtype)
-        return acc.scan_accumulate(grad_fn, params, mbs,
-                                   carry_dtype=carry_dtype)
-
-    def apply_pod_reduce(g, err):
-        """The cross-pod leg: bucketed engine or legacy per-leaf walk."""
-        if layout is not None:
-            g, ne = _reduce_bucketed(
-                g, err if use_err else None, axis="pod",
-                axis_size=n_pods, compress=compress, layout=layout,
-                impl=q_impl)
-            return g, (ne if ne is not None else ())
-        return _cross_pod_reduce(g, err, compress, n_pods)
-
-    # ---- fused overlap step (HetConfig.overlap="buckets") ---------------
-    # The optimizer moves INSIDE the manual region: the per-bucket
-    # pipeline exchanges bucket k while bucket k+1 quantizes, and the
-    # flat-view AdamW update for bucket k runs the moment it lands.
-    # The packed moments enter/leave the region replicated over the
-    # reduction axes; every rank computes the identical update.
-    if overlap:
-        dmask = bkt.decay_mask(layout)
-        segs = bkt.segment_ids(layout) if ocfg.name == "lamb" else None
-        n_leaves = len(layout.sizes)
-        red_axis: Any = "pod" if hier else (dp if len(dp) > 1 else dp[0])
-        red_size = n_pods if hier else n_dp
-
-        def fused_reduce_update(g, params, m, v, e, w_sum, lr_step, lr):
-            """Inside shard_map(manual over the reduction axes).
-
-            ``g``: this rank's unreduced grad tree; ``e``: this rank's
-            (nb, be) error slice or None; ``w_sum``: the GLOBAL weight
-            sum. Returns (params', m', v', err'(nb, be) | None, gnorm,
-            mean trust ratio — 1.0 for AdamW).
-            """
-            gb = bkt.pack_buckets(g, layout)
-            pb = bkt.pack_buckets(params, layout)
-            inv_w = 1.0 / jnp.maximum(w_sum, 1e-9)
-            kwargs = dict(axis=red_axis, axis_size=red_size,
-                          compress=(compress != "none"),
-                          block_size=_BLOCK, impl=q_impl)
-            if fused_stream and ocfg.name != "lamb":
-                def hook(ssq, red_k, xs_k, k):
-                    p_k, m_k, v_k, dm_k = xs_k
-                    g_k = red_k * inv_w
-                    out = adam.apply_update_flat(
-                        p_k, g_k, m_k, v_k, lr_step, ocfg, lr,
-                        decay_mask=dm_k)
-                    return ssq + jnp.sum(g_k * g_k), out
-
-                outs, new_e, ssq = bkt.exchange_buckets_overlapped(
-                    gb, e, bucket_fn=hook,
-                    fn_carry=jnp.zeros((), jnp.float32),
-                    bucket_xs=(pb, m, v, dmask), **kwargs)
-                new_pb, new_m, new_v = outs
-                gnorm = jnp.sqrt(ssq)
-                trust = jnp.ones((), jnp.float32)
-            else:
-                # clip barrier, and ALL of LAMB in this after-backward
-                # engine: fusing LAMB's hook into the per-bucket scan
-                # deterministically perturbs how XLA compiles the
-                # whole-module gradient/reduction program (~0.4% of
-                # reduced-grad elements move 1 ulp, measured across
-                # every hook/optimization_barrier variant), which
-                # breaks the backward==buckets bitwise contract
-                # (tests/test_overlap.py). The backward-overlap flush
-                # pipeline streams LAMB bitwise-safely; here the
-                # barrier form is the bit-exact choice — and the
-                # exchange is already fully overlapped bucket-to-
-                # bucket, so only the optimizer pass trails.
-                red, new_e, _ = bkt.exchange_buckets_overlapped(
-                    gb, e, **kwargs)
-                new_pb, new_m, new_v, gnorm, trust = \
-                    _flat_barrier_update(
-                        pb, red, m, v, lr_step, ocfg, lr, inv_w=inv_w,
-                        dmask=dmask, segs=segs, n_leaves=n_leaves)
-            return (bkt.unpack_buckets(new_pb, layout), new_m, new_v,
-                    new_e, gnorm, trust)
-
-        def overlap_step(state: TrainState, batch: Dict
-                         ) -> Tuple[TrainState, Dict]:
-            lr_step = state.opt.step + 1
-            lr = schedules.learning_rate(ocfg, lr_step)
-            err_in = state.err if use_err else ()
-            err_spec = P("pod") if use_err else P()
-            axes = {"pod"} if hier else set(dp)
-            batch_spec = P("pod") if hier else P(dp)
-
-            def unslice_err(err):
-                return (err.reshape(layout.num_buckets,
-                                    layout.bucket_elems)
-                        if use_err else None)
-
-            def reslice_err(new_e, err):
-                return (new_e.reshape(1, layout.num_buckets,
-                                      layout.bucket_elems)
-                        if use_err else err)
-
-            pspecs_in = state_specs(model, tcfg, mesh).params
-
-            def local(params, b, err, m, v, step_no, lr_in):
-                g, o, w = compute_grads(params, b)
-                if hier:
-                    # re-pin lost (data, model) layouts (see the
-                    # hierarchical branch below)
-                    g = jax.tree.map(
-                        lambda gr, s:
-                        jax.lax.with_sharding_constraint(gr, s),
-                        g, pspecs_in)
-                o = jax.lax.psum(o, red_axis)
-                w = jax.lax.psum(w, red_axis)
-                np_, nm, nv, ne, gn, tr = fused_reduce_update(
-                    g, params, m, v, unslice_err(err), w,
-                    step_no, lr_in)
-                return (np_, nm, nv, reslice_err(ne, err), o, w,
-                        gn, tr)
-
-            (new_params, new_m, new_v, new_err, o, w, gnorm,
-             trust) = jax.shard_map(
-                local, mesh=mesh,
-                in_specs=(P(), batch_spec, err_spec, P(), P(),
-                          P(), P()),
-                out_specs=(P(), P(), P(), err_spec, P(), P(),
-                           P(), P()),
-                axis_names=axes, check_vma=False,
-            )(state.params, batch, err_in, state.opt.m,
-              state.opt.v, lr_step, lr)
-            loss = weighting.finalize(o, w)
-            metrics = {"loss": loss, "weight": w, "grad_norm": gnorm,
-                       "lr": lr}
-            if ocfg.name == "lamb":
-                metrics["trust_ratio"] = trust
-            new_state = TrainState(
-                params=new_params,
-                opt=adam.AdamState(step=lr_step, m=new_m, v=new_v),
-                err=new_err if use_err else state.err)
-            return new_state, metrics
-
-    canonical = tcfg.het.weighting == "canonical"
-
-    def canonical_step(state: TrainState, batch: Dict
-                       ) -> Tuple[TrainState, Dict]:
-        """Order-canonical executor (core/weighting.py), now a real
-        train-step mode instead of bench-only: per-row vmapped grads
-        summed along the global-row axis with ONE fixed reduction tree.
-        The row->rank partition drops out of the float math entirely,
-        so two runs consuming the same global rows are bit-identical
-        whatever capacity replans happened in between — provided the
-        sampler emits rows in canonical global order
-        (HetSampler(canonical_order=True))."""
-        def row_loss(p, b):
-            return model.loss_fn(p, b,
-                                 label_smoothing=tcfg.label_smoothing)
-
-        (o_r, w_r), g_r = weighting.per_row_values(
-            row_loss, state.params, batch)
-        loss, grads, _, w = weighting.canonical_aggregate(o_r, w_r, g_r)
-        lr = schedules.learning_rate(ocfg, state.opt.step + 1)
-        opt_apply = (lamb.apply_update if ocfg.name == "lamb"
-                     else adam.apply_update)
-        params, opt, met = opt_apply(state.params, grads,
-                                     state.opt, ocfg, lr)
-        metrics = {"loss": loss, "weight": w, **met}
-        return TrainState(params=params, opt=opt, err=state.err), metrics
-
-    def train_step(state: TrainState, batch: Dict
-                   ) -> Tuple[TrainState, Dict]:
-        if canonical:
-            return canonical_step(state, batch)
-        if overlap:
-            return overlap_step(state, batch)
-        if hier:
-            pspecs_in = state_specs(model, tcfg, mesh).params
-
-            def pod_local(params, b, err):
-                g, o, w = compute_grads(params, b)
-                # inside the partially-manual region XLA's sharding
-                # propagation can lose the (data, model) layout of
-                # the gradients; re-pin them to the param specs so
-                # the pod exchange moves shards, not replicated
-                # leaves
-                g = jax.tree.map(
-                    lambda gr, s: jax.lax.with_sharding_constraint(
-                        gr, s),
-                    g, pspecs_in)
-                with jax.named_scope("exchange"):
-                    g, ne = apply_pod_reduce(g, err)
-                    return g, jax.lax.psum(o, "pod"), \
-                        jax.lax.psum(w, "pod"), ne
-
-            grads, o, w, new_err = jax.shard_map(
-                pod_local, mesh=mesh,
-                in_specs=(P(), P("pod"), P("pod") if use_err
-                          else P()),
-                out_specs=(P(), P(), P(), P("pod") if use_err
-                           else P()),
-                axis_names={"pod"}, check_vma=False,
-            )(state.params, batch, state.err)
-        elif bucketed_ar:
-            axis = dp if len(dp) > 1 else dp[0]
-
-            def reduce_buckets(g):
-                out, _ = _reduce_bucketed(g, None, axis=axis,
-                                          axis_size=n_dp,
-                                          compress="none", layout=layout,
-                                          impl=q_impl)
-                return out
-
-            def dp_local(params, b):
-                g, o, w = compute_grads(params, b)
-                with jax.named_scope("exchange"):
-                    return reduce_buckets(g), jax.lax.psum(o, dp), \
-                        jax.lax.psum(w, dp)
-
-            grads, o, w = jax.shard_map(
-                dp_local, mesh=mesh,
-                in_specs=(P(), P(dp)),
-                out_specs=(P(), P(), P()),
-                axis_names=set(dp), check_vma=False,
-            )(state.params, batch)
-            new_err = state.err
-        else:
-            grads, o, w = compute_grads(state.params, batch)
-            new_err = state.err
-        with jax.named_scope("exchange"):
-            # the weighted mean over every rank's rows (with plain
-            # allreduce the partitioner places the sums' collectives)
-            loss = weighting.finalize(o, w)
-            grads = weighting.scale_grads(grads, w)
-        with jax.named_scope("optimizer"):
-            lr = schedules.learning_rate(ocfg, state.opt.step + 1)
-            opt_apply = (lamb.apply_update if ocfg.name == "lamb"
-                         else adam.apply_update)
-            params, opt, met = opt_apply(state.params, grads,
-                                         state.opt, ocfg, lr)
-        metrics = {"loss": loss, "weight": w, **met}
-        return TrainState(params=params, opt=opt, err=new_err), metrics
-
-    specs = state_specs(model, tcfg, mesh)
-    bspecs = _fed_batch_specs(cfg, tcfg, mesh)
-    return _counted(jax.jit(
-        train_step,
-        in_shardings=(shr.named(mesh, specs), shr.named(mesh, bspecs)),
-        out_shardings=(shr.named(mesh, specs), None),
-        donate_argnums=(0,),
-    ), "train.exchange_bytes", exchange_bytes)
+    sched = train_schedule(model, tcfg, mesh)
+    build = {"overlap_buckets": _build_overlap_buckets_step,
+             "overlap_backward": _build_backward_overlap_step,
+             "pipeline": _build_pipeline_step,
+             "canonical": _build_canonical_step,
+             }.get(sched.kind, _build_reduced_step)
+    return _jit_train_step(build(model, tcfg, mesh, sched), model, tcfg,
+                           mesh, sched)
 
 
 # --------------------------------------------------------------------------

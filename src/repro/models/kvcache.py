@@ -106,7 +106,7 @@ def attention_decode(params, x: jnp.ndarray, cfg: ModelConfig,
     # over "model" (split-K spec), XLA partitions the softmax reduction
     # across ranks automatically. A chunked python-level loop over the
     # sharded dim BREAKS that (each chunk broadcast to all ranks) —
-    # measured +60% ICI — see EXPERIMENTS.md §Perf (refuted hypothesis).
+    # measured +60% ICI (a refuted hypothesis).
     out = attn_ref.mha_dense(q, k_cache, v_cache, causal=False,
                              kv_len=kv_len)
     out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim)

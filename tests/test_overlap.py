@@ -203,25 +203,26 @@ def test_overlap_config_validation():
     import dataclasses
     from repro.configs import base as cfgs
     from repro.configs.base import HetConfig, TrainConfig
-    from repro.launch.steps import _overlap_enabled
+    from repro.launch.steps import train_schedule
+    from repro.models.model import build_model
 
     mesh = make_mesh((1, 1), ("data", "model"))
-    model = cfgs.smoke_config("olmo-1b")
+    model = build_model(cfgs.smoke_config("olmo-1b"))
     for het, err in ((HetConfig(overlap="buckets"), "explicit"),
                      (HetConfig(overlap="backward"), "explicit"),
                      (HetConfig(overlap="buckets",
                                 grad_reduction="bucketed_allreduce"),
                       "bucket_mb"),
                      (HetConfig(overlap="banana"), "not one of")):
-        tcfg = TrainConfig(model=model, het=het)
+        tcfg = TrainConfig(model=model.cfg, het=het)
         with pytest.raises(ValueError, match=err):
-            _overlap_enabled(tcfg, mesh)
-    ok = TrainConfig(model=model, het=HetConfig(
+            train_schedule(model, tcfg, mesh)
+    ok = TrainConfig(model=model.cfg, het=HetConfig(
         overlap="buckets", grad_reduction="bucketed_allreduce",
         bucket_mb=0.05))
-    assert _overlap_enabled(ok, mesh)
+    assert train_schedule(model, ok, mesh).kind == "overlap_buckets"
     none = dataclasses.replace(ok, het=HetConfig())
-    assert not _overlap_enabled(none, mesh)
+    assert train_schedule(model, none, mesh).kind == "allreduce"
 
 
 def test_backward_overlap_build_validation():
